@@ -88,8 +88,8 @@ def kernels() -> Rows:
     # decode attention
     B, H, Kv, dh, T = 8, 16, 4, 64, 1024
     q = jax.random.normal(ks[3], (B, H, dh), jnp.float32)
-    ck = jax.random.normal(ks[4], (B, T, Kv, dh), jnp.float32)
-    cv = jax.random.normal(ks[5], (B, T, Kv, dh), jnp.float32)
+    ck = jax.random.normal(ks[4], (B, Kv, T, dh), jnp.float32)
+    cv = jax.random.normal(ks[5], (B, Kv, T, dh), jnp.float32)
     lens = jnp.full((B,), T, jnp.int32)
     us = time_fn(
         lambda: ops.decode_attention(q, ck, cv, lens, bt=256,
@@ -137,8 +137,8 @@ def paged_decode() -> Rows:
     mixed = np.array([64, 128, 256, 384, 512, 640, 896, 1024])
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(ks[0], (B, H, dh), jnp.float32)
-    ck = jax.random.normal(ks[1], (B, T, Kv, dh), jnp.float32)
-    cv = jax.random.normal(ks[2], (B, T, Kv, dh), jnp.float32)
+    ck = jax.random.normal(ks[1], (B, Kv, T, dh), jnp.float32)
+    cv = jax.random.normal(ks[2], (B, Kv, T, dh), jnp.float32)
     lens = jnp.asarray(mixed, jnp.int32)
 
     # pack the dense cache into a block pool sized to the allocated blocks
@@ -147,7 +147,7 @@ def paged_decode() -> Rows:
     tab = np.zeros((B, nb), np.int32)
     owner = np.full((n_pool,), -1, np.int32)
     bpos = np.zeros((n_pool,), np.int32)
-    pool_k = np.zeros((n_pool, page, Kv, dh), np.float32)
+    pool_k = np.zeros((n_pool, Kv, page, dh), np.float32)
     pool_v = np.zeros_like(pool_k)
     ck_np, cv_np = np.asarray(ck), np.asarray(cv)
     nxt = 1
@@ -155,8 +155,8 @@ def paged_decode() -> Rows:
         for j in range(-(-int(mixed[b]) // page)):
             tab[b, j] = nxt
             owner[nxt], bpos[nxt] = b, j
-            pool_k[nxt] = ck_np[b, j * page:(j + 1) * page]
-            pool_v[nxt] = cv_np[b, j * page:(j + 1) * page]
+            pool_k[nxt] = ck_np[b, :, j * page:(j + 1) * page]
+            pool_v[nxt] = cv_np[b, :, j * page:(j + 1) * page]
             nxt += 1
     pk, pv = jnp.asarray(pool_k), jnp.asarray(pool_v)
     tab_j = jnp.asarray(tab)

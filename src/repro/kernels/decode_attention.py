@@ -7,9 +7,13 @@ from HBM through VMEM.
 
 Three variants share the same online-softmax tile update:
 
-* :func:`decode_attention` — dense ``(B, T, Kv, dh)`` cache.  Grid
-  (batch, kv_head, ceil(T/bt)); the softmax state (m, l, acc) lives in VMEM
-  scratch and persists across the sequential T-tiles.  A ragged tail tile
+* :func:`decode_attention` — dense head-major ``(B, Kv, T, dh)`` cache.
+  Grid (batch, kv_head, ceil(T/bt)); each KV block is one head's
+  ``(bt, dh)`` tile, which is what the TPU's (sublane, lane) tiling needs
+  (a token-major cache would cut the kv-head axis to 1 in the
+  second-minor position, which Mosaic refuses).  The softmax state
+  (m, l, acc) lives in VMEM scratch and persists across the sequential
+  T-tiles.  A ragged tail tile
   (``T % bt != 0``) is masked by the same ``pos < lengths`` predicate that
   masks per-sequence cache lengths, and tiles entirely past a sequence's
   length skip their MXU work.
@@ -20,8 +24,8 @@ Three variants share the same online-softmax tile update:
   — the ``OnlineSoftmax.online_fwd`` / ``combine`` idiom.
 
 * :func:`decode_attention_paged` — block-table-indexed variant over a
-  shared block pool ``(n_pool, page, Kv, dh)``.  The K/V BlockSpec index
-  maps resolve logical KV blocks through a scalar-prefetched
+  shared head-major block pool ``(n_pool, Kv, page, dh)``.  The K/V
+  BlockSpec index maps resolve logical KV blocks through a scalar-prefetched
   ``(n_slots, max_blocks)`` block table, so a slot only streams the pool
   blocks it actually owns; dead table cells point at the reserved trash
   block 0 and are skipped.
@@ -35,8 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from .pallas_compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -65,15 +67,16 @@ def _online_tile_update(s, v, m_ref, l_ref, acc_ref):
 
 def _masked_tile(q_ref, k_ref, v_ref, length, tile_start: jax.Array, bt: int,
                  scale: float, m_ref, l_ref, acc_ref):
-    q = q_ref[0, 0].astype(jnp.float32)  # (G, dh)
-    k = k_ref[0, :, 0].astype(jnp.float32)  # (bt, dh)
-    v = v_ref[0, :, 0].astype(jnp.float32)  # (bt, dh)
+    q = q_ref[...].astype(jnp.float32)  # (G, dh)
+    k = k_ref[...].astype(jnp.float32)  # (bt, dh)
+    v = v_ref[...].astype(jnp.float32)  # (bt, dh)
     pos = tile_start + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+    pos_col = tile_start + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
     valid = pos < length
     # rows past the sequence length are garbage — a ragged tail tile even
     # reads past the array edge (NaN under the interpreter); zero V so a
     # p=0 row can never poison the accumulator through 0 * NaN
-    v = jnp.where(valid.reshape(bt, 1), v, 0.0)
+    v = jnp.where(pos_col < length, v, 0.0)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale  # (G, bt)
@@ -94,10 +97,10 @@ def _init_state(m_ref, l_ref, acc_ref):
 
 def _decode_attn_kernel(
     lengths_ref,  # (B,) int32 scalar prefetch
-    q_ref,  # (1, 1, G, dh)
-    k_ref,  # (1, bt, 1, dh)
-    v_ref,  # (1, bt, 1, dh)
-    out_ref,  # (1, 1, G, dh)
+    q_ref,  # (G, dh)
+    k_ref,  # (bt, dh)
+    v_ref,  # (bt, dh)
+    out_ref,  # (G, dh)
     m_ref,  # (G, 1) fp32 scratch
     l_ref,  # (G, 1) fp32 scratch
     acc_ref,  # (G, dh) fp32 scratch
@@ -126,16 +129,16 @@ def _decode_attn_kernel(
     def _finish():
         # length-0 rows never ran a tile: acc == 0, l == 0 -> zeros out
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        out_ref[...] = out[None, None].astype(out_ref.dtype)
+        out_ref[...] = out.astype(out_ref.dtype)
 
 
 def _decode_attn_split_kernel(
     lengths_ref,  # (B,) int32 scalar prefetch
-    q_ref,  # (1, 1, G, dh)
-    k_ref,  # (1, bt, 1, dh)
-    v_ref,  # (1, bt, 1, dh)
-    out_ref,  # (1, 1, 1, G, dh)  normalized partial for this split
-    lse_ref,  # (1, 1, 1, G)      log-sum-exp for this split
+    q_ref,  # (G, dh)
+    k_ref,  # (bt, dh)
+    v_ref,  # (bt, dh)
+    out_ref,  # (G, dh)  normalized partial for this split
+    lse_ref,  # (G, 1)   log-sum-exp for this split
     m_ref,  # (G, 1) fp32 scratch
     l_ref,  # (G, 1) fp32 scratch
     acc_ref,  # (G, dh) fp32 scratch
@@ -168,21 +171,21 @@ def _decode_attn_split_kernel(
         # no live position export lse = NEG_INF so the combine drops them.
         l = l_ref[...]  # (G, 1)
         out = acc_ref[...] / jnp.maximum(l, 1e-30)
-        out_ref[...] = out[None, None, None].astype(out_ref.dtype)
-        lse = jnp.where(
+        out_ref[...] = out.astype(out_ref.dtype)
+        lse_ref[...] = jnp.where(
             l > 0, m_ref[...] + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF
         )
-        lse_ref[...] = lse[:, 0][None, None, None]
 
 
 def _combine_splits(out_p: jax.Array, lse: jax.Array) -> jax.Array:
     """LSE combine over the split axis.
 
-    out_p (B, Kv, S, G, dh) normalized partials, lse (B, Kv, S, G).
+    out_p (B, Kv, S, G, dh) normalized partials, lse (B, Kv, S, G, 1).
     ``o = sum_s o_s * exp(lse_s - lse_sum)`` with empty splits (lse at
     NEG_INF) contributing zero weight; a fully-empty row (length 0)
     combines to zeros.
     """
+    lse = lse[..., 0]
     lse_max = lse.max(axis=2, keepdims=True)
     w = jnp.where(lse > NEG_INF * 0.5, jnp.exp(lse - lse_max), 0.0)
     den = w.sum(axis=2)  # (B, Kv, G)
@@ -192,8 +195,8 @@ def _combine_splits(out_p: jax.Array, lse: jax.Array) -> jax.Array:
 
 def decode_attention(
     q: jax.Array,  # (B, H, dh) one query token per sequence
-    cache_k: jax.Array,  # (B, T, Kv, dh)
-    cache_v: jax.Array,  # (B, T, Kv, dh)
+    cache_k: jax.Array,  # (B, Kv, T, dh) head-major
+    cache_v: jax.Array,  # (B, Kv, T, dh)
     lengths: jax.Array,  # (B,) int32 valid entries
     *,
     bt: int = 512,
@@ -201,7 +204,7 @@ def decode_attention(
     interpret: bool = False,
 ) -> jax.Array:
     B, H, dh = q.shape
-    _, T, Kv, _ = cache_k.shape
+    _, Kv, T, _ = cache_k.shape
     G = H // Kv
     bt = min(bt, T)
     n_tiles = -(-T // bt)  # ragged tail tile masked in-kernel
@@ -214,12 +217,12 @@ def decode_attention(
             num_scalar_prefetch=1,
             grid=(B, Kv, n_tiles),
             in_specs=[
-                pl.BlockSpec((1, 1, G, dh), lambda b, h, t, L: (b, h, 0, 0)),
-                pl.BlockSpec((1, bt, 1, dh), lambda b, h, t, L: (b, t, h, 0)),
-                pl.BlockSpec((1, bt, 1, dh), lambda b, h, t, L: (b, t, h, 0)),
+                pl.BlockSpec((None, None, G, dh), lambda b, h, t, L: (b, h, 0, 0)),
+                pl.BlockSpec((None, None, bt, dh), lambda b, h, t, L: (b, h, t, 0)),
+                pl.BlockSpec((None, None, bt, dh), lambda b, h, t, L: (b, h, t, 0)),
             ],
             out_specs=pl.BlockSpec(
-                (1, 1, G, dh), lambda b, h, t, L: (b, h, 0, 0)
+                (None, None, G, dh), lambda b, h, t, L: (b, h, 0, 0)
             ),
             scratch_shapes=[
                 pltpu.VMEM((G, 1), jnp.float32),
@@ -234,7 +237,7 @@ def decode_attention(
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, Kv, G, dh), q.dtype),
-            compiler_params=CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             ),
             interpret=interpret,
@@ -248,19 +251,25 @@ def decode_attention(
         num_scalar_prefetch=1,
         grid=(B, Kv, n_splits, n_t),
         in_specs=[
-            pl.BlockSpec((1, 1, G, dh), lambda b, h, s, t, L: (b, h, 0, 0)),
             pl.BlockSpec(
-                (1, bt, 1, dh), lambda b, h, s, t, L: (b, s * n_t + t, h, 0)
+                (None, None, G, dh), lambda b, h, s, t, L: (b, h, 0, 0)
             ),
             pl.BlockSpec(
-                (1, bt, 1, dh), lambda b, h, s, t, L: (b, s * n_t + t, h, 0)
+                (None, None, bt, dh),
+                lambda b, h, s, t, L: (b, h, s * n_t + t, 0),
+            ),
+            pl.BlockSpec(
+                (None, None, bt, dh),
+                lambda b, h, s, t, L: (b, h, s * n_t + t, 0),
             ),
         ],
         out_specs=[
             pl.BlockSpec(
-                (1, 1, 1, G, dh), lambda b, h, s, t, L: (b, h, s, 0, 0)
+                (None, None, None, G, dh), lambda b, h, s, t, L: (b, h, s, 0, 0)
             ),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, s, t, L: (b, h, s, 0)),
+            pl.BlockSpec(
+                (None, None, None, G, 1), lambda b, h, s, t, L: (b, h, s, 0, 0)
+            ),
         ],
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
@@ -276,9 +285,9 @@ def decode_attention(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, Kv, n_splits, G, dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, Kv, n_splits, G), jnp.float32),
+            jax.ShapeDtypeStruct((B, Kv, n_splits, G, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "arbitrary", "arbitrary", "arbitrary", "arbitrary"
             ),
@@ -297,10 +306,10 @@ def decode_attention(
 def _paged_decode_attn_kernel(
     lengths_ref,  # (B,) int32 scalar prefetch
     tables_ref,  # (B, max_blocks) int32 scalar prefetch (index maps only)
-    q_ref,  # (1, 1, G, dh)
-    k_ref,  # (1, page, 1, dh)  pool block resolved through the table
-    v_ref,  # (1, page, 1, dh)
-    out_ref,  # (1, 1, G, dh)
+    q_ref,  # (G, dh)
+    k_ref,  # (page, dh)  pool block resolved through the table
+    v_ref,  # (page, dh)
+    out_ref,  # (G, dh)
     m_ref,  # (G, 1) fp32 scratch
     l_ref,  # (G, 1) fp32 scratch
     acc_ref,  # (G, dh) fp32 scratch
@@ -332,20 +341,20 @@ def _paged_decode_attn_kernel(
     @pl.when(j == n_blocks - 1)
     def _finish():
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        out_ref[...] = out[None, None].astype(out_ref.dtype)
+        out_ref[...] = out.astype(out_ref.dtype)
 
 
 def decode_attention_paged(
     q: jax.Array,  # (B, H, dh) one query token per sequence
-    pool_k: jax.Array,  # (n_pool, page, Kv, dh) shared block pool
-    pool_v: jax.Array,  # (n_pool, page, Kv, dh)
+    pool_k: jax.Array,  # (n_pool, Kv, page, dh) head-major block pool
+    pool_v: jax.Array,  # (n_pool, Kv, page, dh)
     block_tables: jax.Array,  # (B, max_blocks) int32 logical -> physical
     lengths: jax.Array,  # (B,) int32 valid entries per sequence
     *,
     interpret: bool = False,
 ) -> jax.Array:
     B, H, dh = q.shape
-    _, page, Kv, _ = pool_k.shape
+    _, Kv, page, _ = pool_k.shape
     G = H // Kv
     n_blocks = block_tables.shape[1]
     qg = q.reshape(B, Kv, G, dh)
@@ -354,15 +363,19 @@ def decode_attention_paged(
         num_scalar_prefetch=2,
         grid=(B, Kv, n_blocks),
         in_specs=[
-            pl.BlockSpec((1, 1, G, dh), lambda b, h, j, L, BT: (b, h, 0, 0)),
             pl.BlockSpec(
-                (1, page, 1, dh), lambda b, h, j, L, BT: (BT[b, j], 0, h, 0)
+                (None, None, G, dh), lambda b, h, j, L, BT: (b, h, 0, 0)
             ),
             pl.BlockSpec(
-                (1, page, 1, dh), lambda b, h, j, L, BT: (BT[b, j], 0, h, 0)
+                (None, None, page, dh), lambda b, h, j, L, BT: (BT[b, j], h, 0, 0)
+            ),
+            pl.BlockSpec(
+                (None, None, page, dh), lambda b, h, j, L, BT: (BT[b, j], h, 0, 0)
             ),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, dh), lambda b, h, j, L, BT: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec(
+            (None, None, G, dh), lambda b, h, j, L, BT: (b, h, 0, 0)
+        ),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
@@ -379,7 +392,7 @@ def decode_attention_paged(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Kv, G, dh), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

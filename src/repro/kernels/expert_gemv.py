@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 
 def _gemv_kernel(
     expert_ids_ref,  # (S,) int32 scalar prefetch
@@ -71,6 +69,9 @@ def expert_gemv(
     bn: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
+    # tokens/output viewed as (S, 1, K) / (S, 1, N) with the row axis
+    # squeezed from the block: the TPU tiling rule refuses a (1, bk) block
+    # over (S, K) but accepts one whose unit dim is the array's own
     S, K = tokens.shape
     E, _, N = weights.shape
     bk, bn = min(bk, K), min(bn, N)
@@ -81,19 +82,20 @@ def expert_gemv(
         num_scalar_prefetch=2,
         grid=(S, n_tiles, k_tiles),
         in_specs=[
-            pl.BlockSpec((1, bk), lambda i, j, k, e, v: (i, k)),
+            pl.BlockSpec((None, 1, bk), lambda i, j, k, e, v: (i, 0, k)),
             pl.BlockSpec((1, bk, bn), lambda i, j, k, e, v: (e[i], k, j)),
         ],
-        out_specs=pl.BlockSpec((1, bn), lambda i, j, k, e, v: (i, j)),
+        out_specs=pl.BlockSpec((None, 1, bn), lambda i, j, k, e, v: (i, 0, j)),
         scratch_shapes=[pltpu.VMEM((1, bn), jnp.float32)],
     )
     kernel = functools.partial(_gemv_kernel, n_k_tiles=k_tiles)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, N), tokens.dtype),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((S, 1, N), tokens.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-    )(expert_ids, valid, tokens, weights)
+    )(expert_ids, valid, tokens.reshape(S, 1, K), weights)
+    return out.reshape(S, N)

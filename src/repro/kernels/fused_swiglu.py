@@ -41,8 +41,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 
 def _fused_swiglu_gmm_kernel(
     # scalar prefetch
@@ -206,7 +204,7 @@ def fused_swiglu_gmm(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), lhs.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "arbitrary", "arbitrary", "arbitrary", "arbitrary"
             ),
@@ -301,7 +299,13 @@ def fused_swiglu_gemv(
     """Raw pallas_call; use ops.swiglu_gemv for the user-facing wrapper.
 
     Per token i: ``out[i] = swiglu(tokens[i]; wg/wu/wd[expert_ids[i]])`` —
-    each row's expert weights are streamed from HBM exactly once."""
+    each row's expert weights are streamed from HBM exactly once.
+
+    Tokens and output are viewed as ``(S, 1, K)`` / ``(S, 1, N)`` with the
+    row axis squeezed out of the block, so each block's last two dims are
+    ``(1, bk)`` / ``(1, N)`` — equal to the array's own unit dim, which the
+    TPU's tiling rule accepts (a ``(1, bk)`` block over ``(S, K)`` is
+    refused)."""
     S, K = tokens.shape
     E, _, F = wg.shape
     N = wd.shape[2]
@@ -313,12 +317,12 @@ def fused_swiglu_gemv(
         num_scalar_prefetch=2,
         grid=(S, f_tiles, k_tiles),
         in_specs=[
-            pl.BlockSpec((1, bk), lambda i, j, k, e, v: (i, k)),
+            pl.BlockSpec((None, 1, bk), lambda i, j, k, e, v: (i, 0, k)),
             pl.BlockSpec((1, bk, bf), lambda i, j, k, e, v: (e[i], k, j)),
             pl.BlockSpec((1, bk, bf), lambda i, j, k, e, v: (e[i], k, j)),
             pl.BlockSpec((1, bf, N), lambda i, j, k, e, v: (e[i], j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, N), lambda i, j, k, e, v: (i, 0)),
+        out_specs=pl.BlockSpec((None, 1, N), lambda i, j, k, e, v: (i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, bf), jnp.float32),
             pltpu.VMEM((1, bf), jnp.float32),
@@ -328,12 +332,13 @@ def fused_swiglu_gemv(
     kernel = functools.partial(
         _fused_swiglu_gemv_kernel, n_k_tiles=k_tiles, n_f_tiles=f_tiles
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, N), tokens.dtype),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((S, 1, N), tokens.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-    )(expert_ids, valid, tokens, wg, wu, wd)
+    )(expert_ids, valid, tokens.reshape(S, 1, K), wg, wu, wd)
+    return out.reshape(S, N)

@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 
 def _gmm_kernel(
     # scalar prefetch
@@ -121,7 +119,7 @@ def grouped_gemm(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), lhs.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

@@ -298,7 +298,7 @@ def swiglu_gemv(
 @functools.partial(jax.jit, static_argnames=("bt", "n_splits", "interpret"))
 def decode_attention(
     q: jax.Array,  # (B, H, dh)
-    cache_k: jax.Array,  # (B, T, Kv, dh)
+    cache_k: jax.Array,  # (B, Kv, T, dh) head-major
     cache_v: jax.Array,
     lengths: jax.Array,  # (B,)
     bt: int = 512,
@@ -322,7 +322,7 @@ def decode_attention(
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def decode_attention_paged(
     q: jax.Array,  # (B, H, dh)
-    pool_k: jax.Array,  # (n_pool, page, Kv, dh) shared block pool
+    pool_k: jax.Array,  # (n_pool, Kv, page, dh) head-major block pool
     pool_v: jax.Array,
     block_tables: jax.Array,  # (B, max_blocks) int32
     lengths: jax.Array,  # (B,)

@@ -74,26 +74,26 @@ def fused_swiglu_gemv_ref(
 
 def decode_attention_ref(
     q: jax.Array,  # (B, H, dh)
-    cache_k: jax.Array,  # (B, T, Kv, dh)
-    cache_v: jax.Array,  # (B, T, Kv, dh)
+    cache_k: jax.Array,  # (B, Kv, T, dh) head-major
+    cache_v: jax.Array,  # (B, Kv, T, dh)
     lengths: jax.Array,  # (B,)
 ) -> jax.Array:
     B, H, dh = q.shape
-    T, Kv = cache_k.shape[1], cache_k.shape[2]
+    Kv, T = cache_k.shape[1], cache_k.shape[2]
     G = H // Kv
     qf = q.reshape(B, Kv, G, dh).astype(jnp.float32)
-    s = jnp.einsum("bkgd,btkd->bkgt", qf, cache_k.astype(jnp.float32)) / (dh**0.5)
+    s = jnp.einsum("bkgd,bktd->bkgt", qf, cache_k.astype(jnp.float32)) / (dh**0.5)
     mask = jnp.arange(T)[None, :] < lengths[:, None]
     s = jnp.where(mask[:, None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgt,btkd->bkgd", p, cache_v.astype(jnp.float32))
+    o = jnp.einsum("bkgt,bktd->bkgd", p, cache_v.astype(jnp.float32))
     return o.reshape(B, H, dh).astype(q.dtype)
 
 
 def decode_attention_paged_ref(
     q: jax.Array,  # (B, H, dh)
-    pool_k: jax.Array,  # (n_pool, page, Kv, dh) shared block pool
-    pool_v: jax.Array,  # (n_pool, page, Kv, dh)
+    pool_k: jax.Array,  # (n_pool, Kv, page, dh) head-major block pool
+    pool_v: jax.Array,  # (n_pool, Kv, page, dh)
     block_tables: jax.Array,  # (B, max_blocks) int32 logical -> physical
     lengths: jax.Array,  # (B,)
 ) -> jax.Array:
@@ -101,9 +101,16 @@ def decode_attention_paged_ref(
     :func:`decode_attention_ref` — the semantic definition of the paged
     layout (dead table cells point at the trash block and are masked by
     ``lengths``)."""
-    B = q.shape[0]
-    _, page, Kv, dh = pool_k.shape
-    nb = block_tables.shape[1]
-    k = pool_k[block_tables].reshape(B, nb * page, Kv, dh)
-    v = pool_v[block_tables].reshape(B, nb * page, Kv, dh)
-    return decode_attention_ref(q, k, v, lengths)
+    return decode_attention_ref(
+        q, gather_pages(pool_k, block_tables), gather_pages(pool_v, block_tables),
+        lengths,
+    )
+
+
+def gather_pages(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
+    """``(n_pool, Kv, page, dh)`` pool + ``(B, nb)`` table -> the dense
+    head-major ``(B, Kv, nb * page, dh)`` cache the table describes."""
+    B, nb = block_tables.shape
+    _, Kv, page, dh = pool.shape
+    g = pool[block_tables]  # (B, nb, Kv, page, dh)
+    return g.transpose(0, 2, 1, 3, 4).reshape(B, Kv, nb * page, dh)

@@ -14,35 +14,24 @@ import jax
 from repro.models.moe import MeshInfo
 
 
-def _axis_types_kw(n_axes: int) -> dict:
-    # jax >= 0.5 wants explicit axis types; 0.4.x has no AxisType at all.
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """The target deployment mesh: one v5e pod = (16, 16) = (data, model);
     two pods = (2, 16, 16) = (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Arbitrary mesh (elastic scaling / tests)."""
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+    """Arbitrary mesh (elastic scaling / tests), every axis ``Auto``."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def use_mesh(mesh):
-    """Context manager activating ``mesh``.
-
-    jax >= 0.5 exposes ``jax.set_mesh``; on 0.4.x the Mesh object itself
-    is the context manager.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager activating ``mesh``."""
+    return jax.set_mesh(mesh)
 
 
 def mesh_info_for(mesh, global_batch: Optional[int] = None) -> MeshInfo:

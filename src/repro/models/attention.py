@@ -153,23 +153,48 @@ def flash_attention(
 
 def decode_attention_ref(
     q: jax.Array,  # (B, 1, H, dh)
-    cache_k: jax.Array,  # (B, T, K, dh)
-    cache_v: jax.Array,  # (B, T, K, dh)
+    cache_k: jax.Array,  # (B, K, T, dh) head-major
+    cache_v: jax.Array,  # (B, K, T, dh)
     length: jax.Array,  # (B,) valid cache entries (incl. current token)
 ) -> jax.Array:
     """One-token GQA attention against the KV cache (the memory-bound GEMV
     op the paper offloads to PIM; Pallas version in kernels/decode_attention)."""
     B, _, H, dh = q.shape
-    T, K = cache_k.shape[1], cache_k.shape[2]
+    K, T = cache_k.shape[1], cache_k.shape[2]
     G = H // K
     qf = q.reshape(B, K, G, dh).astype(jnp.float32)
-    s = jnp.einsum("bkgd,btkd->bkgt", qf, cache_k.astype(jnp.float32))
+    s = jnp.einsum("bkgd,bktd->bkgt", qf, cache_k.astype(jnp.float32))
     s = s / jnp.sqrt(dh)
     mask = jnp.arange(T)[None, :] < length[:, None]  # (B, T)
     s = jnp.where(mask[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgt,btkd->bkgd", p, cache_v.astype(jnp.float32))
+    o = jnp.einsum("bkgt,bktd->bkgd", p, cache_v.astype(jnp.float32))
     return o.reshape(B, 1, H, dh).astype(q.dtype)
+
+
+def _per_head_shard(kernel, mi, cfg: AttnConfig, batch_sharded: bool):
+    """``kernel(q (B, H, dh), k, v (B|pool, K, T|page, dh), *rest)`` as the
+    step calls it.  XLA cannot partition a Pallas kernel, so on a mesh it
+    runs under ``shard_map``: each model shard attends its own kv heads
+    and their query-head groups (q's heads are kv-group-major), and the
+    batch stays split over the data axes where the cache is per slot (a
+    paged pool is shared by all slots, so it is not)."""
+    if mi is None or mi.mesh is None:
+        return kernel
+    from jax.sharding import PartitionSpec as P
+
+    dp = (mi.data_axes or None) if batch_sharded else None
+    m = mi.model_axis if cfg.n_kv_heads % mi.ep_size == 0 else None
+    q_spec = P(dp, m, None)
+    kv_spec = P(dp, m, None, None)
+    if batch_sharded:  # (q, k, v, lengths)
+        rest = (P(dp),)
+    else:  # (q, pool_k, pool_v, block_tables, lengths)
+        rest = (P(None, None), P(None))
+    return jax.shard_map(
+        kernel, mesh=mi.mesh, in_specs=(q_spec, kv_spec, kv_spec) + rest,
+        out_specs=q_spec, check_vma=False,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -221,12 +246,14 @@ def gqa_prefill(
     q_chunk: int = 1024,
     kv_chunk: int = 1024,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Returns ``(y, k, v)`` with k/v in the head-major cache layout
+    ``(B, K, S, dh)`` (see :meth:`repro.models.model.LM.init_cache`)."""
     use_rope = cfg.mrope_sections is not None or positions is not None
     q, k, v = gqa_project_qkv(params, x, positions, cfg, mrope_positions, use_rope)
     o = flash_attention(q, k, v, causal=causal, q_chunk=q_chunk, kv_chunk=kv_chunk)
     B, S = x.shape[:2]
     y = o.reshape(B, S, -1) @ params["wo"]
-    return y, k, v
+    return y, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
 
 
 def gqa_decode(
@@ -238,24 +265,27 @@ def gqa_decode(
     cfg: AttnConfig,
     mrope_positions=None,
     use_rope: bool = True,
+    mi=None,  # MeshInfo: on a mesh the kernel runs per kv-head shard
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One decode step; returns output and the (k, v) row to insert."""
+    """One decode step over the head-major ``(B, K, T, dh)`` cache;
+    returns the output and the updated cache."""
     pos = position[:, None] if position is not None else None
     q, k1, v1 = gqa_project_qkv(params, x, pos, cfg, mrope_positions, use_rope)
     B = x.shape[0]
-    T = cache_k.shape[1]
-    # insert current kv at `position`
+    # insert current kv at `position`: the (K, 1, dh) row of each slot
     idx = position if position is not None else jnp.zeros((B,), jnp.int32)
-    cache_k = jax.vmap(lambda c, r, i: jax.lax.dynamic_update_slice(c, r, (i, 0, 0)))(
-        cache_k, k1, idx
-    )
-    cache_v = jax.vmap(lambda c, r, i: jax.lax.dynamic_update_slice(c, r, (i, 0, 0)))(
-        cache_v, v1, idx
-    )
+
+    def insert(c, r, i):
+        return jax.lax.dynamic_update_slice(c, r.astype(c.dtype), (0, i, 0))
+
+    cache_k = jax.vmap(insert)(cache_k, k1.transpose(0, 2, 1, 3), idx)
+    cache_v = jax.vmap(insert)(cache_v, v1.transpose(0, 2, 1, 3), idx)
     if _flash_decode_mode() == "kernel":
         from repro.kernels import ops as kernel_ops
 
-        o = kernel_ops.decode_attention(q[:, 0], cache_k, cache_v, idx + 1)
+        o = _per_head_shard(
+            kernel_ops.decode_attention, mi, cfg, batch_sharded=True
+        )(q[:, 0], cache_k, cache_v, idx + 1)
         o = o[:, None]
     else:
         # the dense einsum is both the XLA twin and the oracle here
@@ -271,25 +301,25 @@ def gqa_decode(
 
 def paged_decode_attention_ref(
     q: jax.Array,  # (B, 1, H, dh)
-    pool_k: jax.Array,  # (n_pool, page, Kv, dh)
-    pool_v: jax.Array,  # (n_pool, page, Kv, dh)
+    pool_k: jax.Array,  # (n_pool, Kv, page, dh) head-major
+    pool_v: jax.Array,  # (n_pool, Kv, page, dh)
     block_tables: jax.Array,  # (B, max_blocks) int32
     lengths: jax.Array,  # (B,)
 ) -> jax.Array:
     """Oracle: gather each slot's blocks into a dense cache, then run the
     dense reference."""
-    B = q.shape[0]
-    _, page, Kv, dh = pool_k.shape
-    nb = block_tables.shape[1]
-    k = pool_k[block_tables].reshape(B, nb * page, Kv, dh)
-    v = pool_v[block_tables].reshape(B, nb * page, Kv, dh)
-    return decode_attention_ref(q, k, v, lengths)
+    from repro.kernels.ref import gather_pages
+
+    return decode_attention_ref(
+        q, gather_pages(pool_k, block_tables), gather_pages(pool_v, block_tables),
+        lengths,
+    )
 
 
 def paged_decode_attention_xla(
     q: jax.Array,  # (B, 1, H, dh)
-    pool_k: jax.Array,  # (n_pool, page, Kv, dh)
-    pool_v: jax.Array,  # (n_pool, page, Kv, dh)
+    pool_k: jax.Array,  # (n_pool, Kv, page, dh) head-major
+    pool_v: jax.Array,  # (n_pool, Kv, page, dh)
     owner: jax.Array,  # (n_pool,) int32 slot owning each block, -1 free
     block_pos: jax.Array,  # (n_pool,) int32 logical index within owner
     lengths: jax.Array,  # (B,)
@@ -304,13 +334,13 @@ def paged_decode_attention_xla(
     non-TPU hosts.
     """
     B, _, H, dh = q.shape
-    n_pool, page, Kv, _ = pool_v.shape
+    n_pool, Kv, page, _ = pool_v.shape
     G = H // Kv
     qf = q.reshape(B, Kv, G, dh).astype(jnp.float32)
     own = jnp.clip(owner, 0, B - 1)
     qp = qf[own]  # (n_pool, Kv, G, dh) — free blocks get slot 0's q, masked
     s = jnp.einsum(
-        "pkgd,ptkd->pkgt", qp, pool_k.astype(jnp.float32)
+        "pkgd,pktd->pkgt", qp, pool_k.astype(jnp.float32)
     ) / jnp.sqrt(dh).astype(jnp.float32)
     pos = block_pos[:, None] * page + jnp.arange(page)[None, :]  # (n_pool, page)
     valid = (owner[:, None] >= 0) & (pos < lengths[own][:, None])
@@ -326,7 +356,7 @@ def paged_decode_attention_xla(
     )[seg]
     p = jnp.where(valid[:, None, None], jnp.exp(s - m_of_blk[..., None]), 0.0)
     l_blk = p.sum(axis=-1)  # (n_pool, Kv, G)
-    acc_blk = jnp.einsum("pkgt,ptkd->pkgd", p, pool_v.astype(jnp.float32))
+    acc_blk = jnp.einsum("pkgt,pktd->pkgd", p, pool_v.astype(jnp.float32))
     l_slot = jax.ops.segment_sum(l_blk, seg, num_segments=B + 1)[:B]
     acc = jax.ops.segment_sum(acc_blk, seg, num_segments=B + 1)[:B]
     out = acc / jnp.maximum(l_slot, 1e-30)[..., None]
@@ -337,12 +367,13 @@ def gqa_decode_paged(
     params: dict,
     x: jax.Array,  # (B, 1, d)
     position: jax.Array,  # (B,) current position
-    pool_k: jax.Array,  # (n_pool, page, Kv, dh)
-    pool_v: jax.Array,  # (n_pool, page, Kv, dh)
+    pool_k: jax.Array,  # (n_pool, Kv, page, dh) head-major
+    pool_v: jax.Array,  # (n_pool, Kv, page, dh)
     paged: Tuple[jax.Array, jax.Array, jax.Array],  # (block_tables, owner, block_pos)
     cfg: AttnConfig,
     mrope_positions=None,
     use_rope: bool = True,
+    mi=None,  # MeshInfo: on a mesh the kernel runs per kv-head shard
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One paged decode step: scatter the new KV row into the shared block
     pool through the slot's block table, then attend over the slot's
@@ -352,21 +383,22 @@ def gqa_decode_paged(
     pos = position[:, None]
     q, k1, v1 = gqa_project_qkv(params, x, pos, cfg, mrope_positions, use_rope)
     B = x.shape[0]
-    page = pool_k.shape[1]
+    page = pool_k.shape[2]
     phys = jnp.take_along_axis(
         block_tables, (position // page)[:, None], axis=1
     )[:, 0]
     off = position % page
-    pool_k = pool_k.at[phys, off].set(k1[:, 0].astype(pool_k.dtype))
-    pool_v = pool_v.at[phys, off].set(v1[:, 0].astype(pool_v.dtype))
+    # advanced indices split by the head slice: the update is (B, Kv, dh)
+    pool_k = pool_k.at[phys, :, off].set(k1[:, 0].astype(pool_k.dtype))
+    pool_v = pool_v.at[phys, :, off].set(v1[:, 0].astype(pool_v.dtype))
     lengths = position + 1
     mode = _flash_decode_mode()
     if mode == "kernel":
         from repro.kernels import ops as kernel_ops
 
-        o = kernel_ops.decode_attention_paged(
-            q[:, 0], pool_k, pool_v, block_tables, lengths
-        )
+        o = _per_head_shard(
+            kernel_ops.decode_attention_paged, mi, cfg, batch_sharded=False
+        )(q[:, 0], pool_k, pool_v, block_tables, lengths)
         o = o[:, None]
     elif mode == "xla":
         o = paged_decode_attention_xla(
@@ -381,25 +413,25 @@ def gqa_decode_paged(
 
 
 def quantize_kv_row(row: jax.Array):
-    """Per-(token, head) int8 quantization: row (B, 1, K, dh) -> (q, scale)."""
+    """Per-(token, head) int8 quantization: row (B, K, 1, dh) -> (q, scale)."""
     m = jnp.max(jnp.abs(row.astype(jnp.float32)), axis=-1, keepdims=True)
     scale = jnp.maximum(m, 1e-8) / 127.0
     q = jnp.clip(jnp.round(row.astype(jnp.float32) / scale), -127, 127).astype(
         jnp.int8
     )
-    return q, scale[..., 0]  # (B, 1, K, dh) int8, (B, 1, K) f32
+    return q, scale[..., 0]  # (B, K, 1, dh) int8, (B, K, 1) f32
 
 
 def gqa_decode_seqpar(
     params: dict,
     x: jax.Array,  # (B, 1, d)
     position: jax.Array,  # (B,)
-    cache_k: jax.Array,  # (B, T, K, dh) — T sharded over the model axis
+    cache_k: jax.Array,  # (B, K, T, dh) — T sharded over the model axis
     cache_v: jax.Array,
     cfg: AttnConfig,
     mi,  # MeshInfo
     use_rope: bool = True,
-    kv_scales=None,  # (k_scale, v_scale) (B, T, K) f32 — int8 KV mode
+    kv_scales=None,  # (k_scale, v_scale) (B, K, T) f32 — int8 KV mode
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Sequence-parallel decode attention (§Perf iteration A).
 
@@ -418,6 +450,7 @@ def gqa_decode_seqpar(
     pos1 = position[:, None]
     q, k1, v1 = gqa_project_qkv(params, x, pos1 if use_rope else None, cfg,
                                 None, use_rope)
+    k1, v1 = k1.transpose(0, 2, 1, 3), v1.transpose(0, 2, 1, 3)  # (B, K, 1, dh)
     B = x.shape[0]
     axis = mi.model_axis
     dp = mi.data_axes if mi.data_axes else None
@@ -432,15 +465,15 @@ def gqa_decode_seqpar(
         ksc = vsc = jnp.zeros(cache_k.shape[:3], jnp.float32)
 
     def body(q_, k1_, v1_, k1s_, v1s_, ck, cv, cks, cvs, pos):
-        # per-shard: ck/cv (B_loc, T_loc, K, dh); q_ (B_loc, 1, H, dh)
-        T_loc = ck.shape[1]
+        # per-shard: ck/cv (B_loc, K, T_loc, dh); q_ (B_loc, 1, H, dh)
+        T_loc = ck.shape[2]
         shard = jax.lax.axis_index(axis)
         local = pos - shard * T_loc
         own = (local >= 0) & (local < T_loc)
         idx = jnp.clip(local, 0, T_loc - 1)
 
         def upd(c, row, i, o):
-            new = jax.lax.dynamic_update_slice(c, row, (i,) + (0,) * (c.ndim - 1))
+            new = jax.lax.dynamic_update_slice(c, row, (0, i) + (0,) * (c.ndim - 2))
             return jnp.where(o, new, c)
 
         ck = jax.vmap(upd)(ck, k1_, idx, own)
@@ -450,13 +483,13 @@ def gqa_decode_seqpar(
             cvs = jax.vmap(upd)(cvs, v1s_, idx, own)
 
         # partial attention over the local slice
-        K_, dh = ck.shape[2], ck.shape[3]
+        K_, dh = ck.shape[1], ck.shape[3]
         H = q_.shape[2]
         G = H // K_
         qf = q_.reshape(-1, K_, G, dh).astype(jnp.float32)
-        s = jnp.einsum("bkgd,btkd->bkgt", qf, ck.astype(jnp.float32))
+        s = jnp.einsum("bkgd,bktd->bkgt", qf, ck.astype(jnp.float32))
         if int8_kv:  # fold the per-(token,head) dequant scales in
-            s = s * cks.transpose(0, 2, 1)[:, :, None, :]
+            s = s * cks[:, :, None, :]
         s = s / jnp.sqrt(dh)
         gpos = shard * T_loc + jnp.arange(T_loc)  # global positions
         mask = gpos[None, :] <= pos[:, None]
@@ -464,11 +497,11 @@ def gqa_decode_seqpar(
         m = s.max(-1)  # (B, K, G)
         p = jnp.exp(s - m[..., None])
         if int8_kv:
-            pv = p * cvs.transpose(0, 2, 1)[:, :, None, :]
+            pv = p * cvs[:, :, None, :]
         else:
             pv = p
         l = p.sum(-1)
-        acc = jnp.einsum("bkgt,btkd->bkgd", pv, cv.astype(jnp.float32))
+        acc = jnp.einsum("bkgt,bktd->bkgd", pv, cv.astype(jnp.float32))
         # merge partials across shards (numerically exact flash merge)
         m_all = jax.lax.pmax(m, axis)
         corr = jnp.exp(m - m_all)
@@ -486,18 +519,18 @@ def gqa_decode_seqpar(
             P(dp, None, None, None),
             P(dp, None, None),
             P(dp, None, None),
-            P(dp, axis, None, None),
-            P(dp, axis, None, None),
-            P(dp, axis, None),
-            P(dp, axis, None),
+            P(dp, None, axis, None),
+            P(dp, None, axis, None),
+            P(dp, None, axis),
+            P(dp, None, axis),
             P(dp),
         ),
         out_specs=(
             P(dp, None, None),
-            P(dp, axis, None, None),
-            P(dp, axis, None, None),
-            P(dp, axis, None),
-            P(dp, axis, None),
+            P(dp, None, axis, None),
+            P(dp, None, axis, None),
+            P(dp, None, axis),
+            P(dp, None, axis),
         ),
     )
     y = o @ params["wo"]
@@ -507,8 +540,11 @@ def gqa_decode_seqpar(
 
 
 def _shard_map_attn(body, mi, args, in_specs, out_specs):
-    from .shard_compat import shard_map_unchecked as _sm
-    return _sm(body, mesh=mi.mesh, in_specs=in_specs, out_specs=out_specs)(*args)
+    # the cross-shard merge is manual psums the checker cannot verify
+    return jax.shard_map(
+        body, mesh=mi.mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )(*args)
 
 
 # ---------------------------------------------------------------------------

@@ -431,6 +431,12 @@ class LM:
     # ==================================================================
 
     def init_cache(self, batch: int, max_seq: int) -> Dict[str, Any]:
+        """Per-slot decode state.  Attention KV is head-major, ``(L, B,
+        n_kv_heads, max_seq, d_head)``: one kv head's ``(T, d_head)`` rows
+        are contiguous, so the decode-attention kernels stream
+        ``(block, d_head)`` tiles that match the TPU's (sublane, lane)
+        tiling.  Every reader (prefill insert, the XLA twins, paged pools,
+        snapshots) follows this one layout."""
         arch, dtype = self.arch, self.dtype
         a = arch.attn
         # §Perf iteration A2: int8 KV cache (halves decode HBM traffic);
@@ -444,17 +450,15 @@ class LM:
         )
 
         def kv(n_layers):
+            shape = (n_layers, batch, a.n_kv_heads, max_seq, a.d_head)
             if kv_int8:
                 return (
-                    jnp.zeros((n_layers, batch, max_seq, a.n_kv_heads, a.d_head), jnp.int8),
-                    jnp.zeros((n_layers, batch, max_seq, a.n_kv_heads, a.d_head), jnp.int8),
-                    jnp.zeros((n_layers, batch, max_seq, a.n_kv_heads), jnp.float32),
-                    jnp.zeros((n_layers, batch, max_seq, a.n_kv_heads), jnp.float32),
+                    jnp.zeros(shape, jnp.int8),
+                    jnp.zeros(shape, jnp.int8),
+                    jnp.zeros(shape[:4], jnp.float32),
+                    jnp.zeros(shape[:4], jnp.float32),
                 )
-            return (
-                jnp.zeros((n_layers, batch, max_seq, a.n_kv_heads, a.d_head), dtype),
-                jnp.zeros((n_layers, batch, max_seq, a.n_kv_heads, a.d_head), dtype),
-            )
+            return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
         if arch.family in ("dense", "moe", "vlm"):
             n_prefix = arch.moe.first_k_dense if arch.moe is not None else 0
@@ -483,10 +487,7 @@ class LM:
             )(jnp.arange(nseg))
             c = {
                 "mamba_seg": seg_states,
-                "attn": (
-                    jnp.zeros((nseg, batch, max_seq, a.n_kv_heads, a.d_head), dtype),
-                    jnp.zeros((nseg, batch, max_seq, a.n_kv_heads, a.d_head), dtype),
-                ),
+                "attn": kv(nseg),
             }
             if tail:
                 c["mamba_tail"] = jax.vmap(
@@ -511,8 +512,9 @@ class LM:
         raise ValueError(arch.family)
 
     def init_paged_cache(self, n_pool: int, page: int) -> Dict[str, Any]:
-        """Paged KV cache: per-layer shared block pools ``(n_layers,
-        n_pool, page, Kv, dh)`` replacing the dense per-slot buffers.  The
+        """Paged KV cache: per-layer shared head-major block pools
+        ``(n_layers, n_pool, Kv, page, dh)`` replacing the dense per-slot
+        buffers.  The
         block table that maps (slot, logical block) → pool block lives
         host-side (``serving.batching.PagedKVCache``) and arrives with
         each decode batch; physical block 0 is the reserved trash block
@@ -531,8 +533,8 @@ class LM:
 
         def kv(n_layers):
             return (
-                jnp.zeros((n_layers, n_pool, page, a.n_kv_heads, a.d_head), dtype),
-                jnp.zeros((n_layers, n_pool, page, a.n_kv_heads, a.d_head), dtype),
+                jnp.zeros((n_layers, n_pool, a.n_kv_heads, page, a.d_head), dtype),
+                jnp.zeros((n_layers, n_pool, a.n_kv_heads, page, a.d_head), dtype),
             )
 
         n_prefix = arch.moe.first_k_dense if arch.moe is not None else 0
@@ -618,7 +620,7 @@ class LM:
         if arch.attn.n_kv_heads % mi.ep_size == 0:
             return False  # head-sharded cache path is already gather-free
         try:
-            T = cache["blocks"][0].shape[2]
+            T = cache["blocks"][0].shape[3]
             B = cache["blocks"][0].shape[1]
         except (KeyError, IndexError, AttributeError):
             return False

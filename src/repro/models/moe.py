@@ -40,9 +40,14 @@ from repro.core.scheduler_jax import (
 )
 from .layers import _he
 
-from .shard_compat import shard_map_unchecked as _shard_map
-
 from jax.sharding import PartitionSpec as P
+
+
+def _shard_map(f, *, mesh, in_specs, out_specs):
+    # the EP bodies do manual psums the replication checker cannot verify
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 class MeshInfo(NamedTuple):
@@ -920,6 +925,10 @@ def moe_block(
         dp_size = 1
         for a in mi.data_axes:
             dp_size *= mi.mesh.shape[a]
+        if (B * S) % dp_size:
+            # a prefill chunk of one slot may not split over the data
+            # shards: each data shard then routes every token itself
+            mi, dp_size = mi._replace(data_axes=()), 1
         use_a2a = (
             os.environ.get("REPRO_EP_MODE", "psum") == "a2a"
             and (B * S) % (dp_size * mi.ep_size) == 0

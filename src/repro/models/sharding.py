@@ -177,8 +177,10 @@ def cache_pspecs(
 ) -> Any:
     """Cache sharding: batch over data axes; heads over model if divisible.
 
-    Cache leaves are stacked (L, B, T, ...) [attn kv / mla] or pytrees of
-    SSM states (L, B, H, ...).
+    Cache leaves are stacked head-major attention KV (L, B, K, T, dh) with
+    int8 scales (L, B, K, T), MLA latents (L, B, T, c), whisper's
+    cross-attention KV (L, B, Se, H, dh), or pytrees of SSM states
+    (L, B, H, ...).
     """
     dp = data_axes if data_axes else None
 
@@ -187,25 +189,24 @@ def cache_pspecs(
         shape = leaf.shape
         nd = len(shape)
         is_ssm_state = any(s in name for s in ("wkv", "ssm", "conv", "x_tm", "x_cm", "mamba"))
-        if (
-            nd >= 5
-            and not is_ssm_state
-            and ("attn" in name or "self" in name or "cross" in name or "blocks" in name)
-        ):
-            # (L, B, T, K, dh): prefer head sharding (TP); when the kv head
+        div = lambda n: bool(model_axis) and n % max(model_size, 1) == 0
+        if nd == 5 and name.startswith("cross"):
+            # encoder KV (L, B, Se, H, dh): heads over the model axis
+            return P(None, dp, None, model_axis if div(shape[3]) else None, None)
+        is_kv = "attn" in name or "self" in name or "blocks" in name or "prefix" in name
+        if nd in (4, 5) and is_kv and not is_ssm_state and arch.attn.kind != "mla":
+            # (L, B, K, T[, dh]): prefer head sharding (TP); when the kv head
             # count doesn't divide the model axis (GQA kv=4/8 on 16-way TP),
             # shard the sequence dim instead — the cache then fits, at the
             # price of per-layer gather collectives (quantified in §Roofline
             # and attacked in §Perf with sequence-parallel decode attention).
-            kv_ok = model_axis and shape[3] % max(model_size, 1) == 0
-            if kv_ok:
-                return P(None, dp, None, model_axis, None)
-            t_ok = model_axis and shape[2] % max(model_size, 1) == 0
-            return P(None, dp, model_axis if t_ok else None, None, None)
+            tail = (None,) * (nd - 4)
+            if div(shape[2]):
+                return P(None, dp, model_axis, None, *tail)
+            return P(None, dp, None, model_axis if div(shape[3]) else None, *tail)
         if nd == 4 and "blocks" in name and not is_ssm_state:
             # MLA latent (L, B, T, c) — shard the sequence dim
-            t_ok = model_axis and shape[2] % max(model_size, 1) == 0
-            return P(None, dp, model_axis if t_ok else None, None)
+            return P(None, dp, model_axis if div(shape[2]) else None, None)
         # SSM states: (L, B, H, P, N) / (L, B, W, C) / (L, B, D) / rwkv wkv.
         # Zamba2's segment states carry two leading stack dims:
         # (nseg, per, B, ...).

@@ -124,7 +124,7 @@ def attn_mlp_block_decode(
     elif paged is not None:
         a, k, v = attn_lib.gqa_decode_paged(
             p["attn"], h, position, cache[0], cache[1], paged, arch.attn,
-            mrope_positions=mrope_positions,
+            mrope_positions=mrope_positions, mi=mi,
         )
         new_cache = (k, v)
     elif seq_par:
@@ -136,7 +136,7 @@ def attn_mlp_block_decode(
     else:
         a, k, v = attn_lib.gqa_decode(
             p["attn"], h, position, cache[0], cache[1], arch.attn,
-            mrope_positions=mrope_positions,
+            mrope_positions=mrope_positions, mi=mi,
         )
         new_cache = (k, v)
     x = x + a

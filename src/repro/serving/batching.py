@@ -46,8 +46,8 @@ class BatchingConfig:
 class PagedKVCache:
     """Host-side block-table allocator for the shared KV block pool.
 
-    The device side is a pair of ``(n_layers, n_pool, page, Kv, dh)``
-    pools (``LM.init_paged_cache``); this class owns the int32 indexing
+    The device side is a pair of head-major ``(n_layers, n_pool, Kv, page,
+    dh)`` pools (``LM.init_paged_cache``); this class owns the int32 indexing
     state shipped with each decode batch:
 
     * ``block_table`` (n_slots, max_blocks) — logical → physical block per

@@ -39,6 +39,7 @@ from repro.core.scheduler import schedule
 from repro.core.scheduler_jax import SieveState, make_sieve_state
 from repro.faults.health import HealthMonitor
 from repro.models.model import LM
+from repro.models.sharding import cache_pspecs, to_shardings
 from repro.sim.dram import PimGemvModel
 from repro.telemetry import StageProbes, Telemetry, TimingFeed
 from repro.telemetry import default as default_telemetry
@@ -67,6 +68,23 @@ _SENTINEL_PROBES = 3  # repeats per boundary; the mean damps OS jitter
 # feasible tail (GPU-only split) without any shape or dtype change — the
 # compiled decode step never retraces on a health transition
 _PIM_BLOCKED_TIME = 1e9
+
+
+def _placed_cache(lm: LM, make, per_slot: bool = True) -> Any:
+    """The cache ``make`` builds, created already sharded by
+    ``cache_pspecs`` when the model runs on a mesh (never gathered onto
+    one device first).  A paged pool (``per_slot=False``) is shared by
+    every slot, so its block dim stays whole on every data shard, as the
+    paged kernel's ``shard_map`` expects; only kv heads split."""
+    mi = lm.mi
+    if mi.mesh is None:
+        return make()
+    specs = cache_pspecs(
+        jax.eval_shape(make), lm.arch,
+        data_axes=mi.data_axes if per_slot else (),
+        model_axis=mi.model_axis, model_size=mi.ep_size,
+    )
+    return jax.jit(make, out_shardings=to_shardings(mi.mesh, specs))()
 
 
 @dataclass
@@ -141,9 +159,14 @@ class ServingEngine:
         self.paged: Optional[PagedKVCache] = None
         if batching.paged:
             self.paged = PagedKVCache(batching)
-            self.cache = lm.init_paged_cache(self.paged.n_pool, self.paged.page)
+            self.cache = _placed_cache(
+                lm, lambda: lm.init_paged_cache(self.paged.n_pool, self.paged.page),
+                per_slot=False,
+            )
         else:
-            self.cache = lm.init_cache(batching.n_slots, batching.max_seq)
+            self.cache = _placed_cache(
+                lm, lambda: lm.init_cache(batching.n_slots, batching.max_seq)
+            )
         # The KV cache is donated on both compiled steps (argnum 2): the
         # engine rebinds ``self.cache`` to the returned cache every call,
         # so the stale buffers would otherwise survive as full-cache
@@ -151,8 +174,10 @@ class ServingEngine:
         # donation XLA aliases cache-in to cache-out and the update is
         # in-place (pinned by tests/test_serving.py::TestBufferDonation).
         self._decode = jax.jit(lm.decode_step, donate_argnums=(2,))
+        # the slot is a traced int32, so prefill compiles once per prompt
+        # length, not once per (slot, prompt length) pair
         self._prefill_chunk = jax.jit(
-            self._prefill_chunk_impl, static_argnums=(3,), donate_argnums=(2,)
+            self._prefill_chunk_impl, donate_argnums=(2,)
         )
 
         # ---- Sieve runtime state (MoE archs only) ----
@@ -318,20 +343,18 @@ class ServingEngine:
                     leaf.delete()
 
     # ------------------------------------------------------------------
-    def _prefill_chunk_impl(self, params, batch, cache, slot: int):
-        """Prefill one request's chunk into its slot (B=1 path).
-
-        For simplicity the chunk is the whole prompt (chunked continuation
-        uses the same mechanism with q_offset bookkeeping at the engine
-        level)."""
+    def _prefill_chunk_impl(self, params, batch, cache, slot: jax.Array):
+        """Prefill one whole prompt into slot ``slot`` (B=1 path; ``slot``
+        is a traced int32 scalar)."""
         block_ids = batch.pop("block_ids", None)  # paged: slot's block-table row
         logits, req_cache, aux = self.lm.prefill(params, batch)
 
         if block_ids is None:
 
             def insert(slot_leaf, req_leaf):
-                # slot_leaf: (L, B_slots, T, ...); req_leaf: (L, 1, P, ...)
-                start = (0, slot, 0) + (0,) * (slot_leaf.ndim - 3)
+                # slot_leaf: (L, B_slots, ...); req_leaf: (L, 1, ...) with
+                # the prompt's P rows where the slot holds max_seq
+                start = (0, slot) + (0,) * (slot_leaf.ndim - 2)
                 return jax.lax.dynamic_update_slice(
                     slot_leaf, req_leaf.astype(slot_leaf.dtype), start
                 )
@@ -340,19 +363,16 @@ class ServingEngine:
             page = self.paged.page
 
             def insert(pool_leaf, req_leaf):
-                # pool_leaf: (L, n_pool, page, ...); req_leaf: (L, 1, P, ...)
-                # pad the prompt's KV rows to whole pages and scatter them
-                # over the slot's allocated blocks (nbp is trace-static:
-                # the prompt length is already a jit key for prefill)
-                L, _, P = req_leaf.shape[:3]
+                # pool_leaf: (L, n_pool, Kv, page, dh); req_leaf:
+                # (L, 1, Kv, P, dh).  Pad the prompt's KV rows to whole
+                # pages and scatter them over the slot's allocated blocks
+                # (nbp is trace-static: the prompt length is a jit key)
+                L, _, Kv, P, dh = req_leaf.shape
                 nbp = -(-P // page)
-                rows = req_leaf[:, 0]
-                pad = nbp * page - P
-                if pad:
-                    rows = jnp.pad(
-                        rows, ((0, 0), (0, pad)) + ((0, 0),) * (rows.ndim - 2)
-                    )
-                pages = rows.reshape((L, nbp, page) + rows.shape[2:])
+                rows = jnp.pad(
+                    req_leaf[:, 0], ((0, 0), (0, 0), (0, nbp * page - P), (0, 0))
+                )
+                pages = rows.reshape(L, Kv, nbp, page, dh).transpose(0, 2, 1, 3, 4)
                 return pool_leaf.at[:, block_ids[:nbp]].set(
                     pages.astype(pool_leaf.dtype)
                 )
@@ -596,7 +616,7 @@ class ServingEngine:
                 batch["mrope_positions"] = jnp.stack([pos, pos, pos])
             with tel.span("engine/prefill", value=float(len(req.prompt))):
                 logits, self.cache, p_aux = self._prefill_chunk(
-                    self.params, batch, self.cache, req.slot
+                    self.params, batch, self.cache, np.int32(req.slot)
                 )
                 logits = np.asarray(logits)
             if self.is_moe:

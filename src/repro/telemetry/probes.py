@@ -244,10 +244,10 @@ class StageProbes:
                 r.standard_normal((B, n_heads, d_head)), jnp.float32
             )
             ck = jnp.asarray(
-                r.standard_normal((B, S, n_kv, d_head)), jnp.float32
+                r.standard_normal((B, n_kv, S, d_head)), jnp.float32
             )
             cv = jnp.asarray(
-                r.standard_normal((B, S, n_kv, d_head)), jnp.float32
+                r.standard_normal((B, n_kv, S, d_head)), jnp.float32
             )
             lens = jnp.full((B,), S, jnp.int32)
             return ref.decode_attention_ref, (q, ck, cv, lens)

@@ -225,8 +225,8 @@ class TestDecodeAttention:
     def test_against_oracle(self, dtype, B, H, Kv, dh, T, bt):
         ks = jax.random.split(jax.random.PRNGKey(3), 4)
         q = jax.random.normal(ks[0], (B, H, dh), dtype)
-        ck = jax.random.normal(ks[1], (B, T, Kv, dh), dtype)
-        cv = jax.random.normal(ks[2], (B, T, Kv, dh), dtype)
+        ck = jax.random.normal(ks[1], (B, Kv, T, dh), dtype)
+        cv = jax.random.normal(ks[2], (B, Kv, T, dh), dtype)
         lens = jax.random.randint(ks[3], (B,), 1, T + 1)
         out = ops.decode_attention(q, ck, cv, lens, bt=bt, interpret=True)
         exp = ref.decode_attention_ref(q, ck, cv, lens)
@@ -241,12 +241,12 @@ class TestDecodeAttention:
         B, H, Kv, dh, T = 1, 4, 2, 16, 32
         ks = jax.random.split(jax.random.PRNGKey(4), 3)
         q = jax.random.normal(ks[0], (B, H, dh))
-        ck = jax.random.normal(ks[1], (B, T, Kv, dh))
-        cv = jax.random.normal(ks[2], (B, T, Kv, dh))
+        ck = jax.random.normal(ks[1], (B, Kv, T, dh))
+        cv = jax.random.normal(ks[2], (B, Kv, T, dh))
         lens = jnp.array([7])
         out1 = ops.decode_attention(q, ck, cv, lens, bt=8, interpret=True)
-        ck2 = ck.at[:, 7:].set(99.0)
-        cv2 = cv.at[:, 7:].set(-99.0)
+        ck2 = ck.at[:, :, 7:].set(99.0)
+        cv2 = cv.at[:, :, 7:].set(-99.0)
         out2 = ops.decode_attention(q, ck2, cv2, lens, bt=8, interpret=True)
         np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), rtol=1e-6)
 
@@ -258,8 +258,8 @@ class TestDecodeAttention:
         B, H, Kv, dh, T = 3, 4, 2, 16, 32
         ks = jax.random.split(jax.random.PRNGKey(5), 3)
         q = jax.random.normal(ks[0], (B, H, dh))
-        ck = jax.random.normal(ks[1], (B, T, Kv, dh))
-        cv = jax.random.normal(ks[2], (B, T, Kv, dh))
+        ck = jax.random.normal(ks[1], (B, Kv, T, dh))
+        cv = jax.random.normal(ks[2], (B, Kv, T, dh))
         # poison the padding-slot rows with extreme values
         ck = ck.at[0].set(1e4)
         cv = cv.at[0].set(-1e4)
@@ -284,8 +284,8 @@ class TestDecodeAttention:
         B, H, Kv, dh = 2, 8, 2, 16
         ks = jax.random.split(jax.random.PRNGKey(6), 4)
         q = jax.random.normal(ks[0], (B, H, dh))
-        ck = jax.random.normal(ks[1], (B, T, Kv, dh))
-        cv = jax.random.normal(ks[2], (B, T, Kv, dh))
+        ck = jax.random.normal(ks[1], (B, Kv, T, dh))
+        cv = jax.random.normal(ks[2], (B, Kv, T, dh))
         lens = jnp.array([T, T - 3])  # lengths reaching into the ragged tail
         out = ops.decode_attention(q, ck, cv, lens, bt=bt, interpret=True)
         exp = ref.decode_attention_ref(q, ck, cv, lens)
@@ -301,8 +301,8 @@ class TestDecodeAttention:
         B, H, Kv, dh, T = 4, 8, 4, 32, 96
         ks = jax.random.split(jax.random.PRNGKey(7), 4)
         q = jax.random.normal(ks[0], (B, H, dh))
-        ck = jax.random.normal(ks[1], (B, T, Kv, dh))
-        cv = jax.random.normal(ks[2], (B, T, Kv, dh))
+        ck = jax.random.normal(ks[1], (B, Kv, T, dh))
+        cv = jax.random.normal(ks[2], (B, Kv, T, dh))
         lens = jnp.array([1, 17, 64, 96])
         out = ops.decode_attention(
             q, ck, cv, lens, bt=16, n_splits=n_splits, interpret=True
@@ -319,8 +319,8 @@ class TestDecodeAttentionPaged:
         leaving block 0 as trash and poisoning free blocks."""
         n_pool = B * nb + 1
         ks = jax.random.split(key, 3)
-        pool_k = jax.random.normal(ks[0], (n_pool, page, Kv, dh))
-        pool_v = jax.random.normal(ks[1], (n_pool, page, Kv, dh))
+        pool_k = jax.random.normal(ks[0], (n_pool, Kv, page, dh))
+        pool_v = jax.random.normal(ks[1], (n_pool, Kv, page, dh))
         order = np.asarray(
             jax.random.permutation(ks[2], np.arange(1, n_pool))
         )
@@ -350,8 +350,8 @@ class TestDecodeAttentionPaged:
         np.testing.assert_allclose(out[1:], exp[1:], rtol=1e-4, atol=1e-4)
         np.testing.assert_array_equal(out[0], np.zeros_like(out[0]))
         # and the dense kernel agrees on the gathered cache
-        ck = pool_k[tab].reshape(B, nb * page, Kv, dh)
-        cv = pool_v[tab].reshape(B, nb * page, Kv, dh)
+        ck = ref.gather_pages(pool_k, tab)
+        cv = ref.gather_pages(pool_v, tab)
         dense = np.asarray(
             ops.decode_attention(q, ck, cv, lens, bt=page, interpret=True)
         )

@@ -51,8 +51,8 @@ class TestPagedAttentionTwin:
     def _pool(self, seed, B, nb, page, Kv, dh, lens):
         n_pool = B * nb + 1
         ks = jax.random.split(jax.random.PRNGKey(seed), 2)
-        pool_k = jax.random.normal(ks[0], (n_pool, page, Kv, dh))
-        pool_v = jax.random.normal(ks[1], (n_pool, page, Kv, dh))
+        pool_k = jax.random.normal(ks[0], (n_pool, Kv, page, dh))
+        pool_v = jax.random.normal(ks[1], (n_pool, Kv, page, dh))
         tab = np.zeros((B, nb), np.int32)
         owner = np.full((n_pool,), -1, np.int32)
         bpos = np.zeros((n_pool,), np.int32)

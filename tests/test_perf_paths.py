@@ -74,8 +74,8 @@ p = init_gqa(jax.random.PRNGKey(0), cfg, 64, jnp.float32)
 B, T = 4, 32
 ks = jax.random.split(jax.random.PRNGKey(1), 3)
 x = jax.random.normal(ks[0], (B, 1, 64))
-ck = jax.random.normal(ks[1], (B, T, 2, 16))
-cv = jax.random.normal(ks[2], (B, T, 2, 16))
+ck = jax.random.normal(ks[1], (B, 2, T, 16))  # head-major
+cv = jax.random.normal(ks[2], (B, 2, T, 16))
 pos = jnp.array([5, 0, 31, 17], jnp.int32)
 y_ref, ck_ref, cv_ref = gqa_decode(p, x, pos, ck, cv, cfg)
 from repro.launch.mesh import make_mesh, use_mesh
@@ -111,9 +111,9 @@ x = jax.random.normal(jax.random.PRNGKey(1), (B, 1, 64))
 from repro.launch.mesh import make_mesh, use_mesh
 mesh = make_mesh((2, 4), ("data", "model"))
 mi = MeshInfo(mesh=mesh, data_axes=("data",), model_axis="model")
-ck = jnp.zeros((B, T, 2, 16)); cv = jnp.zeros((B, T, 2, 16))
-ck8 = jnp.zeros((B, T, 2, 16), jnp.int8); cv8 = jnp.zeros((B, T, 2, 16), jnp.int8)
-ks8 = jnp.zeros((B, T, 2)); vs8 = jnp.zeros((B, T, 2))
+ck = jnp.zeros((B, 2, T, 16)); cv = jnp.zeros((B, 2, T, 16))  # head-major
+ck8 = jnp.zeros((B, 2, T, 16), jnp.int8); cv8 = jnp.zeros((B, 2, T, 16), jnp.int8)
+ks8 = jnp.zeros((B, 2, T)); vs8 = jnp.zeros((B, 2, T))
 with use_mesh(mesh):
     f_ref = jax.jit(lambda *a: gqa_decode_seqpar(p, a[0], a[1], a[2], a[3], cfg, mi))
     f_q = jax.jit(lambda *a: gqa_decode_seqpar(p, a[0], a[1], a[2], a[3], cfg, mi, kv_scales=(a[4], a[5])))

@@ -97,7 +97,7 @@ class TestEngine:
         logits, cache_pf, _ = jax.jit(lm.prefill)(p, {"tokens": jnp.asarray([prompt])})
         cache = lm.init_cache(2, 64)  # engine slots/max_seq
         cache = jax.tree.map(
-            lambda big, small: big.at[:, :1, : small.shape[2]].set(
+            lambda big, small: big.at[:, :1, :, : small.shape[3]].set(
                 small.astype(big.dtype)
             ),
             cache,
@@ -190,6 +190,16 @@ class TestEngine:
         assert all(
             leaf.is_deleted() for leaf in jax.tree.leaves(stale)
         )
+
+    def test_prefill_compiles_once_per_prompt_length(self):
+        """The slot is a traced argument: prompts of one length admitted
+        into different slots share one prefill executable."""
+        eng = make_engine(n_slots=2)
+        for r in reqs(2):
+            eng.submit(r)
+        eng.step()
+        assert sorted(r.slot for r in eng.sched.active) == [0, 1]
+        assert eng._prefill_chunk._cache_size() == 1
 
     def test_throughput_accounting(self):
         eng = make_engine()

@@ -1,0 +1,151 @@
+"""Compile the main path's Pallas kernels, and one decode step, for a
+described TPU v5e at qwen3-moe-30b-a3b widths.
+
+Nothing runs: the TPU compiler, installed with jax, compiles for a chip
+that is described and not attached, and refuses what the chip would
+refuse (tiling, VMEM, memory).  The topology is described inside a
+fixture, so only the worker that runs this file loads the TPU library.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_arch
+from repro.kernels import ops
+from repro.launch.serve import cut_depth
+from repro.models import LM, attention, moe
+
+ARCH = get_arch("qwen3-moe-30b-a3b")
+E, D, F = ARCH.moe.n_experts, ARCH.d_model, ARCH.moe.d_expert
+H, KV, DH = ARCH.attn.n_heads, ARCH.attn.n_kv_heads, ARCH.attn.d_head
+SLOTS, MAX_SEQ, PAGE = 32, 2048, 64
+BF, I32 = jnp.bfloat16, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A described-chip executable can be written to the persistent cache
+    but not read back without a chip; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+def _kernel_case(name, sds):
+    """(function, abstract args) for one kernel at qwen3 widths."""
+    w_in, w_out = sds((E, D, F)), sds((E, F, D))
+    toks, eids = sds((E, D)), sds((E,), I32)
+    q, lens = sds((SLOTS, H, DH)), sds((SLOTS,), I32)
+    cache = sds((SLOTS, KV, MAX_SEQ, DH))
+    n_pool = SLOTS * MAX_SEQ // PAGE + 1
+    pool = sds((n_pool, KV, PAGE, DH))
+    return {
+        "swiglu_gmm_capacity": (
+            lambda b, g, u, d, s: ops.swiglu_gmm_capacity(
+                b, g, u, d, s, interpret=False
+            ),
+            (sds((E, 8, D)), w_in, w_in, w_out, sds((E,), I32)),
+        ),
+        "swiglu_gemv": (
+            lambda t, g, u, d, e, v: ops.swiglu_gemv(
+                t, g, u, d, e, v, interpret=False
+            ),
+            (toks, w_in, w_in, w_out, eids, eids),
+        ),
+        "expert_gemv": (
+            lambda t, w, e, v: ops.expert_gemv(t, w, e, v, interpret=False),
+            (toks, w_in, eids, eids),
+        ),
+        "decode_attention": (
+            lambda q, k, v, n: ops.decode_attention(q, k, v, n, interpret=False),
+            (q, cache, cache, lens),
+        ),
+        "decode_attention_split4": (
+            lambda q, k, v, n: ops.decode_attention(
+                q, k, v, n, n_splits=4, interpret=False
+            ),
+            (q, cache, cache, lens),
+        ),
+        "decode_attention_paged": (
+            lambda q, k, v, t, n: ops.decode_attention_paged(
+                q, k, v, t, n, interpret=False
+            ),
+            (q, pool, pool, sds((SLOTS, MAX_SEQ // PAGE), I32), lens),
+        ),
+    }[name]
+
+
+@pytest.mark.parametrize(
+    "name",
+    [
+        "swiglu_gmm_capacity",
+        "swiglu_gemv",
+        "expert_gemv",
+        "decode_attention",
+        "decode_attention_split4",
+        "decode_attention_paged",
+    ],
+)
+def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache):
+    def sds(shape, dtype=BF):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, args = _kernel_case(name, sds)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_qwen3_decode_step_compiles_for_v5e(
+    one_chip, no_compile_cache, monkeypatch
+):
+    """One full-width layer of the served decode step, steered onto the
+    Pallas path the chip selects (this host would pick the XLA twins)."""
+    monkeypatch.setattr(moe, "_dual_backend", lambda: "pallas")
+    monkeypatch.setattr(attention, "_flash_decode_mode", lambda: "kernel")
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    lm = LM(cut_depth(ARCH, 1), dtype=BF)
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree,
+        )
+
+    params = placed(lm.abstract_params())
+    cache = placed(jax.eval_shape(lambda: lm.init_cache(SLOTS, MAX_SEQ)))
+    batch = placed({
+        "tokens": jax.ShapeDtypeStruct((SLOTS, 1), I32),
+        "position": jax.ShapeDtypeStruct((SLOTS,), I32),
+    })
+    compiled = (
+        jax.jit(lm.decode_step, donate_argnums=(2,))
+        .lower(params, batch, cache)
+        .compile()
+    )
+    text = compiled.as_text()
+    # head grouped GEMM, tail GEMV and decode attention all compiled in
+    assert text.count('custom_call_target="tpu_custom_call"') >= 3
