@@ -236,6 +236,7 @@ def gqa_project_qkv(
     return q, k, v
 
 
+@jax.named_scope("attention")
 def gqa_prefill(
     params: dict,
     x: jax.Array,
@@ -256,6 +257,7 @@ def gqa_prefill(
     return y, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
 
 
+@jax.named_scope("attention")
 def gqa_decode(
     params: dict,
     x: jax.Array,  # (B, 1, d)
@@ -363,6 +365,7 @@ def paged_decode_attention_xla(
     return out.reshape(B, 1, H, dh).astype(q.dtype)
 
 
+@jax.named_scope("attention")
 def gqa_decode_paged(
     params: dict,
     x: jax.Array,  # (B, 1, d)
@@ -422,6 +425,7 @@ def quantize_kv_row(row: jax.Array):
     return q, scale[..., 0]  # (B, K, 1, dh) int8, (B, K, 1) f32
 
 
+@jax.named_scope("attention")
 def gqa_decode_seqpar(
     params: dict,
     x: jax.Array,  # (B, 1, d)
@@ -552,6 +556,7 @@ def _shard_map_attn(body, mi, args, in_specs, out_specs):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("attention")
 def mla_prefill(
     params: dict,
     x: jax.Array,  # (B, S, d)
@@ -586,6 +591,7 @@ def mla_prefill(
     return y, c_kv, k_rope
 
 
+@jax.named_scope("attention")
 def mla_decode(
     params: dict,
     x: jax.Array,  # (B, 1, d)
@@ -652,6 +658,7 @@ def _divisor_chunk(n: int, target: int) -> int:
     return max(c, 1)
 
 
+@jax.named_scope("attention")
 def cross_attention(
     params: dict,
     x: jax.Array,  # (B, Sq, d)

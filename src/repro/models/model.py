@@ -183,6 +183,7 @@ class LM:
             x = embed(p["embed"], batch["tokens"])
         return x, mrope
 
+    @jax.named_scope("lm_head")
     def _logits(self, p, h) -> jax.Array:
         w = p.get("w_out")
         logits = (h @ p["embed"].T) if w is None else (h @ w)

@@ -123,6 +123,7 @@ class RouterOut(NamedTuple):
     counts: jax.Array  # (E,) int32 token count per expert
 
 
+@jax.named_scope("moe/router")
 def route(x: jax.Array, w_router: jax.Array, cfg: MoEConfig) -> RouterOut:
     """Top-k routing with renormalized weights + load-balance aux loss."""
     T = x.shape[0]
@@ -168,6 +169,7 @@ def capacity(T: int, cfg: MoEConfig, n_experts: int) -> int:
 _COUNTING_DISPATCH_MAX_ELEMS = 4_000_000
 
 
+@jax.named_scope("moe/dispatch")
 def dispatch(
     x: jax.Array,  # (T, d)
     r: RouterOut,
@@ -289,6 +291,7 @@ def dispatch_argsort(
     return Dispatched(buf, slot_of, n_dropped)
 
 
+@jax.named_scope("moe/combine")
 def combine(
     y_buf: jax.Array,  # (E, C, d)
     slot_of: jax.Array,  # (T, k)
@@ -585,41 +588,44 @@ def experts_ffn_dual(
     E, C, d = buf.shape
     tau = int(min(max(cfg.dual_tail_tokens, 0), C))
     H = cfg.dual_max_head if 0 < cfg.dual_max_head < E else E
-    split = _dual_split(rows, cfg, tau, (H if H < E else None), sieve)
+    with jax.named_scope("moe/dispatch"):
+        split = _dual_split(rows, cfg, tau, (H if H < E else None), sieve)
     head_sizes_full = jnp.where(split["head_mask"], rows, 0).astype(jnp.int32)
 
     wg, wu, wd = params["w_gate"], params["w_up"], params["w_down"]
-    if H < E:
-        # compact: gather the H most popular experts' slabs and weights
-        hid = split["order"][:H]
-        slab = buf[hid]
-        head_sizes = head_sizes_full[hid]
-        wgh, wuh, wdh = wg[hid], wu[hid], wd[hid]
-    else:
-        slab, head_sizes = buf, head_sizes_full
-        wgh, wuh, wdh = wg, wu, wd
+    with jax.named_scope("moe/head"):
+        if H < E:
+            # compact: gather the H most popular experts' slabs and weights
+            hid = split["order"][:H]
+            slab = buf[hid]
+            head_sizes = head_sizes_full[hid]
+            wgh, wuh, wdh = wg[hid], wu[hid], wd[hid]
+        else:
+            slab, head_sizes = buf, head_sizes_full
+            wgh, wuh, wdh = wg, wu, wd
 
-    if backend == "pallas":
-        y_head = _swiglu_grouped_pallas(slab, wgh, wuh, wdh, head_sizes)
-    else:
-        y_head = _swiglu_grouped_xla(slab, wgh, wuh, wdh, head_sizes)
-    if H < E:
-        y = jnp.zeros((E, C, d), y_head.dtype).at[hid].set(y_head)
-    else:
-        y = y_head
+        if backend == "pallas":
+            y_head = _swiglu_grouped_pallas(slab, wgh, wuh, wdh, head_sizes)
+        else:
+            y_head = _swiglu_grouped_xla(slab, wgh, wuh, wdh, head_sizes)
+        if H < E:
+            y = jnp.zeros((E, C, d), y_head.dtype).at[hid].set(y_head)
+        else:
+            y = y_head
 
     if tau > 0:
-        # tail slab: every expert's first tau capacity rows; rows of head
-        # experts / beyond the live count are masked invalid.
-        live = jnp.arange(tau, dtype=jnp.int32)[None, :] < jnp.minimum(
-            rows, tau
-        )[:, None]
-        valid = split["tail_mask"][:, None] & live
-        ty = _tail_path(
-            buf[:, :tau, :], wg, wu, wd,
-            jnp.arange(E, dtype=jnp.int32), valid, backend, gather_w=False,
-        )
-        y = y.at[:, :tau, :].add(ty.astype(y.dtype))
+        with jax.named_scope("moe/tail"):
+            # tail slab: every expert's first tau capacity rows; rows of
+            # head experts / beyond the live count are masked invalid.
+            live = jnp.arange(tau, dtype=jnp.int32)[None, :] < jnp.minimum(
+                rows, tau
+            )[:, None]
+            valid = split["tail_mask"][:, None] & live
+            ty = _tail_path(
+                buf[:, :tau, :], wg, wu, wd,
+                jnp.arange(E, dtype=jnp.int32), valid, backend, gather_w=False,
+            )
+            y = y.at[:, :tau, :].add(ty.astype(y.dtype))
 
     return y.astype(buf.dtype), split["n_dropped"]
 
@@ -666,47 +672,50 @@ def experts_ffn_dual_segmented(
         .set(1)
         .reshape(G)
     )
-    split = _dual_split(
-        rows_g, cfg, tau, (Hg if Hg < G else None), sieve,
-        weight_of_group=first_seg,
-    )
+    with jax.named_scope("moe/dispatch"):
+        split = _dual_split(
+            rows_g, cfg, tau, (Hg if Hg < G else None), sieve,
+            weight_of_group=first_seg,
+        )
     head_sizes_full = jnp.where(split["head_mask"], rows_g, 0).astype(jnp.int32)
 
     wg, wu, wd = params["w_gate"], params["w_up"], params["w_down"]
     slab_full = buf.reshape(G, C, d)
-    if Hg < G:
-        # compact: gather the Hg most popular segments' slabs; each keeps
-        # its expert's weight row through the rhs_of_group table
-        hid = split["order"][:Hg]
-        slab = slab_full[hid]
-        head_sizes = head_sizes_full[hid]
-        rhs = e_of_g[hid]
-    else:
-        slab, head_sizes, rhs = slab_full, head_sizes_full, e_of_g
+    with jax.named_scope("moe/head"):
+        if Hg < G:
+            # compact: gather the Hg most popular segments' slabs; each
+            # keeps its expert's weight row through the rhs_of_group table
+            hid = split["order"][:Hg]
+            slab = slab_full[hid]
+            head_sizes = head_sizes_full[hid]
+            rhs = e_of_g[hid]
+        else:
+            slab, head_sizes, rhs = slab_full, head_sizes_full, e_of_g
 
-    if backend == "pallas":
-        y_head = _swiglu_grouped_pallas(
-            slab, wg, wu, wd, head_sizes, rhs_of_group=rhs
-        )
-    else:
-        y_head = _swiglu_grouped_xla(
-            slab, wg, wu, wd, head_sizes, rhs_of_group=rhs
-        )
-    if Hg < G:
-        y = jnp.zeros((G, C, d), y_head.dtype).at[hid].set(y_head)
-    else:
-        y = y_head
+        if backend == "pallas":
+            y_head = _swiglu_grouped_pallas(
+                slab, wg, wu, wd, head_sizes, rhs_of_group=rhs
+            )
+        else:
+            y_head = _swiglu_grouped_xla(
+                slab, wg, wu, wd, head_sizes, rhs_of_group=rhs
+            )
+        if Hg < G:
+            y = jnp.zeros((G, C, d), y_head.dtype).at[hid].set(y_head)
+        else:
+            y = y_head
 
     if tau > 0:
-        live = jnp.arange(tau, dtype=jnp.int32)[None, :] < jnp.minimum(
-            rows_g, tau
-        )[:, None]
-        valid = split["tail_mask"][:, None] & live
-        ty = _tail_path(
-            slab_full[:, :tau, :], wg, wu, wd, e_of_g, valid, backend,
-            gather_w=True,
-        )
-        y = y.at[:, :tau, :].add(ty.astype(y.dtype))
+        with jax.named_scope("moe/tail"):
+            live = jnp.arange(tau, dtype=jnp.int32)[None, :] < jnp.minimum(
+                rows_g, tau
+            )[:, None]
+            valid = split["tail_mask"][:, None] & live
+            ty = _tail_path(
+                slab_full[:, :tau, :], wg, wu, wd, e_of_g, valid, backend,
+                gather_w=True,
+            )
+            y = y.at[:, :tau, :].add(ty.astype(y.dtype))
     return (
         y.reshape(E, S, C, d).astype(buf.dtype),
         split["n_dropped"],

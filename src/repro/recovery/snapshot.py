@@ -22,9 +22,8 @@ What makes a restore *bit-identical* (pinned by tests/test_recovery.py):
 * ``CostTable.version`` is restored verbatim (``load_state_dict`` alone
   bumps it), so the refresh cadence's version-skip logic fires at the
   same steps;
-* ``_jit_cache_seen`` and the TimingFeed telemetry cursor are *not*
-  restored — a fresh process has fresh jit caches and a fresh ring, and
-  restoring stale indices would miscount misses / skip events.
+* the TimingFeed telemetry cursor is *not* restored — a fresh process
+  has a fresh ring, and restoring a stale index would skip events.
 
 Corruption handling mirrors ``train.checkpoint``: every leaf and the
 state blob are verified against the manifest *before* any engine field is

@@ -215,7 +215,6 @@ class ServingEngine:
         self._last_head_counts: List[int] = []
         self._last_decode_batch = 0
         self._last_kv_depth = 1
-        self._jit_cache_seen = 0  # jit entries already counted as misses
         # per-layer metric names, built once (f-strings per step add up on
         # a ~5ms decode step)
         self._layer_metric_names: List[tuple] = []
@@ -426,8 +425,9 @@ class ServingEngine:
         if self.tel.enabled:
             self.tel.gauge("engine/brownout_stage", float(stage))
 
-    def _run_sieve(self, counts_per_layer: np.ndarray) -> None:
-        """Host-side scheduler pass over this step's per-layer counts."""
+    def _run_sieve(self, counts_per_layer: np.ndarray) -> List[List[int]]:
+        """Host-side scheduler pass over this step's per-layer counts;
+        returns each layer's head (grouped-GEMM) expert set."""
         kw = {}
         if self.policy in ("dual_threshold", "dual_cost"):
             # the host decision trail must evaluate the same feasibility
@@ -443,7 +443,7 @@ class ServingEngine:
             and self._timing_feed is not None
             and self._timing_feed.quarantined
         )
-        tel = self.tel
+        heads = []
         for li, counts in enumerate(counts_per_layer):
             part = schedule(
                 self.policy, counts, self.cost_model, self.cost_table, **kw
@@ -481,24 +481,7 @@ class ServingEngine:
                         self.cost_table.update(
                             n, self._pim.expert_time(self.layer_spec, n)
                         )
-            if tel.enabled:
-                while len(self._layer_metric_names) <= li:
-                    j = len(self._layer_metric_names)
-                    self._layer_metric_names.append(
-                        (f"expert_tokens/layer{j}", f"head_mass/layer{j}")
-                    )
-                hist_name, mass_name = self._layer_metric_names[li]
-                routed = counts[counts > 0]
-                total = int(routed.sum())
-                tel.observe(hist_name, routed)
-                if total > 0:
-                    # bimodality gauge: fraction of routed mass on the
-                    # chosen head (grouped-GEMM) set at this step's split
-                    gpu = np.asarray(part.gpu_experts, dtype=np.int64)
-                    head_mass = (
-                        float(counts[gpu].sum()) / total if gpu.size else 0.0
-                    )
-                    tel.gauge(mass_name, head_mass)
+            heads.append(part.gpu_experts)
             self.stats.partitions.append(
                 {
                     "step": self.stats.steps,
@@ -508,6 +491,28 @@ class ServingEngine:
                     "t_total_est": part.t_total,
                 }
             )
+        return heads
+
+    def _layer_telemetry(self, counts_per_layer: np.ndarray, heads) -> None:
+        """Per-layer expert-token histograms and head-mass gauges of the
+        step's sieve pass."""
+        tel = self.tel
+        for li, (counts, head) in enumerate(zip(counts_per_layer, heads)):
+            while len(self._layer_metric_names) <= li:
+                j = len(self._layer_metric_names)
+                self._layer_metric_names.append(
+                    (f"expert_tokens/layer{j}", f"head_mass/layer{j}")
+                )
+            hist_name, mass_name = self._layer_metric_names[li]
+            routed = counts[counts > 0]
+            total = int(routed.sum())
+            tel.observe(hist_name, routed)
+            if total > 0:
+                # bimodality gauge: fraction of routed mass on the chosen
+                # head (grouped-GEMM) set at this step's split
+                gpu = np.asarray(head, dtype=np.int64)
+                head_mass = float(counts[gpu].sum()) / total if gpu.size else 0.0
+                tel.gauge(mass_name, head_mass)
 
     def _run_probes(self) -> None:
         """Refresh-cadence stage probes: measure the queued tail counts
@@ -631,6 +636,7 @@ class ServingEngine:
 
         # ---- decode ----
         batch_reqs = self.sched.decode_batch()
+        sieve_pass = None  # (counts, heads) for the step-end telemetry
         if batch_reqs:
             B = self.cfg.n_slots
             tokens = np.zeros((B, 1), np.int32)
@@ -659,20 +665,34 @@ class ServingEngine:
                 mp = jnp.asarray(position)[None, :, None]
                 db["mrope_positions"] = jnp.concatenate([mp, mp, mp], axis=0)
             with tel.span("engine/decode", value=float(len(batch_reqs))):
-                logits, self.cache, aux = self._decode(self.params, db, self.cache)
-                logits = np.asarray(logits)
-            toks = self._sample(logits[:, 0])
-            for r in batch_reqs:
-                r.generated.append(int(toks[r.slot]))
-                self.stats.decode_tokens += 1
+                with tel.span("engine/decode_launch"):
+                    logits, self.cache, aux = self._decode(
+                        self.params, db, self.cache
+                    )
+                if tel.enabled:
+                    # the device's end, apart from the copy that follows
+                    # (np.asarray makes the same sync untraced)
+                    with tel.span("engine/decode_wait"):
+                        jax.block_until_ready(logits)
+                with tel.span("engine/decode_logits"):
+                    logits = np.asarray(logits)
+            with tel.span("engine/decode_sample", value=float(logits.shape[0])):
+                toks = self._sample(logits[:, 0])
+                for r in batch_reqs:
+                    r.generated.append(int(toks[r.slot]))
+                    self.stats.decode_tokens += 1
             if self.is_moe:
-                self.stats.dropped_tokens += int(aux.dropped)
-                self.stats.routed_tokens += int(np.asarray(aux.counts).sum())
+                with tel.span("engine/aux_to_host"):
+                    dropped = int(aux.dropped)
+                    counts = np.asarray(aux.counts)
+                self.stats.dropped_tokens += dropped
+                self.stats.routed_tokens += int(counts.sum())
             self._last_decode_batch = len(batch_reqs)
             self._last_kv_depth = int(position.max()) + 1
-            if self.is_moe and aux.counts.shape[0] > 0:
+            if self.is_moe and counts.shape[0] > 0:
                 with tel.span("engine/sieve_host"):
-                    self._run_sieve(np.asarray(aux.counts))
+                    heads = self._run_sieve(counts)
+                sieve_pass = (counts, heads)
 
         # measured cost loop + cost-table refresh cadence: the in-graph
         # split only ever changes at these boundaries (stale-table
@@ -691,54 +711,51 @@ class ServingEngine:
                     or self.brownout_stage >= 2,
                 )
 
-        # KV-capacity cap: the next decode feed writes KV at
-        # ``r.position - 1``; once that reaches max_seq the dense
-        # dynamic_update_slice would clamp and silently overwrite the last
-        # entry (and the paged path would write past its last block) —
-        # finish the request loudly instead.
-        for r in self.sched.active:
-            if (
-                r.generated
-                and not r.done
-                and r.position - 1 >= self.cfg.max_seq
-            ):
-                r.truncated = True
-                self.stats.truncated_requests += 1
+        with tel.span("engine/retire"):
+            # KV-capacity cap: the next decode feed writes KV at
+            # ``r.position - 1``; once that reaches max_seq the dense
+            # dynamic_update_slice would clamp and silently overwrite the
+            # last entry (and the paged path would write past its last
+            # block) — finish the request loudly instead.
+            for r in self.sched.active:
+                if (
+                    r.generated
+                    and not r.done
+                    and r.position - 1 >= self.cfg.max_seq
+                ):
+                    r.truncated = True
+                    self.stats.truncated_requests += 1
 
-        done = self.sched.retire(time.perf_counter())
-        if self.paged is not None:
-            for r in done:
-                self.paged.free_slot(r.slot)
+            done = self.sched.retire(time.perf_counter())
+            if self.paged is not None:
+                for r in done:
+                    self.paged.free_slot(r.slot)
         # deadline-expired queue entries are terminal too — surface them
         # to the caller after the paged free loop (they never held a slot)
         done = expired + done
         self.stats.steps += 1
         self.stats.wall_time += time.perf_counter() - t0
         if tel.enabled:
-            # KV occupancy: fraction of the slot pool's cells holding live
-            # KV entries (sum of per-request write cursors / total cells)
-            occ = sum(r.position for r in self.sched.active) / float(
-                self.cfg.n_slots * self.cfg.max_seq
-            )
-            tel.gauge("engine/kv_occupancy", occ)
-            if self.paged is not None:
-                # fraction of allocatable pool blocks currently owned
+            with tel.span("engine/telemetry"):
+                if sieve_pass is not None:
+                    self._layer_telemetry(*sieve_pass)
+                # KV occupancy: fraction of the slot pool's cells holding
+                # live KV entries (sum of per-request write cursors / cells)
+                occ = sum(r.position for r in self.sched.active) / float(
+                    self.cfg.n_slots * self.cfg.max_seq
+                )
+                tel.gauge("engine/kv_occupancy", occ)
+                if self.paged is not None:
+                    # fraction of allocatable pool blocks currently owned
+                    tel.gauge(
+                        "engine/kv_pool_used",
+                        1.0 - self.paged.n_free / max(self.paged.n_pool - 1, 1),
+                    )
                 tel.gauge(
-                    "engine/kv_pool_used",
-                    1.0 - self.paged.n_free / max(self.paged.n_pool - 1, 1),
+                    "engine/batch_occupancy",
+                    len(batch_reqs) / max(self.cfg.n_slots, 1),
                 )
-            tel.gauge(
-                "engine/batch_occupancy",
-                len(batch_reqs) / max(self.cfg.n_slots, 1),
-            )
-            tel.gauge("engine/drop_rate", self.stats.drop_rate)
-            # jit-cache growth since last step = compile misses this step
-            n_entries = self._decode._cache_size() + self._prefill_chunk._cache_size()
-            if n_entries > self._jit_cache_seen:
-                tel.counter(
-                    "engine/jit_cache_miss", n_entries - self._jit_cache_seen
-                )
-                self._jit_cache_seen = n_entries
+                tel.gauge("engine/drop_rate", self.stats.drop_rate)
         step_span.__exit__(None, None, None)
         return done
 

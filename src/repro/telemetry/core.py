@@ -16,6 +16,21 @@ head/tail arithmetic-intensity disparity — is read from at runtime):
   sample point into the ring so the same signal renders as a Perfetto
   counter track (``repro.telemetry.export``).
 
+An enabled instance is bridged to JAX (imported lazily, so this module
+loads without it):
+
+* every span it times on its clock (:meth:`Telemetry.span`) also opens a
+  ``jax.profiler.TraceAnnotation`` of the same name, so the span lands on
+  the profiler's host plane, on the device trace's clock, whenever a
+  profiler session is active (:meth:`Telemetry.span_at`, simulated time,
+  is not bridged);
+* every program JAX builds (a backend compile, or a load from the
+  persistent compile cache) while one of its spans is open is recorded as
+  one span ``engine/compile/<fun_name>``, from the start of the program's
+  tracing to the end of its build, and counted in
+  ``engine/jit_cache_miss``.  One ``jax.monitoring`` listener per process
+  feeds every enabled instance.
+
 **Disabled mode is the default posture and is allocation-free on the hot
 path**: every public method early-returns, and :meth:`Telemetry.span`
 hands back one shared no-op context-manager singleton — no object is
@@ -29,6 +44,7 @@ from __future__ import annotations
 import math
 import os
 import time
+import weakref
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
@@ -54,28 +70,100 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
+# the compile record: one span per program built, and their count
+COMPILE_SPAN_PREFIX = "engine/compile/"
+COMPILE_COUNTER = "engine/jit_cache_miss"
+
+# jax.monitoring events of one program's build, in the order they fire:
+# tracing (once per traced function, nested ones first), lowering, then
+# the build itself, which covers a backend compile or a persistent-cache load
+_TRACE_EVENTS = (
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+)
+_BUILD_EVENT = "/jax/core/compile/backend_compile_duration"
+
+# set by the first enabled instance (see _attach)
+_annotation = None  # jax.profiler.TraceAnnotation
+_listening = False
+_recorders: "weakref.WeakSet[Telemetry]" = weakref.WeakSet()
+_pending_ns: Optional[int] = None  # perf_counter_ns start of the build under way
+
+
+def _attach(tel: "Telemetry") -> None:
+    """Bridge an enabled instance to JAX: its spans open profiler
+    annotations, and it receives the compile record.  The listener is
+    registered once per process, whatever the number of instances."""
+    global _annotation, _listening
+    _recorders.add(tel)
+    if _listening:
+        return
+    try:
+        import jax.monitoring
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return
+    _annotation = TraceAnnotation
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
+    _listening = True
+
+
+def _on_jax_event(event: str, duration_s: float, **kwargs) -> None:
+    """Fold JAX's per-phase compile events into one record per program:
+    a build starts where the earliest tracing since the last build did."""
+    global _pending_ns
+    if event != _BUILD_EVENT and event not in _TRACE_EVENTS:
+        return
+    now = time.perf_counter_ns()
+    start = now - int(duration_s * 1e9)
+    if _pending_ns is not None:
+        start = min(start, _pending_ns)
+    if event != _BUILD_EVENT:
+        _pending_ns = start
+        return
+    _pending_ns = None
+    name = COMPILE_SPAN_PREFIX + str(kwargs.get("fun_name", "?"))
+    for tel in list(_recorders):
+        if tel.enabled and tel._depth > 0:
+            tel._record_compile(name, now - start)
+
 
 class _Span:
-    """Live span: records (t_enter, duration) into the ring on exit."""
+    """Live span: records (t_enter, duration) into the ring on exit, and
+    spans the same interval with a profiler annotation."""
 
-    __slots__ = ("_tel", "_name_id", "_track_id", "_value", "_t0")
+    __slots__ = ("_tel", "_name", "_name_id", "_track_id", "_value", "_t0", "_ann")
 
-    def __init__(self, tel: "Telemetry", name_id: int, track_id: int, value: float):
+    def __init__(
+        self, tel: "Telemetry", name: str, name_id: int, track_id: int, value: float,
+    ):
         self._tel = tel
+        self._name = name
         self._name_id = name_id
         self._track_id = track_id
         self._value = value
+        self._ann = None
 
     def __enter__(self):
-        self._t0 = self._tel._clock()
+        if _annotation is not None:
+            self._ann = _annotation(self._name)
+            self._ann.__enter__()
+        tel = self._tel
+        self._t0 = tel._clock()
+        tel._depth += 1
+        if tel._depth == 1:
+            tel._open_t0 = self._t0
         return self
 
     def __exit__(self, exc_type, exc, tb):
         tel = self._tel
+        tel._depth -= 1
         tel._emit(
             KIND_SPAN, self._name_id, self._track_id,
             self._t0, tel._clock() - self._t0, self._value,
         )
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -156,6 +244,12 @@ class Telemetry:
         self._gauges: Dict[str, float] = {}
         self._hists: Dict[str, _Hist] = {}
         self._default_track = self._intern_track("main")
+        # spans open on the clock, and where the outermost one started:
+        # programs built meanwhile are this instance's to record
+        self._depth = 0
+        self._open_t0 = 0
+        if self.enabled:
+            _attach(self)
 
     # ---- interning -------------------------------------------------------
     def _intern(self, name: str) -> int:
@@ -220,7 +314,7 @@ class Telemetry:
         """
         if not self.enabled:
             return NULL_SPAN
-        return _Span(self, self._intern(name), self._intern_track(track), value)
+        return _Span(self, name, self._intern(name), self._intern_track(track), value)
 
     def span_at(
         self, name: str, t_start_s: float, dur_s: float,
@@ -245,6 +339,17 @@ class Telemetry:
             KIND_SPAN, self._intern(name), self._intern_track(track),
             int(t_start_s * 1e9), max(dur_ns, 0), value,
         )
+
+    def _record_compile(self, name: str, dur_ns: int) -> None:
+        """One program built while a span was open: a span ending now on
+        this instance's clock, begun no earlier than its outermost open
+        span, and one more in the compile counter."""
+        end = self._clock()
+        t0 = max(end - dur_ns, self._open_t0)
+        self._emit(
+            KIND_SPAN, self._intern(name), self._default_track, t0, end - t0, _NAN,
+        )
+        self.counter(COMPILE_COUNTER)
 
     def point(
         self, name: str, value: float,

@@ -557,13 +557,51 @@ class TestEngineTelemetry:
         for hist_name, mass_name in eng._layer_metric_names:
             assert "repro_" + hist_name.replace("/", "_") in snap
             assert 0.0 <= gauges[mass_name] <= 1.0
-        # every compile landed in the miss counter (decode compiles once;
-        # prefill compiles per static slot argument)
+        # the miss counter counts the programs built during the steps, one
+        # engine/compile/* span each: here the decode step and the one
+        # prompt length's prefill, every jit-cache entry the engine made
+        compiles = [e for e in tel.events() if e["kind"] == "span"
+                    and e["name"].startswith("engine/compile/")]
+        assert tel.counters()["engine/jit_cache_miss"] == float(len(compiles))
         n_entries = (
             eng._decode._cache_size() + eng._prefill_chunk._cache_size()
         )
-        assert tel.counters()["engine/jit_cache_miss"] == float(n_entries)
+        assert len(compiles) == n_entries == 2
         assert eng._decode._cache_size() == 1
+
+    def test_decode_step_host_tail_spans(self):
+        """The decode step's host work is split into spans inside
+        engine/step; the launch, wait and logits copy sit inside
+        engine/decode, whose boundaries are unchanged."""
+        tel = Telemetry(enabled=True)
+        eng = _moe_engine(telemetry=tel)
+        _run_requests(eng, n=2, max_new=4)
+        spans = [e for e in tel.events() if e["kind"] == "span"]
+
+        def of(name):
+            return [e for e in spans if e["name"] == name]
+
+        def within(inner, outer):
+            return all(
+                any(o["t0_ns"] <= i["t0_ns"]
+                    and i["t0_ns"] + i["dur_ns"] <= o["t0_ns"] + o["dur_ns"]
+                    for o in outer)
+                for i in inner
+            )
+
+        n_decode = len(of("engine/decode"))
+        assert n_decode > 0
+        for name in ("engine/decode_launch", "engine/decode_wait",
+                     "engine/decode_logits"):
+            assert len(of(name)) == n_decode
+            assert within(of(name), of("engine/decode"))
+        for name in ("engine/decode_sample", "engine/aux_to_host",
+                     "engine/sieve_host", "engine/retire", "engine/telemetry"):
+            assert of(name) and within(of(name), of("engine/step"))
+            assert not within(of(name), of("engine/decode"))
+        # the sample span carries the rows it sampled (every slot's)
+        assert {e["value"] for e in of("engine/decode_sample")} == {4.0}
+        assert len(of("engine/retire")) == len(of("engine/step"))
 
     def test_engine_off_telemetry_records_nothing(self):
         tel = Telemetry(enabled=False)
@@ -611,6 +649,138 @@ class TestEngineTelemetry:
         _run_requests(eng, n=2, max_new=6)
         assert eng._probes is None and eng._timing_feed is None
         assert eng.cost_table.version > 0  # proxy observations landed
+
+
+# ---------------------------------------------------------------------------
+# Bridge to JAX: profiler annotations and the compile record
+# ---------------------------------------------------------------------------
+
+
+def _compile_spans(tel):
+    return [e for e in tel.events() if e["kind"] == "span"
+            and e["name"].startswith("engine/compile/")]
+
+
+class TestProfilerBridge:
+    def test_engine_spans_on_the_profiler_host_plane(self, tmp_path):
+        """Under a profiler session every engine/* span is also a host
+        event of the .xplane.pb, nested in engine/step, on the clock of
+        the XLA operations the same trace records."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        eng = _moe_engine(telemetry=Telemetry(enabled=True))
+        _run_requests(eng, n=2, max_new=3)  # compile outside the trace
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            _run_requests(eng, n=2, max_new=3)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+        host, xla = {}, []
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for e in line.events:
+                    iv = (e.start_ns, e.start_ns + e.duration_ns)
+                    if e.name.startswith("engine/"):
+                        host.setdefault(e.name, []).append(iv)
+                    elif line.name.startswith("tf_XLA"):
+                        xla.append(iv)
+        steps = host["engine/step"]
+        for name in ("engine/step", "engine/decode", "engine/decode_launch",
+                     "engine/decode_wait", "engine/decode_logits",
+                     "engine/decode_sample", "engine/sieve_host"):
+            assert host.get(name), name
+            for a, b in host[name]:
+                assert any(s <= a and b <= e for s, e in steps), name
+        # one clock: the decode program's operations run inside the
+        # engine/decode spans that launched and waited for them
+        decodes = host["engine/decode"]
+        assert any(s <= a and b <= e for a, b in xla for s, e in decodes)
+
+    def test_one_listener_per_process(self):
+        from jax._src import monitoring as jmon
+
+        from repro.telemetry import core as core_mod
+
+        tels = [Telemetry(enabled=True) for _ in range(20)]
+        tels += [Telemetry(enabled=False) for _ in range(5)]
+        listeners = jmon.get_event_duration_listeners()
+        assert listeners.count(core_mod._on_jax_event) == 1
+
+    def test_compiles_recorded_into_enabled_instances_with_open_spans(self):
+        import jax
+        import jax.numpy as jnp
+
+        a, b = Telemetry(enabled=True), Telemetry(enabled=True)
+        idle, off = Telemetry(enabled=True), Telemetry(enabled=False)
+        x = jnp.ones((3, 5))
+        with a.span("outer"), b.span("outer"), off.span("outer"):
+            jax.jit(lambda v: v * 3.0 + 1.0)(x).block_until_ready()
+        for tel in (a, b):
+            (ev,) = _compile_spans(tel)
+            assert ev["name"] == "engine/compile/jit(<lambda>)"
+            (outer,) = [e for e in tel.events() if e["name"] == "outer"]
+            assert outer["t0_ns"] <= ev["t0_ns"]
+            assert ev["t0_ns"] + ev["dur_ns"] <= outer["t0_ns"] + outer["dur_ns"]
+            assert tel.counters()["engine/jit_cache_miss"] == 1.0
+        # no span open: not serving, so nothing recorded
+        assert _compile_spans(idle) == [] and idle.n_emitted == 0
+        assert off.n_emitted == 0
+
+    def test_new_prompt_length_compiles_once_steady_decode_never(self):
+        tel = Telemetry(enabled=True)
+        eng = _moe_engine(telemetry=tel)
+        _run_requests(eng)  # builds the prefill of 8 tokens and the decode
+        tel.reset()
+        _run_requests(eng)
+        assert _compile_spans(tel) == []
+        assert "engine/jit_cache_miss" not in tel.counters()
+        tel.reset()
+        n_prefill = eng._prefill_chunk._cache_size()
+        _run_requests(eng, prompt_len=12)  # a prompt length not built yet
+        spans = _compile_spans(tel)
+        assert [e["name"] for e in spans] == ["engine/compile/jit(_prefill_chunk_impl)"]
+        assert eng._prefill_chunk._cache_size() == n_prefill + 1
+        assert tel.counters()["engine/jit_cache_miss"] == float(len(spans))
+        # the step that built it waited for the whole build, inside it
+        (ev,) = spans
+        assert ev["dur_ns"] > 0
+        assert any(
+            s["t0_ns"] <= ev["t0_ns"]
+            and ev["t0_ns"] + ev["dur_ns"] <= s["t0_ns"] + s["dur_ns"]
+            for s in tel.events() if s["name"] == "engine/prefill"
+        )
+
+
+class TestDeviceNames:
+    def test_decode_step_carries_named_scopes(self):
+        """The lowered decode step names the expert path, attention and
+        the logits projection (metadata for the profiler's op view)."""
+        import re
+
+        import jax
+
+        eng = _moe_engine(expert_exec="dual_path_cost", policy="dual_cost")
+        lm, B = eng.lm, eng.cfg.n_slots
+        batch = {
+            "tokens": jax.ShapeDtypeStruct((B, 1), np.int32),
+            "position": jax.ShapeDtypeStruct((B,), np.int32),
+            "sieve": eng._sieve_state,
+        }
+        text = jax.jit(lm.decode_step).lower(
+            eng.params, batch, eng.cache
+        ).as_text(debug_info=True)
+        locs = set(re.findall(r'loc\("([^"]*)"', text))
+        for scope in ("moe/router", "moe/dispatch", "moe/head", "moe/tail",
+                      "moe/combine", "attention", "lm_head"):
+            assert any(re.search(rf"(^|/){scope}/", loc) for loc in locs), scope
 
 
 # ---------------------------------------------------------------------------
