@@ -30,7 +30,7 @@ from .layers import (
     init_norm,
     sinusoidal_positions,
 )
-from .moe import LOCAL_MESH, MeshInfo
+from .moe import LOCAL_MESH, ROUTED_STACKS, MeshInfo, experts_in_place
 from .ssm import (
     Mamba2State,
     RWKV6State,
@@ -196,8 +196,44 @@ class LM:
     # Forward (training / prefill share the stack walk)
     # ==================================================================
 
+    def moe_layers_in_place(self) -> int:
+        """Number of MoE layers whose expert kernels read the routed
+        weights from the whole layer-stacked arrays in ``prefill`` and
+        ``decode_step`` (:func:`repro.models.moe.experts_in_place`); 0
+        where the layer loop slices each layer's stacks.  ``forward``
+        always slices: a closed-over stack would turn into a whole-stack
+        gradient accumulator under ``scan``."""
+        arch = self.arch
+        if arch.family not in ("dense", "moe", "vlm") or not experts_in_place(
+            arch, self.mi
+        ):
+            return 0
+        return arch.n_layers - arch.moe.first_k_dense
+
+    def _block_scan(self, blocks, in_place: bool):
+        """``(xs, block_of)`` for a scan over ``p["blocks"]``: ``block_of``
+        maps one step's input back to that layer's block params.  In place,
+        the routed expert stacks leave ``xs`` (whose slice XLA would copy
+        ahead of the Pallas calls): each is viewed whole as ``(L*E, ...)``,
+        a bitcast, and every layer gets them with its base row ``l*E``."""
+        if not in_place:
+            return blocks, lambda blk: blk
+        moe_p = blocks["moe"]
+        L, E = moe_p["w_gate"].shape[:2]
+        whole = {
+            k: moe_p[k].reshape((L * E,) + moe_p[k].shape[2:])
+            for k in ROUTED_STACKS
+        }
+        rest = {k: v for k, v in moe_p.items() if k not in whole}
+
+        def block_of(inp):
+            blk, layer = inp
+            return {**blk, "moe": {**blk["moe"], **whole, "expert_base": layer * E}}
+
+        return ({**blocks, "moe": rest}, jnp.arange(L, dtype=jnp.int32)), block_of
+
     def _walk_attn_stack(self, p, x, positions, mrope, collect_cache: bool,
-                         sieve=None):
+                         sieve=None, in_place: bool = False):
         """dense/moe/vlm families."""
         arch, mi = self.arch, self.mi
         moe = arch.moe is not None
@@ -220,16 +256,18 @@ class LM:
                 if collect_cache:
                     caches.setdefault("prefix", []).append(cache)
 
-        def body(x, blk_p):
+        xs, block_of = self._block_scan(p["blocks"], in_place)
+
+        def body(x, inp):
             x, cache, aux = tf.attn_mlp_block_seq(
-                blk_p, x, positions, arch, mi, moe=moe,
+                block_of(inp), x, positions, arch, mi, moe=moe,
                 mrope_positions=mrope, q_chunk=self.q_chunk, kv_chunk=self.kv_chunk,
                 sieve=sieve,
             )
             return self._sp(x), (cache if collect_cache else None, aux)
 
         scan_body = jax.checkpoint(body) if self.remat else body
-        x, (cache_stack, aux_stack) = jax.lax.scan(scan_body, self._sp(x), p["blocks"])
+        x, (cache_stack, aux_stack) = jax.lax.scan(scan_body, self._sp(x), xs)
         if collect_cache:
             caches["blocks"] = cache_stack
         return x, caches, auxes, aux_stack
@@ -558,7 +596,7 @@ class LM:
         if arch.family in ("dense", "moe", "vlm"):
             x, caches, prefix_aux, aux_stack = self._walk_attn_stack(
                 p, x, positions, mrope, collect_cache=True,
-                sieve=batch.get("sieve"),
+                sieve=batch.get("sieve"), in_place=self.moe_layers_in_place() > 0,
             )
             cache = {"blocks": caches["blocks"]}
             if "prefix" in caches:
@@ -667,17 +705,21 @@ class LM:
                     auxes.append(aux)
                 new_prefix = jax.tree.map(lambda *xs: jnp.stack(xs), *new_list)
 
+            xs, block_of = self._block_scan(
+                p["blocks"], self.moe_layers_in_place() > 0
+            )
+
             def body(x, inp):
-                blk_p, cache_l = inp
+                blk_in, cache_l = inp
                 x, new_c, aux = tf.attn_mlp_block_decode(
-                    blk_p, x, position, cache_l, arch, mi, moe=moe,
+                    block_of(blk_in), x, position, cache_l, arch, mi, moe=moe,
                     mrope_positions=mrope, seq_par=seq_par, sieve=sieve,
                     paged=paged,
                 )
                 return x, (new_c, aux)
 
             x, (new_blocks, aux_stack) = jax.lax.scan(
-                body, x, (p["blocks"], cache["blocks"])
+                body, x, (xs, cache["blocks"])
             )
             new_cache = {"blocks": new_blocks}
             if n_prefix:

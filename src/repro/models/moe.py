@@ -582,6 +582,11 @@ def experts_ffn_dual(
     the drop count is nonzero only when a head budget squeezes a
     >tau-row expert off the grouped path (0 with the default
     ``dual_max_head=0``).
+    With ``params["expert_base"]`` set (a traced int32 ``l*E``), the
+    weights are the whole layer-stacked arrays viewed as ``(L*E, d, f)``
+    / ``(L*E, f, d)``: the kernels read expert ``e`` of layer ``l`` at row
+    ``l*E + e`` through their scalar-prefetch tables (Pallas backend only;
+    see :func:`experts_in_place`).
     """
     if backend is None:
         backend = _dual_backend()
@@ -593,21 +598,34 @@ def experts_ffn_dual(
     head_sizes_full = jnp.where(split["head_mask"], rows, 0).astype(jnp.int32)
 
     wg, wu, wd = params["w_gate"], params["w_up"], params["w_down"]
+    # weight row of each expert: its own index, or l*E + e where the
+    # weights are the whole layer-stacked (L*E, ...) arrays
+    base = params.get("expert_base")
+    if base is not None and backend != "pallas":
+        raise ValueError("layer-stacked expert weights need the Pallas kernels")
+    w_row = jnp.arange(E, dtype=jnp.int32)
+    if base is not None:
+        w_row = base + w_row
     with jax.named_scope("moe/head"):
         if H < E:
-            # compact: gather the H most popular experts' slabs and weights
+            # compact: gather the H most popular experts' slabs; each keeps
+            # its weight row through the rhs_of_group table
             hid = split["order"][:H]
             slab = buf[hid]
             head_sizes = head_sizes_full[hid]
-            wgh, wuh, wdh = wg[hid], wu[hid], wd[hid]
+            rhs = w_row[hid]
         else:
-            slab, head_sizes = buf, head_sizes_full
-            wgh, wuh, wdh = wg, wu, wd
+            slab, head_sizes, rhs = buf, head_sizes_full, None
 
         if backend == "pallas":
-            y_head = _swiglu_grouped_pallas(slab, wgh, wuh, wdh, head_sizes)
+            y_head = _swiglu_grouped_pallas(
+                slab, wg, wu, wd, head_sizes,
+                rhs_of_group=w_row if rhs is None else rhs,
+            )
         else:
-            y_head = _swiglu_grouped_xla(slab, wgh, wuh, wdh, head_sizes)
+            y_head = _swiglu_grouped_xla(
+                slab, wg, wu, wd, head_sizes, rhs_of_group=rhs
+            )
         if H < E:
             y = jnp.zeros((E, C, d), y_head.dtype).at[hid].set(y_head)
         else:
@@ -622,8 +640,8 @@ def experts_ffn_dual(
             )[:, None]
             valid = split["tail_mask"][:, None] & live
             ty = _tail_path(
-                buf[:, :tau, :], wg, wu, wd,
-                jnp.arange(E, dtype=jnp.int32), valid, backend, gather_w=False,
+                buf[:, :tau, :], wg, wu, wd, w_row, valid, backend,
+                gather_w=False,
             )
             y = y.at[:, :tau, :].add(ty.astype(y.dtype))
 
@@ -724,6 +742,24 @@ def experts_ffn_dual_segmented(
 
 _EXEC_MODES = ("dense", "dual_path", "dual_path_cost")
 _DUAL_MODES = ("dual_path", "dual_path_cost")
+ROUTED_STACKS = ("w_gate", "w_up", "w_down")
+
+
+def experts_in_place(arch: ArchConfig, mi: MeshInfo) -> bool:
+    """Whether an inference walk hands the expert kernels each routed
+    weight stack whole, ``(L, E, ...)`` viewed as ``(L*E, ...)``, with the
+    layer's base row ``l*E`` in ``params["expert_base"]``, in place of the
+    layer's slice.  The slice feeds a Pallas call, so XLA would copy it
+    (one layer's three stacks per layer and step); it is kept where that
+    copy does not arise or the view does not hold: the XLA backend fuses
+    the slice into its einsums, the EP bodies hold stacks sharded on the
+    expert axis, and ``expert_exec="dense"`` runs no kernel."""
+    return (
+        arch.moe is not None
+        and arch.moe.expert_exec in _DUAL_MODES
+        and mi.ep_size <= 1
+        and _dual_backend() == "pallas"
+    )
 
 
 def _check_expert_exec(cfg: MoEConfig) -> None:
@@ -975,7 +1011,7 @@ def moe_block(
             )(routed_params, xt)
     else:
         routed = moe_local(
-            {k: params[k] for k in ("w_router", "w_gate", "w_up", "w_down")},
+            {k: v for k, v in params.items() if k != "shared"},
             xt, arch, sieve=sieve,
         )
 

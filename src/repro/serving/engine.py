@@ -285,6 +285,11 @@ class ServingEngine:
                     4096, max(batching.n_slots, batching.max_seq, 64)
                 )
                 self._refresh_sieve_state(step=0)
+        # build-time record: MoE layers whose expert kernels read the
+        # layer-stacked weights in place (0 where each layer is sliced)
+        self.tel.gauge(
+            "engine/moe_layers_in_place", float(lm.moe_layers_in_place())
+        )
 
     # ------------------------------------------------------------------
     def _refresh_sieve_state(self, step: int, gpu_only: bool = False) -> None:

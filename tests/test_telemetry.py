@@ -603,6 +603,19 @@ class TestEngineTelemetry:
         assert {e["value"] for e in of("engine/decode_sample")} == {4.0}
         assert len(of("engine/retire")) == len(of("engine/step"))
 
+    def test_moe_layers_in_place_gauge(self, monkeypatch):
+        """Recorded once at build time: every MoE layer reads the stacked
+        expert weights in place on the Pallas path, none on the XLA path."""
+        for backend, expected in (("pallas", 2.0), ("xla", 0.0)):
+            monkeypatch.setenv("REPRO_DUAL_BACKEND", backend)
+            tel = Telemetry(enabled=True)
+            eng = _moe_engine(telemetry=tel)
+            assert eng.lm.arch.n_layers == 2
+            assert tel.gauges()["engine/moe_layers_in_place"] == expected
+            assert [e["name"] for e in tel.events()] == [
+                "engine/moe_layers_in_place"
+            ]
+
     def test_engine_off_telemetry_records_nothing(self):
         tel = Telemetry(enabled=False)
         eng = _moe_engine(telemetry=tel)

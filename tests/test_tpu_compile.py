@@ -149,3 +149,49 @@ def test_qwen3_decode_step_compiles_for_v5e(
     text = compiled.as_text()
     # head grouped GEMM, tail GEMV and decode attention all compiled in
     assert text.count('custom_call_target="tpu_custom_call"') >= 3
+
+
+def test_qwen3_decode_step_reads_expert_stacks_in_place(
+    one_chip, no_compile_cache, monkeypatch
+):
+    """Two full-width layers of the served decode step: the expert kernels
+    read the layer-stacked weights through a bitcast to ``(L*E, ...)``, so
+    the compiled program makes no value of one layer's ``(E, d, f)`` /
+    ``(E, f, d)`` stack and no copy of a whole stack."""
+    import re
+
+    monkeypatch.setattr(moe, "_dual_backend", lambda: "pallas")
+    monkeypatch.setattr(attention, "_flash_decode_mode", lambda: "kernel")
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    L = 2
+    lm = LM(cut_depth(ARCH, L), dtype=BF)
+    assert lm.moe_layers_in_place() == L
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree,
+        )
+
+    params = placed(lm.abstract_params())
+    cache = placed(jax.eval_shape(lambda: lm.init_cache(SLOTS, MAX_SEQ)))
+    batch = placed({
+        "tokens": jax.ShapeDtypeStruct((SLOTS, 1), I32),
+        "position": jax.ShapeDtypeStruct((SLOTS,), I32),
+    })
+    text = (
+        jax.jit(lm.decode_step, donate_argnums=(2,))
+        .lower(params, batch, cache)
+        .compile()
+        .as_text()
+    )
+    layer = {f"bf16[{E},{D},{F}]", f"bf16[{E},{F},{D}]"}
+    whole = {f"bf16[{L},{E},{D},{F}]", f"bf16[{L},{E},{F},{D}]",
+             f"bf16[{L * E},{D},{F}]", f"bf16[{L * E},{F},{D}]"}
+    ops_of = {}
+    for m in re.finditer(r"= (bf16\[[\d,]+\])\S* ([\w\-]+)\(", text):
+        ops_of.setdefault(m.group(1), set()).add(m.group(2))
+    assert not layer & set(ops_of)
+    moved = set().union(*(ops_of.get(s, set()) for s in whole))
+    assert "bitcast" in moved
+    assert moved <= {"parameter", "bitcast", "get-tuple-element", "tuple"}
