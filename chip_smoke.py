@@ -18,7 +18,10 @@ Phases (one chip):
    the engine, whose expert path ships as ``dual_path_cost``;
 3. the first prefill and decode logits of that run against the same
    inputs replayed through an engine with ``expert_exec="dense"`` (the
-   repo's oracle) on the same weights.
+   repo's oracle) on the same weights;
+4. phases 2 and 3 for deepseek-v2-236b as one chip's share of an expert
+   group: its dense lead layer and one MoE layer holding 20 of the
+   router's 160 experts, published routing and rope.
 
 Four chips: the same model with its experts split over a (data 1,
 model 4) mesh.  Its first MoE layer alone is checked against the same
@@ -51,6 +54,7 @@ LAYERS = 8
 SEED = 0
 REQUESTS, PROMPT_LEN, MAX_NEW = 4, 128, 16
 SLOTS, MAX_SEQ = 32, 2048
+DEEPSEEK_HELD = 20  # one routing group of 8, as in the benchmark's deepseek cell
 
 # Kernel vs reference: both end in one bf16 rounding of the output
 # (half an ulp is 2^-9 of the value); the kernels also round the SwiGLU
@@ -354,9 +358,6 @@ def delete(tree) -> None:
 
 
 def one_chip(arch, batching) -> None:
-    import jax
-
-    from repro.launch.serve import build_engine
     from repro.models import attention, moe
     from repro.kernels import ops
 
@@ -368,13 +369,41 @@ def one_chip(arch, batching) -> None:
         raise Fail("the chip path did not select the Pallas kernels")
 
     kernel_checks(arch)
+    served_vs_oracle(arch, batching, "served vs dense oracle")
+    served_vs_oracle(deepseek_share(), batching, "deepseek share vs dense oracle")
+
+
+def deepseek_share():
+    """DeepSeek-V2 as one chip's share of an expert group, at published
+    widths (group-limited routing, YaRN): the dense lead layer and one MoE
+    layer holding ``DEEPSEEK_HELD`` of the router's 160 experts, dropless."""
+    from repro.launch.serve import build_arch
+
+    arch = build_arch("deepseek-v2-236b", full=True, layers=2)
+    m = arch.moe
+    arch = dataclasses.replace(arch, moe=dataclasses.replace(
+        m, held=DEEPSEEK_HELD, expert_exec="dual_path_cost",
+        capacity_factor=m.n_experts / m.top_k))
+    log(f"arch={arch.name} layers={arch.n_layers} experts held "
+        f"{arch.moe.n_held} of {m.n_experts} (groups {m.n_group}, top "
+        f"{m.topk_group}) top_k={m.top_k} vocab={arch.vocab_size}")
+    return arch
+
+
+def served_vs_oracle(arch, batching, label: str) -> None:
+    """Serve through the engine, then replay its first calls through an
+    engine with ``expert_exec="dense"`` on the same weights."""
+    import jax
+
+    from repro.launch.serve import build_engine
 
     t0 = time.perf_counter()
     eng = build_engine(arch, batching, seed=SEED)
     jax.block_until_ready(eng.params)
     n_params = sum(x.size for x in jax.tree.leaves(eng.params))
     log(f"params: {n_params} ({n_params * 2 / 1e9:.2f} GB bf16) built in "
-        f"{time.perf_counter() - t0:.1f}s")
+        f"{time.perf_counter() - t0:.1f}s; MoE layers read in place: "
+        f"{eng.lm.moe_layers_in_place()}")
     done, pre, dec = serve(eng, compile_report=True)
     stats = jax.devices()[0].memory_stats() or {}
     log(f"peak_bytes_in_use={stats.get('peak_bytes_in_use')} "
@@ -389,8 +418,9 @@ def one_chip(arch, batching) -> None:
     oracle = build_engine(dense_arch, batching, seed=SEED, params=params)
     ref_pre, ref_dec = replay(oracle, pre, dec)
     live = sorted(r.slot for r in done)
-    compare_logits("served vs dense oracle", pre, dec, ref_pre, ref_dec,
-                   arch.vocab_size, live, LOGITS_TOL)
+    compare_logits(label, pre, dec, ref_pre, ref_dec, arch.vocab_size, live,
+                   LOGITS_TOL)
+    delete((params, oracle.cache, oracle._sieve_state))
 
 
 def ep_layer_check(arch, params, mi) -> None:

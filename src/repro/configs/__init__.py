@@ -9,6 +9,7 @@ from .base import (  # noqa: F401
     SHAPES,
     SSMConfig,
     ShapeSpec,
+    YarnConfig,
     all_archs,
     cell_is_skipped,
     get_arch,

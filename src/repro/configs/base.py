@@ -25,6 +25,23 @@ class MLAConfig:
 
 
 @dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (DeepSeek-V2's ``rope_scaling``, type "yarn"):
+    the inverse frequencies ramp from interpolated (divided by ``factor``)
+    to extrapolated between the correction dims of ``beta_fast`` and
+    ``beta_slow`` rotations over ``original_max_position`` positions; cos
+    and sin are scaled by mscale(mscale) / mscale(mscale_all_dim) and the
+    attention softmax by mscale(mscale_all_dim)**2."""
+
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
+
+
+@dataclass(frozen=True)
 class AttnConfig:
     kind: str = "gqa"  # "gqa" | "mla" | "none"
     n_heads: int = 0
@@ -33,6 +50,7 @@ class AttnConfig:
     qkv_bias: bool = False
     rope_theta: float = 1e6
     mla: Optional[MLAConfig] = None
+    rope_scaling: Optional[YarnConfig] = None  # MLA rope only
     # Qwen2-VL M-RoPE: head-dim split across (temporal, height, width)
     mrope_sections: Optional[Tuple[int, int, int]] = None
 
@@ -75,6 +93,53 @@ class MoEConfig:
     # threshold are dropped and counted in MoEOut.n_dropped (same contract
     # as capacity overflow).
     dual_max_head: int = 0
+    # Routing (DeepSeek-V2's MoEGate): with n_group > 1 the experts form
+    # n_group equal groups, a group scores its best expert, and a token's
+    # top_k come from its topk_group best groups.  norm_topk_prob
+    # renormalises the top_k weights to sum 1; otherwise they are the
+    # softmax scores times routed_scaling_factor.
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # The share of the experts held here, one chip's part of an
+    # expert-parallel group run without its exchange: experts
+    # [held_offset, held_offset + n_held) of the router's n_experts.
+    # held = 0 holds every expert.
+    held: int = 0
+    held_offset: int = 0
+
+    def __post_init__(self):
+        E, G = self.n_experts, self.n_group
+        if G < 1 or E % G:
+            raise ValueError(
+                f"MoEConfig: n_experts={E} must split into n_group={G} "
+                "equal routing groups"
+            )
+        if not 1 <= self.topk_group <= G:
+            raise ValueError(
+                f"MoEConfig: topk_group={self.topk_group} must lie in [1, "
+                f"n_group={G}]"
+            )
+        if self.top_k > self.topk_group * (E // G):
+            raise ValueError(
+                f"MoEConfig: top_k={self.top_k} exceeds the "
+                f"{self.topk_group * (E // G)} experts of topk_group="
+                f"{self.topk_group} groups"
+            )
+        if self.held < 0 or self.held_offset < 0 or (
+            self.held_offset + self.n_held > E
+        ):
+            raise ValueError(
+                f"MoEConfig: held experts [{self.held_offset}, "
+                f"{self.held_offset + self.n_held}) lie outside the router's "
+                f"{E}"
+            )
+
+    @property
+    def n_held(self) -> int:
+        """Experts whose weights this layer holds."""
+        return self.held or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -190,6 +255,8 @@ class ArchConfig:
                 self.moe, n_experts=8, top_k=2, d_expert=32,
                 n_shared=min(self.moe.n_shared, 1),
                 first_k_dense=min(self.moe.first_k_dense, 1),
+                n_group=min(self.moe.n_group, 4),
+                topk_group=min(self.moe.topk_group, 2),
             )
         if self.ssm is not None:
             kw["ssm"] = dataclasses.replace(

@@ -8,6 +8,7 @@ in :mod:`repro.kernels` provide the TPU-optimized versions of the same math
 
 from __future__ import annotations
 
+import math
 import os
 from typing import NamedTuple, Optional, Tuple
 
@@ -15,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import AttnConfig
-from .layers import _he, apply_mrope, apply_rope
+from .layers import _he, apply_mrope, apply_rope, softmax_mscale
 
 NEG_INF = -1e30
 
@@ -90,12 +91,14 @@ def flash_attention(
     q_chunk: int = 1024,
     kv_chunk: int = 1024,
     q_offset: int = 0,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Online-softmax blockwise attention; supports GQA via head groups.
 
     Memory is O(q_chunk * kv_chunk) per (batch, head) instead of O(Sq*Sk).
     ``q_offset`` places the query block inside the kv timeline (for chunked
-    prefill where queries start mid-sequence).
+    prefill where queries start mid-sequence).  ``scale`` is the softmax
+    scale (default ``1/sqrt(dh)``).
     """
     B, Sq, H, dh = q.shape
     _, Sk, K, _ = k.shape
@@ -105,7 +108,8 @@ def flash_attention(
     kv_chunk = min(kv_chunk, Sk)
     assert Sq % q_chunk == 0 and Sk % kv_chunk == 0, (Sq, q_chunk, Sk, kv_chunk)
     nq, nk = Sq // q_chunk, Sk // kv_chunk
-    scale = 1.0 / jnp.sqrt(dh).astype(jnp.float32)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(dh).astype(jnp.float32)
 
     # Head-major layout: repeat kv heads to the full query head count so
     # tensor parallelism shards the head dim cleanly (GQA-aware grouping
@@ -556,6 +560,12 @@ def _shard_map_attn(body, mi, args, in_specs, out_specs):
 # ---------------------------------------------------------------------------
 
 
+def _mla_softmax_scale(cfg: AttnConfig) -> float:
+    """1/sqrt(qk head dim), times YaRN's mscale**2 where rope is scaled."""
+    m = cfg.mla
+    return softmax_mscale(cfg.rope_scaling) / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+
+
 @jax.named_scope("attention")
 def mla_prefill(
     params: dict,
@@ -572,11 +582,12 @@ def mla_prefill(
     cq = _rms(x @ params["w_dq"], params["q_norm_scale"])
     q = (cq @ params["w_uq"]).reshape(B, S, H, m.qk_nope_dim + m.qk_rope_dim)
     q_nope, q_rope = q[..., : m.qk_nope_dim], q[..., m.qk_nope_dim :]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
 
     c_kv = _rms(x @ params["w_dkv"], params["kv_norm_scale"])  # (B, S, c)
     k_rope = apply_rope(
-        (x @ params["w_kr"])[:, :, None, :], positions, cfg.rope_theta
+        (x @ params["w_kr"])[:, :, None, :], positions, cfg.rope_theta,
+        cfg.rope_scaling,
     )[:, :, 0, :]  # (B, S, r) shared across heads
     k_nope = (c_kv @ params["w_uk"]).reshape(B, S, H, m.qk_nope_dim)
     v = (c_kv @ params["w_uv"]).reshape(B, S, H, m.v_head_dim)
@@ -586,7 +597,8 @@ def mla_prefill(
         [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (B, S, H, m.qk_rope_dim))],
         -1,
     )
-    o = flash_attention(qq, kk, v, causal=True, q_chunk=q_chunk, kv_chunk=kv_chunk)
+    o = flash_attention(qq, kk, v, causal=True, q_chunk=q_chunk, kv_chunk=kv_chunk,
+                        scale=_mla_softmax_scale(cfg))
     y = o.reshape(B, S, -1) @ params["wo"]
     return y, c_kv, k_rope
 
@@ -608,11 +620,12 @@ def mla_decode(
     cq = _rms(x @ params["w_dq"], params["q_norm_scale"])
     q = (cq @ params["w_uq"]).reshape(B, 1, H, m.qk_nope_dim + m.qk_rope_dim)
     q_nope, q_rope = q[..., : m.qk_nope_dim], q[..., m.qk_nope_dim :]
-    q_rope = apply_rope(q_rope, position[:, None], cfg.rope_theta)
+    q_rope = apply_rope(q_rope, position[:, None], cfg.rope_theta, cfg.rope_scaling)
 
     c1 = _rms(x @ params["w_dkv"], params["kv_norm_scale"])  # (B, 1, c)
     kr1 = apply_rope(
-        (x @ params["w_kr"])[:, :, None, :], position[:, None], cfg.rope_theta
+        (x @ params["w_kr"])[:, :, None, :], position[:, None], cfg.rope_theta,
+        cfg.rope_scaling,
     )[:, :, 0, :]
     cache_ckv = jax.vmap(lambda c, r, i: jax.lax.dynamic_update_slice(c, r, (i, 0)))(
         cache_ckv, c1, position
@@ -625,7 +638,7 @@ def mla_decode(
     w_uk = params["w_uk"].reshape(-1, H, m.qk_nope_dim)  # (c, H, n)
     q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0].astype(jnp.float32),
                        w_uk.astype(jnp.float32))
-    scale = 1.0 / jnp.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    scale = _mla_softmax_scale(cfg)
     s = (
         jnp.einsum("bhc,btc->bht", q_lat, cache_ckv.astype(jnp.float32))
         + jnp.einsum(

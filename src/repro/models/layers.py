@@ -8,6 +8,7 @@ softmax, rotary phases).
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -103,19 +104,61 @@ def lm_logits(
 # ---------------------------------------------------------------------------
 
 
-def rope_freqs(d_head: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, d_head, 2, dtype=jnp.float32) / d_head))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """DeepSeek-V2's ``yarn_get_mscale``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_correction_dim(rotations: float, d: int, theta: float, n_pos: int) -> float:
+    return (d * math.log(n_pos / (rotations * 2 * math.pi))) / (2 * math.log(theta))
+
+
+def rope_freqs(d_head: int, theta: float, scaling=None) -> jax.Array:
+    """Rope inverse frequencies; with a :class:`YarnConfig` the YaRN ones:
+    extrapolated below the correction dim of ``beta_fast``, interpolated
+    (divided by ``factor``) above that of ``beta_slow``, a linear ramp
+    between."""
+    inv = 1.0 / (theta ** (jnp.arange(0, d_head, 2, dtype=jnp.float32) / d_head))
+    if scaling is None:
+        return inv
+    n_pos = scaling.original_max_position
+    low = max(math.floor(_yarn_correction_dim(scaling.beta_fast, d_head, theta, n_pos)), 0)
+    high = min(math.ceil(_yarn_correction_dim(scaling.beta_slow, d_head, theta, n_pos)),
+               d_head - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(d_head // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    return inv / scaling.factor * ramp + inv * (1.0 - ramp)
+
+
+def rope_mscale(scaling) -> float:
+    """Scale of rope's cos and sin under YaRN (1 without scaling)."""
+    if scaling is None:
+        return 1.0
+    return yarn_mscale(scaling.factor, scaling.mscale) / yarn_mscale(
+        scaling.factor, scaling.mscale_all_dim)
+
+
+def softmax_mscale(scaling) -> float:
+    """Factor on the attention softmax scale under YaRN (1 without)."""
+    if scaling is None or not scaling.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(scaling.factor, scaling.mscale_all_dim) ** 2
 
 
 def apply_rope(
     x: jax.Array,  # (..., seq, heads, d_head)
     positions: jax.Array,  # (..., seq)
     theta: float,
+    scaling=None,  # YarnConfig
 ) -> jax.Array:
     d = x.shape[-1]
-    inv = rope_freqs(d, theta)  # (d/2,)
+    inv = rope_freqs(d, theta, scaling)  # (d/2,)
     ang = positions[..., None].astype(jnp.float32) * inv  # (..., seq, d/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    m = rope_mscale(scaling)
+    if m != 1.0:
+        cos, sin = cos * m, sin * m
     cos = cos[..., None, :]  # broadcast over heads
     sin = sin[..., None, :]
     x1, x2 = x[..., : d // 2], x[..., d // 2 :]

@@ -44,7 +44,9 @@ class StepAux(NamedTuple):
     """Aggregated per-step diagnostics (MoE aux loss, Sieve counts, drops)."""
 
     moe_aux: jax.Array  # scalar
-    counts: jax.Array  # (n_moe_layers, E) token counts per layer (Sieve input)
+    # (n_moe_layers, E) token counts per layer and router output (Sieve
+    # input); on a held expert share, those its experts take
+    counts: jax.Array
     dropped: jax.Array  # scalar
 
 

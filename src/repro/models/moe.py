@@ -2,8 +2,16 @@
 
 Design (DESIGN.md §5, §8.2):
 
-* **Router**: fp32 logits, top-k, renormalized softmax weights, GShard-style
-  load-balancing aux loss.
+* **Router**: fp32 logits, softmax scores, top-k, GShard-style
+  load-balancing aux loss.  Weights are the top-k scores renormalised to
+  sum 1 (``norm_topk_prob``, Qwen3-MoE) or, as DeepSeek-V2 publishes them,
+  left unnormalised and multiplied by ``routed_scaling_factor``; with
+  ``n_group > 1`` the top-k come from each token's ``topk_group`` best
+  expert groups (DeepSeek-V2's ``group_limited_greedy``).
+* **Held share**: a layer may hold experts [held_offset, held_offset +
+  n_held) of its router's n_experts, one chip's part of an expert-parallel
+  group run without the exchange: it routes over all of them, dispatches
+  only the assignments its experts take, and returns that partial output.
 * **Dispatch**: capacity-based scatter (sort-free, one-hot-free) into an
   ``(E, C, d)`` buffer — static SPMD shapes, no fake matmul FLOPs, matches
   the paper's fixed-size-tensor metadata step (§6.1 ④).  Overflow tokens
@@ -74,10 +82,12 @@ LOCAL_MESH = MeshInfo(None, (), None)
 
 def init_moe(key, arch: ArchConfig, dtype=jnp.bfloat16) -> dict:
     cfg = arch.moe
-    d, f, E = arch.d_model, cfg.d_expert, cfg.n_experts
+    d, f, E = arch.d_model, cfg.d_expert, cfg.n_held
     ks = jax.random.split(key, 5)
     p = {
-        "w_router": (jax.random.normal(ks[0], (d, E)) * 0.02).astype(jnp.float32),
+        "w_router": (
+            jax.random.normal(ks[0], (d, cfg.n_experts)) * 0.02
+        ).astype(jnp.float32),
         "w_gate": _he(ks[1], (E, d, f), 1.0, dtype),
         "w_up": _he(ks[2], (E, d, f), 1.0, dtype),
         "w_down": _he(ks[3], (E, f, d), 1.0, dtype),
@@ -123,14 +133,31 @@ class RouterOut(NamedTuple):
     counts: jax.Array  # (E,) int32 token count per expert
 
 
+def _group_limited(probs: jax.Array, cfg: MoEConfig) -> jax.Array:
+    """The scores with every expert outside a token's ``topk_group`` best
+    groups set to 0; a group scores its best expert."""
+    T, E = probs.shape
+    G = cfg.n_group
+    with jax.named_scope("moe/group_route"):
+        group_score = probs.reshape(T, G, E // G).max(-1)  # (T, G)
+        _, gi = jax.lax.top_k(group_score, cfg.topk_group)
+        keep = jax.nn.one_hot(gi, G, dtype=jnp.int32).sum(1) > 0  # (T, G)
+        return jnp.where(jnp.repeat(keep, E // G, axis=1), probs, 0.0)
+
+
 @jax.named_scope("moe/router")
 def route(x: jax.Array, w_router: jax.Array, cfg: MoEConfig) -> RouterOut:
-    """Top-k routing with renormalized weights + load-balance aux loss."""
+    """Top-k routing (group-limited where ``cfg.n_group > 1``), weights
+    renormalised or scaled as ``cfg`` says, plus load-balance aux loss."""
     T = x.shape[0]
     logits = (x.astype(jnp.float32) @ w_router.astype(jnp.float32))  # (T, E)
     probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, cfg.top_k)
-    weights = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    scores = probs if cfg.n_group == 1 else _group_limited(probs, cfg)
+    top_p, top_i = jax.lax.top_k(scores, cfg.top_k)
+    if cfg.norm_topk_prob:
+        weights = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    else:
+        weights = top_p * cfg.routed_scaling_factor
     # GShard aux loss: E * sum_e mean_t(prob_e) * mean_t(frac_routed_e)
     E = w_router.shape[1]
     frac = jnp.zeros((E,), jnp.float32).at[top_i.reshape(-1)].add(1.0) / (
@@ -363,7 +390,7 @@ def default_sieve_state(
     """
     cfg = arch.moe
     return _default_sieve_state(
-        arch.d_model, cfg.d_expert, cfg.n_experts, cfg.top_k, cfg.n_shared,
+        arch.d_model, cfg.d_expert, cfg.n_held, cfg.top_k, cfg.n_shared,
         max_count,
     )
 
@@ -379,7 +406,7 @@ def resolve_sieve_state(
     if sieve is not None:
         return sieve
     return _default_sieve_state(
-        d_model, cfg.d_expert, cfg.n_experts, cfg.top_k, cfg.n_shared,
+        d_model, cfg.d_expert, cfg.n_held, cfg.top_k, cfg.n_shared,
         _DEFAULT_SIEVE_MAX_COUNT,
     )
 
@@ -793,7 +820,10 @@ def experts_ffn_exec(
 class MoEOut(NamedTuple):
     y: jax.Array  # (T, d)
     aux_loss: jax.Array
-    counts: jax.Array  # (E,) global token counts (Sieve scheduler input)
+    # (E,) token counts per router output (Sieve scheduler input): global
+    # under EP; on a held share, the assignments its experts take (zero
+    # off the share)
+    counts: jax.Array
     n_dropped: jax.Array
 
 
@@ -804,16 +834,21 @@ def moe_local(
     sieve: Optional[SieveState] = None,
 ) -> MoEOut:
     """Single-device routed-experts path (reference; also the per-shard math
-    when EP is disabled)."""
+    when EP is disabled).  On a held share (``cfg.n_held < cfg.n_experts``)
+    only the assignments to the held experts are dispatched and computed:
+    ``y`` is their part of the layer's routed output."""
     cfg = arch.moe
     T = x.shape[0]
     r = route(x, params["w_router"], cfg)
     cap = capacity(T, cfg, cfg.n_experts)
-    disp = dispatch(x, r, cfg.n_experts, cap)
-    rows = jnp.minimum(r.counts, cap)
+    off, n = cfg.held_offset, cfg.n_held
+    disp = dispatch(x, r, cfg.n_experts, cap, expert_offset=off, n_local=n)
+    e = jnp.arange(cfg.n_experts)
+    counts = jnp.where((e >= off) & (e < off + n), r.counts, 0)
+    rows = jnp.minimum(counts[off:off + n], cap)
     y_buf, exec_dropped = experts_ffn_exec(params, disp.buf, rows, cfg, sieve)
     y = combine(y_buf, disp.slot_of, r.weights, T)
-    return MoEOut(y, r.aux_loss, r.counts, disp.n_dropped + exec_dropped)
+    return MoEOut(y, r.aux_loss, counts, disp.n_dropped + exec_dropped)
 
 
 def _ep_body(
@@ -966,7 +1001,13 @@ def moe_block(
     # bodies through in_specs (replicated) rather than closure capture
     sieve = resolve_sieve_state(cfg, d, sieve)
 
-    if mi.mesh is not None and mi.ep_size > 1 and cfg.n_experts % mi.ep_size == 0:
+    ep = mi.mesh is not None and mi.ep_size > 1 and cfg.n_experts % mi.ep_size == 0
+    if ep and cfg.n_held != cfg.n_experts:
+        raise ValueError(
+            "a held expert share runs on one device, without an "
+            "expert-parallel mesh"
+        )
+    if ep:
         dp_size = 1
         for a in mi.data_axes:
             dp_size *= mi.mesh.shape[a]
@@ -1031,15 +1072,17 @@ def moe_block(
 
 
 def moe_reference(params: dict, x: jax.Array, arch: ArchConfig) -> jax.Array:
-    """Exact routed-expert output without capacity limits (oracle)."""
+    """Exact routed-expert output of the held experts without capacity
+    limits (oracle)."""
     cfg = arch.moe
     T, d = x.shape
     r = route(x, params["w_router"], cfg)
     y = jnp.zeros((T, d), jnp.float32)
-    for e in range(cfg.n_experts):
-        gate = x @ params["w_gate"][e]
-        up = x @ params["w_up"][e]
-        ye = (jax.nn.silu(gate) * up) @ params["w_down"][e]
+    for j in range(cfg.n_held):
+        e = cfg.held_offset + j
+        gate = x @ params["w_gate"][j]
+        up = x @ params["w_up"][j]
+        ye = (jax.nn.silu(gate) * up) @ params["w_down"][j]
         w_e = jnp.sum(
             jnp.where(r.expert_idx == e, r.weights, 0.0).astype(jnp.float32), axis=1
         )

@@ -223,7 +223,7 @@ class ServingEngine:
             self.layer_spec = MoELayerSpec(
                 d_model=arch.d_model,
                 d_ff=arch.moe.d_expert,
-                n_experts=arch.moe.n_experts,
+                n_experts=arch.moe.n_held,
                 top_k=arch.moe.top_k,
                 n_shared=arch.moe.n_shared,
             )
@@ -290,6 +290,10 @@ class ServingEngine:
         self.tel.gauge(
             "engine/moe_layers_in_place", float(lm.moe_layers_in_place())
         )
+        if self.is_moe:
+            # the experts each MoE layer holds (all of the router's, or
+            # one chip's share of an expert-parallel group)
+            self.tel.gauge("engine/moe_experts_held", float(arch.moe.n_held))
 
     # ------------------------------------------------------------------
     def _refresh_sieve_state(self, step: int, gpu_only: bool = False) -> None:
@@ -545,7 +549,7 @@ class ServingEngine:
             self._last_head_counts = []
         if self._last_decode_batch:
             self._probes.dispatch(
-                self._last_decode_batch, moe.n_experts, moe.top_k
+                self._last_decode_batch, moe.n_held, moe.top_k
             )
             self._probes.attention(self._last_decode_batch, self._last_kv_depth)
 
@@ -695,9 +699,14 @@ class ServingEngine:
             self._last_decode_batch = len(batch_reqs)
             self._last_kv_depth = int(position.max()) + 1
             if self.is_moe and counts.shape[0] > 0:
+                # the scheduler sees the held experts' columns; the counter
+                # counts the assignments they take
+                moe = self.lm.arch.moe
+                held = counts[:, moe.held_offset:moe.held_offset + moe.n_held]
+                tel.counter("engine/moe_held_assignments", float(held.sum()))
                 with tel.span("engine/sieve_host"):
-                    heads = self._run_sieve(counts)
-                sieve_pass = (counts, heads)
+                    heads = self._run_sieve(held)
+                sieve_pass = (held, heads)
 
         # measured cost loop + cost-table refresh cadence: the in-graph
         # split only ever changes at these boundaries (stale-table

@@ -612,8 +612,12 @@ class TestEngineTelemetry:
             eng = _moe_engine(telemetry=tel)
             assert eng.lm.arch.n_layers == 2
             assert tel.gauges()["engine/moe_layers_in_place"] == expected
+            # the other build-time gauge: every expert is held here
+            assert tel.gauges()["engine/moe_experts_held"] == float(
+                eng.lm.arch.moe.n_experts
+            )
             assert [e["name"] for e in tel.events()] == [
-                "engine/moe_layers_in_place"
+                "engine/moe_layers_in_place", "engine/moe_experts_held"
             ]
 
     def test_engine_off_telemetry_records_nothing(self):

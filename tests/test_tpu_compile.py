@@ -195,3 +195,49 @@ def test_qwen3_decode_step_reads_expert_stacks_in_place(
     moved = set().union(*(ops_of.get(s, set()) for s in whole))
     assert "bitcast" in moved
     assert moved <= {"parameter", "bitcast", "get-tuple-element", "tuple"}
+
+
+def test_deepseek_share_decode_step_compiles_for_v5e(
+    one_chip, no_compile_cache, monkeypatch
+):
+    """DeepSeek-V2's served decode step as one chip's share of an expert
+    group, at published widths: the dense lead layer and two MoE layers
+    holding 20 of the router's 160 experts, 128 slots of 2048 latent
+    positions.  The expert kernels compile and read the held stacks in
+    place: no value of one layer's ``(20, d, f)`` / ``(20, f, d)`` share."""
+    import dataclasses
+    import re
+
+    monkeypatch.setattr(moe, "_dual_backend", lambda: "pallas")
+    monkeypatch.setattr(attention, "_flash_decode_mode", lambda: "kernel")
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    arch = cut_depth(get_arch("deepseek-v2-236b"), 3)
+    arch = dataclasses.replace(arch, moe=dataclasses.replace(
+        arch.moe, held=20, expert_exec="dual_path_cost"))
+    lm = LM(arch, dtype=BF)
+    assert lm.moe_layers_in_place() == 2
+    slots, d, f = 128, arch.d_model, arch.moe.d_expert
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree,
+        )
+
+    params = placed(lm.abstract_params())
+    cache = placed(jax.eval_shape(lambda: lm.init_cache(slots, MAX_SEQ)))
+    batch = placed({
+        "tokens": jax.ShapeDtypeStruct((slots, 1), I32),
+        "position": jax.ShapeDtypeStruct((slots,), I32),
+    })
+    text = (
+        jax.jit(lm.decode_step, donate_argnums=(2,))
+        .lower(params, batch, cache)
+        .compile()
+        .as_text()
+    )
+    # the head grouped GEMM and the tail GEMV (MLA decode is plain XLA)
+    assert text.count('custom_call_target="tpu_custom_call"') >= 2
+    results = {m.group(1) for m in re.finditer(r"= (bf16\[[\d,]+\])\S* [\w\-]+\(", text)}
+    assert not {f"bf16[20,{d},{f}]", f"bf16[20,{f},{d}]"} & results
+    assert f"bf16[40,{d},{f}]" in results  # the two layers' stacks, viewed whole
