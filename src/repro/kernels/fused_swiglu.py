@@ -20,9 +20,13 @@ into one pass:
   expert's ``wg``/``wu``/``wd`` tiles exactly once with the activation held
   in-register (three ``pallas_call`` streams per row → one).
 
-Both keep the grouped-GEMM scalar-prefetch contract (``sizes`` +
-``rhs_of_group`` tile→group tables) and the dead-tile MXU skip: tiles with
-no live rows run none of the three dots.
+Only live work is visited.  The grid's first axis runs over the live
+m-tiles (head) or valid rows (tail) alone: :func:`gmm_fetch_tables` /
+:func:`gemv_fetch_tables` order them first and the grid is bounded at
+their scalar-prefetched count, so a tile or row with nothing to compute
+costs no grid step and no weight, token or output copy.  Its output block
+is never written and keeps the zeros of the buffer aliased to the output;
+a call with no live work at all skips the kernel.
 
 VMEM budget: the grouped kernel keeps a ``(bm, F)`` fp32 SiLU product, the
 ``(bm, bf)`` gate/up accumulators, one ``(bf, bn)`` weight tile, and a
@@ -42,18 +46,89 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _live_first(live: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """(count, order): the indices of the live entries first, in their
+    own order, then the dead ones."""
+    order = jnp.argsort(jnp.where(live, 0, 1), stable=True).astype(jnp.int32)
+    return live.sum(dtype=jnp.int32), order
+
+
+def _skip_if_idle(name, run, n_live, *operands, out_shape, dtype):
+    """``run(n_live, *operands, zeros)``, with the zero buffer its kernel
+    writes into, where anything is live; the zeros alone where nothing is
+    (a grid of no steps is not issued).  ``run`` is traced as a function
+    of its own named ``name``: the compiled program names the kernel after
+    the function it sits in (else the branch's), and the benchmark finds
+    the expert kernels in a device trace by the names of their ``ops``
+    wrappers."""
+    run.__name__ = run.__qualname__ = name
+    zeros = jnp.zeros(out_shape, dtype)
+    return jax.lax.cond(
+        n_live > 0, jax.jit(run), lambda *a: a[-1], n_live, *operands, zeros
+    )
+
+
+# ---------------------------------------------------------------------------
+# Grouped head path
+# ---------------------------------------------------------------------------
+
+
+def gmm_fetch_tables(
+    group_sizes: jax.Array,  # (G,) int32: actual rows per group
+    group_of_tile: jax.Array,  # (m_tiles,) int32
+    row_in_group: jax.Array,  # (m_tiles,) int32: tile's first row in its group
+    rhs_of_group: jax.Array,  # (G,) int32: weight row per group
+    bm: int,
+):
+    """Scalar-prefetch tables of :func:`fused_swiglu_gmm`, one entry per
+    grid step: the m-tiles with live rows first.  Returns ``(n_live,
+    tile, w_row, n_rows)``: the grid's bound, then per step the m-tile it
+    computes, the weight row it streams and the tile's live rows.  Steps
+    past ``n_live`` are never run."""
+    n_rows = jnp.clip(group_sizes[group_of_tile] - row_in_group, 0, bm)
+    n_live, tile = _live_first(n_rows > 0)
+    w_row = rhs_of_group[group_of_tile][tile]
+    return n_live, tile, w_row.astype(jnp.int32), n_rows[tile].astype(jnp.int32)
+
+
+def gmm_index_maps(f_tiles: int, k_tiles: int):
+    """Block index maps of :func:`fused_swiglu_gmm` over the grid ``(i,
+    n, j, k)`` and its tables ``(tile, w_row, n_rows)``: ``lhs``, ``wg``,
+    ``wu``, ``wd``, ``out``.  ``wg``/``wu`` feed only the first n-tile;
+    on later ones they keep naming that n-tile's last block, so the
+    pipeline fetches them once per step of the first axis."""
+
+    def lhs(i, n, j, k, t, w, r):
+        return t[i], k
+
+    def gate_up(i, n, j, k, t, w, r):
+        first = n == 0
+        return (
+            w[i],
+            jnp.where(first, k, k_tiles - 1),
+            jnp.where(first, j, f_tiles - 1),
+        )
+
+    def down(i, n, j, k, t, w, r):
+        return w[i], j, n
+
+    def out(i, n, j, k, t, w, r):
+        return t[i], n
+
+    return lhs, gate_up, gate_up, down, out
+
+
 def _fused_swiglu_gmm_kernel(
     # scalar prefetch
-    group_of_tile_ref,  # (m_tiles,) int32: group id per m-tile
-    row_in_group_ref,  # (m_tiles,) int32: tile's first row offset in its group
-    group_sizes_ref,  # (G,) int32: actual rows per group
-    rhs_of_group_ref,  # (G,) int32: weight row per group (consumed by the
-    #                     wg/wu/wd BlockSpec index maps)
+    tile_ref,  # (m_tiles,) int32: m-tile of each grid step (index maps)
+    w_row_ref,  # (m_tiles,) int32: weight row of each grid step (index maps)
+    n_rows_ref,  # (m_tiles,) int32: live rows of each grid step's tile
     # inputs
     lhs_ref,  # (bm, bk)
     wg_ref,  # (1, bk, bf)
     wu_ref,  # (1, bk, bf)
     wd_ref,  # (1, bf, bn)
+    zeros_ref,  # (M, N) in HBM, aliased to the output
     # outputs
     out_ref,  # (bm, bn)
     # scratch
@@ -64,11 +139,10 @@ def _fused_swiglu_gmm_kernel(
     *,
     n_k_tiles: int,
     n_f_tiles: int,
-    n_n_tiles: int,
     bm: int,
     bf: int,
 ):
-    del rhs_of_group_ref
+    del tile_ref, w_row_ref, zeros_ref
     i = pl.program_id(0)
     n = pl.program_id(1)  # n tile (d_model output)
     j = pl.program_id(2)  # f tile (the SwiGLU hidden dim)
@@ -83,14 +157,9 @@ def _fused_swiglu_gmm_kernel(
     def _init_out():
         out_acc_ref[...] = jnp.zeros_like(out_acc_ref)
 
-    g = group_of_tile_ref[i]
-    base = row_in_group_ref[i]
-    size = group_sizes_ref[g]
-    live = base < size  # any real rows in this tile?
-
     # gate/up run once per (i, j, k) — on the first n-tile only; later
     # n-tiles reuse the SiLU product parked in h_ref
-    @pl.when(live & (n == 0))
+    @pl.when(n == 0)
     def _gate_up():
         x = lhs_ref[...]
         gate_acc_ref[...] += jax.lax.dot_general(
@@ -102,7 +171,7 @@ def _fused_swiglu_gmm_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(live & (n == 0) & (k == n_k_tiles - 1))
+    @pl.when((n == 0) & (k == n_k_tiles - 1))
     def _activate():
         # silu(gate) * up in VMEM — the (bm, F) intermediate never touches
         # HBM; it feeds the down projection of every n-tile.
@@ -110,7 +179,7 @@ def _fused_swiglu_gmm_kernel(
             jax.nn.silu(gate_acc_ref[...]) * up_acc_ref[...]
         )
 
-    @pl.when(live & (k == n_k_tiles - 1))
+    @pl.when(k == n_k_tiles - 1)
     def _down():
         h = h_ref[:, pl.ds(j * bf, bf)].astype(lhs_ref.dtype)
         out_acc_ref[...] += jax.lax.dot_general(
@@ -121,8 +190,8 @@ def _fused_swiglu_gmm_kernel(
     @pl.when((j == n_f_tiles - 1) & (k == n_k_tiles - 1))
     def _finish():
         # mask rows beyond the group's real size
-        rows = base + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
-        mask = rows < size
+        rows = jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
+        mask = rows < n_rows_ref[i]
         out_ref[...] = jnp.where(mask, out_acc_ref[...], 0.0).astype(
             out_ref.dtype
         )
@@ -145,9 +214,11 @@ def fused_swiglu_gmm(
     interpret: bool = False,
 ) -> jax.Array:
     """Raw pallas_call; use ops.swiglu_gmm_capacity for the user-facing
-    wrapper.  Same layout/scalar-prefetch contract as
+    wrapper.  Same layout contract as
     :func:`repro.kernels.grouped_gemm.grouped_gemm`; ``rhs_of_group``
-    defaults to the identity (group g uses expert g's weights).
+    defaults to the identity (group g uses expert g's weights).  Only
+    m-tiles with live rows are visited (:func:`gmm_fetch_tables`); the
+    rows of the others read zero.
 
     ``bn`` blocks the output d_model axis so the fp32 accumulator is
     ``(bm, bn)`` instead of the full ``(bm, d_model)``; the default (one
@@ -163,72 +234,104 @@ def fused_swiglu_gmm(
     assert wu.shape == wg.shape and wd.shape[:2] == (E, F), (
         wg.shape, wu.shape, wd.shape,
     )
-    m_tiles, n_tiles, f_tiles, k_tiles = M // bm, N // bn, F // bf, K // bk
+    n_tiles, f_tiles, k_tiles = N // bn, F // bf, K // bk
     if rhs_of_group is None:
         rhs_of_group = jnp.arange(group_sizes.shape[0], dtype=jnp.int32)
+    n_live, tile, w_row, n_rows = gmm_fetch_tables(
+        group_sizes.astype(jnp.int32), group_of_tile, row_in_group,
+        rhs_of_group.astype(jnp.int32), bm,
+    )
+    lhs_map, wg_map, wu_map, wd_map, out_map = gmm_index_maps(f_tiles, k_tiles)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(m_tiles, n_tiles, f_tiles, k_tiles),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, n, j, k, g, r, s, w: (i, k)),
-            pl.BlockSpec(
-                (1, bk, bf), lambda i, n, j, k, g, r, s, w: (w[g[i]], k, j)
+    def run(n_live, tile, w_row, n_rows, lhs, wg, wu, wd, zeros):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_live, n_tiles, f_tiles, k_tiles),
+            in_specs=[
+                pl.BlockSpec((bm, bk), lhs_map),
+                pl.BlockSpec((1, bk, bf), wg_map),
+                pl.BlockSpec((1, bk, bf), wu_map),
+                pl.BlockSpec((1, bf, bn), wd_map),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), out_map),
+            scratch_shapes=[
+                pltpu.VMEM((bm, bf), jnp.float32),
+                pltpu.VMEM((bm, bf), jnp.float32),
+                pltpu.VMEM((bm, F), jnp.float32),
+                pltpu.VMEM((bm, bn), jnp.float32),
+            ],
+        )
+        kernel = functools.partial(
+            _fused_swiglu_gmm_kernel,
+            n_k_tiles=k_tiles,
+            n_f_tiles=f_tiles,
+            bm=bm,
+            bf=bf,
+        )
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((M, N), lhs.dtype),
+            # operand 7: the zero buffer, after the three tables and the
+            # four arrays
+            input_output_aliases={7: 0},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=(
+                    "arbitrary", "arbitrary", "arbitrary", "arbitrary"
+                ),
             ),
-            pl.BlockSpec(
-                (1, bk, bf), lambda i, n, j, k, g, r, s, w: (w[g[i]], k, j)
-            ),
-            pl.BlockSpec(
-                (1, bf, bn), lambda i, n, j, k, g, r, s, w: (w[g[i]], j, n)
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (bm, bn), lambda i, n, j, k, g, r, s, w: (i, n)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((bm, bf), jnp.float32),
-            pltpu.VMEM((bm, bf), jnp.float32),
-            pltpu.VMEM((bm, F), jnp.float32),
-            pltpu.VMEM((bm, bn), jnp.float32),
-        ],
+            interpret=interpret,
+        )(tile, w_row, n_rows, lhs, wg, wu, wd, zeros)
+
+    return _skip_if_idle(
+        "swiglu_gmm_capacity", run, n_live, tile, w_row, n_rows, lhs, wg, wu,
+        wd, out_shape=(M, N), dtype=lhs.dtype,
     )
-    kernel = functools.partial(
-        _fused_swiglu_gmm_kernel,
-        n_k_tiles=k_tiles,
-        n_f_tiles=f_tiles,
-        n_n_tiles=n_tiles,
-        bm=bm,
-        bf=bf,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((M, N), lhs.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=(
-                "arbitrary", "arbitrary", "arbitrary", "arbitrary"
-            ),
-        ),
-        interpret=interpret,
-    )(
-        group_of_tile,
-        row_in_group,
-        group_sizes.astype(jnp.int32),
-        rhs_of_group.astype(jnp.int32),
-        lhs,
-        wg,
-        wu,
-        wd,
-    )
+
+
+# ---------------------------------------------------------------------------
+# Streaming tail path
+# ---------------------------------------------------------------------------
+
+
+def gemv_fetch_tables(expert_ids: jax.Array, valid: jax.Array):
+    """Scalar-prefetch tables of :func:`fused_swiglu_gemv`, one entry per
+    grid step: the valid rows first.  Returns ``(n_live, row, w_row)``:
+    the grid's bound, then per step the row it computes and the weight
+    row it streams.  Steps past ``n_live`` are never run."""
+    n_live, row = _live_first(valid > 0)
+    return n_live, row, expert_ids[row].astype(jnp.int32)
+
+
+def gemv_index_maps():
+    """Block index maps of :func:`fused_swiglu_gemv` over the grid ``(i,
+    j, k)`` and its tables ``(row, w_row)``: ``tokens``, ``wg``, ``wu``,
+    ``wd``, ``out``."""
+
+    def tok(i, j, k, r, w):
+        return r[i], 0, k
+
+    def gate_up(i, j, k, r, w):
+        return w[i], k, j
+
+    def down(i, j, k, r, w):
+        return w[i], j, 0
+
+    def out(i, j, k, r, w):
+        return r[i], 0, 0
+
+    return tok, gate_up, gate_up, down, out
 
 
 def _fused_swiglu_gemv_kernel(
-    expert_ids_ref,  # (S,) int32 scalar prefetch
-    valid_ref,  # (S,) int32 scalar prefetch (1 = live row)
+    row_ref,  # (S,) int32 scalar prefetch: row of each grid step
+    w_row_ref,  # (S,) int32 scalar prefetch: weight row of each grid step
     tok_ref,  # (1, bk)
     wg_ref,  # (1, bk, bf)
     wu_ref,  # (1, bk, bf)
     wd_ref,  # (1, bf, N)
+    zeros_ref,  # (S, 1, N) in HBM, aliased to the output
     out_ref,  # (1, N)
     gate_acc_ref,  # (1, bf) fp32
     up_acc_ref,  # (1, bf) fp32
@@ -237,7 +340,7 @@ def _fused_swiglu_gemv_kernel(
     n_k_tiles: int,
     n_f_tiles: int,
 ):
-    i = pl.program_id(0)
+    del row_ref, w_row_ref, zeros_ref
     j = pl.program_id(1)
     k = pl.program_id(2)
 
@@ -250,24 +353,20 @@ def _fused_swiglu_gemv_kernel(
     def _init_out():
         out_acc_ref[...] = jnp.zeros_like(out_acc_ref)
 
-    live = valid_ref[i] > 0
+    # (1, bk) x (bk, bf): weight-tile streaming dominates (the PIM
+    # regime); the row's activation stays in VMEM across all three
+    # projections.
+    t = tok_ref[...]
+    gate_acc_ref[...] += jax.lax.dot_general(
+        t, wg_ref[0], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    up_acc_ref[...] += jax.lax.dot_general(
+        t, wu_ref[0], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
 
-    @pl.when(live)
-    def _gate_up():
-        # (1, bk) x (bk, bf): weight-tile streaming dominates (the PIM
-        # regime); the row's activation stays in VMEM across all three
-        # projections.
-        t = tok_ref[...]
-        gate_acc_ref[...] += jax.lax.dot_general(
-            t, wg_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        up_acc_ref[...] += jax.lax.dot_general(
-            t, wu_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(live & (k == n_k_tiles - 1))
+    @pl.when(k == n_k_tiles - 1)
     def _activate_down():
         h = (
             jax.nn.silu(gate_acc_ref[...]) * up_acc_ref[...]
@@ -279,9 +378,7 @@ def _fused_swiglu_gemv_kernel(
 
     @pl.when((j == n_f_tiles - 1) & (k == n_k_tiles - 1))
     def _finish():
-        out_ref[...] = jnp.where(live, out_acc_ref[...], 0.0).astype(
-            out_ref.dtype
-        )
+        out_ref[...] = out_acc_ref[...].astype(out_ref.dtype)
 
 
 def fused_swiglu_gemv(
@@ -298,8 +395,9 @@ def fused_swiglu_gemv(
 ) -> jax.Array:
     """Raw pallas_call; use ops.swiglu_gemv for the user-facing wrapper.
 
-    Per token i: ``out[i] = swiglu(tokens[i]; wg/wu/wd[expert_ids[i]])`` —
-    each row's expert weights are streamed from HBM exactly once.
+    Per valid token i: ``out[i] = swiglu(tokens[i]; wg/wu/wd[expert_ids[i]])``
+    — each row's expert weights are streamed from HBM exactly once; an
+    invalid row is not visited (:func:`gemv_fetch_tables`) and reads zero.
 
     Tokens and output are viewed as ``(S, 1, K)`` / ``(S, 1, N)`` with the
     row axis squeezed out of the block, so each block's last two dims are
@@ -312,33 +410,45 @@ def fused_swiglu_gemv(
     bk, bf = min(bk, K), min(bf, F)
     assert K % bk == 0 and F % bf == 0, (K, F, bk, bf)
     k_tiles, f_tiles = K // bk, F // bf
+    n_live, row, w_row = gemv_fetch_tables(expert_ids, valid)
+    tok_map, wg_map, wu_map, wd_map, out_map = gemv_index_maps()
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, f_tiles, k_tiles),
-        in_specs=[
-            pl.BlockSpec((None, 1, bk), lambda i, j, k, e, v: (i, 0, k)),
-            pl.BlockSpec((1, bk, bf), lambda i, j, k, e, v: (e[i], k, j)),
-            pl.BlockSpec((1, bk, bf), lambda i, j, k, e, v: (e[i], k, j)),
-            pl.BlockSpec((1, bf, N), lambda i, j, k, e, v: (e[i], j, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, 1, N), lambda i, j, k, e, v: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, bf), jnp.float32),
-            pltpu.VMEM((1, bf), jnp.float32),
-            pltpu.VMEM((1, N), jnp.float32),
-        ],
+    def run(n_live, row, w_row, tokens, wg, wu, wd, zeros):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_live, f_tiles, k_tiles),
+            in_specs=[
+                pl.BlockSpec((None, 1, bk), tok_map),
+                pl.BlockSpec((1, bk, bf), wg_map),
+                pl.BlockSpec((1, bk, bf), wu_map),
+                pl.BlockSpec((1, bf, N), wd_map),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((None, 1, N), out_map),
+            scratch_shapes=[
+                pltpu.VMEM((1, bf), jnp.float32),
+                pltpu.VMEM((1, bf), jnp.float32),
+                pltpu.VMEM((1, N), jnp.float32),
+            ],
+        )
+        kernel = functools.partial(
+            _fused_swiglu_gemv_kernel, n_k_tiles=k_tiles, n_f_tiles=f_tiles
+        )
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((S, 1, N), tokens.dtype),
+            # operand 6: the zero buffer, after the two tables and the
+            # four arrays
+            input_output_aliases={6: 0},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            ),
+            interpret=interpret,
+        )(row, w_row, tokens, wg, wu, wd, zeros)
+
+    out = _skip_if_idle(
+        "swiglu_gemv", run, n_live, row, w_row, tokens.reshape(S, 1, K), wg,
+        wu, wd, out_shape=(S, 1, N), dtype=tokens.dtype,
     )
-    kernel = functools.partial(
-        _fused_swiglu_gemv_kernel, n_k_tiles=k_tiles, n_f_tiles=f_tiles
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, 1, N), tokens.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(expert_ids, valid, tokens.reshape(S, 1, K), wg, wu, wd)
     return out.reshape(S, N)

@@ -215,8 +215,9 @@ def swiglu_gmm_capacity(
     slab passes plus a full HBM round trip of the (G, C, F) intermediate
     for the three-call path) and the ``silu(gate) * up`` intermediate
     lives only in VMEM.  Same layout contract as :func:`gmm_capacity`
-    (C padded to a multiple of bm, dead tiles skip the MXU work,
-    ``rhs_of_group`` shares weights between groups).
+    (C padded to a multiple of bm, ``rhs_of_group`` shares weights
+    between groups); only m-tiles with live rows are visited, so a dead
+    group streams no weights.
     """
     if interpret is None:
         interpret = _interpret_default()
@@ -275,7 +276,8 @@ def swiglu_gemv(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Fused tail path: per-row SwiGLU with the expert's weight matrices
-    streamed once each (three :func:`expert_gemv` streams -> one)."""
+    streamed once each (three :func:`expert_gemv` streams -> one); an
+    invalid row is not visited and streams nothing."""
     if interpret is None:
         interpret = _interpret_default()
     S = tokens.shape[0]

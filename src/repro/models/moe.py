@@ -438,8 +438,8 @@ def _swiglu_grouped_pallas(slab, wg, wu, wd, sizes, rhs_of_group=None,
                            fused: Optional[bool] = None):
     """Head path: one single-pass fused SwiGLU grouped matmul over the
     capacity slab — the slab is read from HBM once and the SiLU
-    intermediate never leaves VMEM; tiles of dead rows skip their MXU
-    work inside the kernel.  ``fused=False`` runs the three-call
+    intermediate never leaves VMEM; only tiles with live rows are
+    visited, so a dead group streams no weights.  ``fused=False`` runs the three-call
     formulation (two slab reads + an HBM round trip of the (G, C, f)
     intermediate)."""
     from repro.kernels import ops

@@ -699,11 +699,14 @@ class ServingEngine:
             self._last_decode_batch = len(batch_reqs)
             self._last_kv_depth = int(position.max()) + 1
             if self.is_moe and counts.shape[0] > 0:
-                # the scheduler sees the held experts' columns; the counter
-                # counts the assignments they take
+                # the scheduler sees the held experts' columns; the counters
+                # count the assignments they take and the (layer, expert)
+                # weight passes of the expert kernels: one per expert with
+                # an assignment
                 moe = self.lm.arch.moe
                 held = counts[:, moe.held_offset:moe.held_offset + moe.n_held]
                 tel.counter("engine/moe_held_assignments", float(held.sum()))
+                tel.counter("engine/moe_expert_streams", float((held > 0).sum()))
                 with tel.span("engine/sieve_host"):
                     heads = self._run_sieve(held)
                 sieve_pass = (held, heads)

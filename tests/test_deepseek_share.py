@@ -393,3 +393,36 @@ def test_engine_schedules_the_held_experts():
                      "position": jnp.zeros((B,), jnp.int32), "sieve": eng._sieve_state},
         lm.init_cache(B, 64)).as_text(debug_info=True)
     assert "moe/group_route" in text
+
+
+@pytest.mark.parametrize("held,held_offset", [(0, 0), (4, 4)], ids=["full", "share"])
+def test_engine_counts_expert_streams(held, held_offset):
+    """Each decode step's ``engine/moe_expert_streams`` is the number of
+    (layer, expert) weight passes of the expert kernels: the experts with
+    at least one assignment in the counts the step hands the scheduler,
+    summed over the MoE layers, for the whole layer and for a held share."""
+    arch = _arch(held=held, held_offset=held_offset, exec_mode="dual_path_cost")
+    lm = LM(arch, dtype=jnp.float32)
+    tel = Telemetry(capacity=1 << 14, enabled=True)
+    eng = ServingEngine(lm, _tree(lm, 11), BatchingConfig(n_slots=2, max_seq=64),
+                        telemetry=tel)
+    streams = []
+    run_sieve = eng._run_sieve
+
+    def tap(counts):
+        streams.append(float(np.sum(np.asarray(counts) > 0)))
+        return run_sieve(counts)
+
+    eng._run_sieve = tap
+    eng.submit(Request(prompt=[3, 4, 5, 6], max_new_tokens=4))
+    eng.submit(Request(prompt=[7, 8, 9], max_new_tokens=3))
+    eng.run_until_done()
+    # one sample a decode step, carrying the running total
+    total = [e["value"] for e in tel.events()
+             if e["name"] == "engine/moe_expert_streams"]
+    steps = np.diff([0.0] + total).tolist()
+    assert streams and steps == streams
+    assert tel.counters()["engine/moe_expert_streams"] == sum(streams)
+    # at most one pass per held expert of each of the 2 MoE layers
+    assert sum(steps) > 0
+    assert all(0 <= s <= 2 * eng.layer_spec.n_experts for s in steps)

@@ -20,6 +20,7 @@ tests/test_kernels.py / tests/test_moe_dual.py:
 """
 
 import dataclasses
+import itertools
 import os
 import subprocess
 import sys
@@ -31,7 +32,7 @@ import pytest
 from _hypothesis_compat import given, settings, strategies as st
 
 from repro.configs import get_arch
-from repro.kernels import ops, ref
+from repro.kernels import fused_swiglu, ops, ref
 from repro.models.moe import (
     RouterOut,
     capacity,
@@ -281,6 +282,177 @@ class TestFusedSwigluGemv:
         np.testing.assert_allclose(
             np.asarray(gemv), np.asarray(gmm), rtol=1e-5, atol=1e-5
         )
+
+
+# ---------------------------------------------------------------------------
+# Live-only streaming: a dead group or row costs no grid step and no copy
+# ---------------------------------------------------------------------------
+
+# which of six experts have rows
+LIVENESS = {
+    "all_dead": [0, 0, 0, 0, 0, 0],
+    "all_live": [1, 1, 1, 1, 1, 1],
+    "leading_dead": [0, 0, 1, 1, 1, 1],
+    "trailing_dead": [1, 1, 1, 1, 0, 0],
+    "single_live": [0, 0, 0, 1, 0, 0],
+    "alternating": [1, 0, 1, 0, 1, 0],
+}
+# rows of each expert where it is live: with C = 20 and bm = 8 a group
+# spans three m-tiles, so 1 and 8 leave later tiles of a live group dead
+LIVE_ROWS = [20, 9, 1, 16, 8, 17]
+
+
+def _stacked_weights(key, L, E, K, F, N):
+    """Layer-stacked ``(L*E, ...)`` weights, as the decode program hands
+    the kernels."""
+    return _weights(key, L * E, K, F, N, jnp.float32)
+
+
+class TestLiveOnlyStreaming:
+    @pytest.mark.parametrize("layer", [0, 1])
+    @pytest.mark.parametrize("pattern", sorted(LIVENESS))
+    def test_gmm_against_oracle(self, pattern, layer):
+        """Head kernel at each liveness pattern, groups spanning three
+        m-tiles, weights read from layer ``layer`` of a two-layer stack
+        at row ``layer*E + e``: equal to the einsum oracle, and dead
+        groups and padding rows exactly zero."""
+        L, E, C, K, F, N = 2, 6, 20, 64, 64, 32
+        ks = jax.random.split(jax.random.PRNGKey(20), 2)
+        buf = jax.random.normal(ks[0], (E, C, K))
+        wg, wu, wd = _stacked_weights(ks[1], L, E, K, F, N)
+        sizes = jnp.asarray(LIVE_ROWS, jnp.int32) * jnp.asarray(LIVENESS[pattern])
+        rog = layer * E + jnp.arange(E, dtype=jnp.int32)
+        out = ops.swiglu_gmm_capacity(
+            buf, wg, wu, wd, sizes, rhs_of_group=rog, bm=8, bk=32, bf=32,
+            interpret=True,
+        )
+        sl = slice(layer * E, (layer + 1) * E)
+        exp = ref.fused_swiglu_gmm_ref(buf, wg[sl], wu[sl], wd[sl], sizes)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(exp), **_tol(jnp.float32)
+        )
+        dead = np.arange(C)[None, :] >= np.asarray(sizes)[:, None]
+        assert np.all(np.asarray(out)[dead] == 0.0)
+
+    @pytest.mark.parametrize("tau", [1, 2])
+    @pytest.mark.parametrize("pattern", sorted(LIVENESS))
+    def test_gemv_against_oracle(self, pattern, tau):
+        """Tail kernel at each liveness pattern, ``tau`` rows an expert
+        (the tail slab's layout), weights read from the second layer of a
+        two-layer stack: equal to the einsum oracle, invalid rows exactly
+        zero."""
+        L, E, K, F, N = 2, 6, 64, 64, 32
+        ks = jax.random.split(jax.random.PRNGKey(21), 2)
+        toks = jax.random.normal(ks[0], (E * tau, K))
+        wg, wu, wd = _stacked_weights(ks[1], L, E, K, F, N)
+        eids = E + jnp.repeat(jnp.arange(E, dtype=jnp.int32), tau)
+        live = jnp.repeat(jnp.asarray(LIVENESS[pattern], jnp.int32), tau)
+        valid = live.at[1::2].set(0) if tau > 1 else live  # a dead row of a live expert
+        out = ops.swiglu_gemv(
+            toks, wg, wu, wd, eids, valid, bk=32, bf=32, interpret=True
+        )
+        exp = ref.fused_swiglu_gemv_ref(toks, wg, wu, wd, eids, valid)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(exp), **_tol(jnp.float32)
+        )
+        assert np.all(np.asarray(out)[np.asarray(valid) == 0] == 0.0)
+
+
+def _block_fetches(maps, grid, tables):
+    """Weight blocks the TPU pipeline copies in: it issues a copy for an
+    operand only where the block index differs from the previous grid
+    step's.  Per map, the count of such changes over the grid in
+    row-major order, and the weight rows the fetched blocks belong to."""
+    tables = [np.asarray(t) for t in tables]
+    count, rows, last = [0] * len(maps), set(), [None] * len(maps)
+    for idx in itertools.product(*(range(int(g)) for g in grid)):
+        for m, fn in enumerate(maps):
+            block = tuple(int(b) for b in fn(*idx, *tables))
+            if block != last[m]:
+                count[m] += 1
+                rows.add(block[0])
+                last[m] = block
+    return count, rows
+
+
+# (E, C, d_model, d_expert): qwen3-30b-a3b decode at 48 slots, and the
+# DeepSeek-V2 share (20 held experts) at 128 slots
+WIDTHS = {"qwen3": (128, 48, 2048, 768), "deepseek_share": (20, 128, 5120, 1536)}
+
+
+class TestWeightFetches:
+    """CPU-checkable statement that a dead expert costs no weight copy:
+    over the grid each kernel runs, the weight index maps fed with the
+    kernel's fetch tables change block exactly once per block of each
+    live expert, and name no other expert's weights."""
+
+    def _live(self, E, C, seed):
+        rng = np.random.default_rng(seed)
+        hot = rng.choice(E, max(1, E // 8), replace=False)
+        sizes = np.zeros(E, np.int32)
+        sizes[hot] = rng.integers(2, C + 1, hot.size)
+        tail = rng.choice(np.flatnonzero(sizes == 0), E // 6, replace=False)
+        valid = np.zeros(E, np.int32)
+        valid[tail] = 1
+        return sizes, valid
+
+    @pytest.mark.parametrize("blocked_n", [False, True])
+    @pytest.mark.parametrize("cell", sorted(WIDTHS))
+    def test_head_fetches_each_live_expert_once(self, cell, blocked_n):
+        E, C, D, F = WIDTHS[cell]
+        sizes, _ = self._live(E, C, seed=1)
+        base = 3 * E  # layer 3 of the stacked weights
+        bm = ops._clamp_bm(128, C)
+        bk, bf = ops._fit_block(512, D), ops._fit_block(256, F)
+        bn = D // 4 if blocked_n else ops._fit_acc_bn(bm, D)
+        _, group_of_tile, row_in_group, bm, _ = ops._capacity_tiles(
+            jnp.zeros((E, C, 1)), bm
+        )
+        n_live, *tables = fused_swiglu.gmm_fetch_tables(
+            jnp.asarray(sizes), group_of_tile, row_in_group,
+            base + jnp.arange(E, dtype=jnp.int32), bm,
+        )
+        n_t, f_t, k_t = D // bn, F // bf, D // bk
+        _, wg_map, wu_map, wd_map, _ = fused_swiglu.gmm_index_maps(f_t, k_t)
+        live = int((sizes > 0).sum())
+        assert int(n_live) == live  # one m-tile a group: C <= bm
+        count, rows = _block_fetches(
+            [wg_map, wu_map, wd_map], (n_live, n_t, f_t, k_t), tables
+        )
+        assert count == [live * f_t * k_t, live * f_t * k_t, live * f_t * n_t]
+        assert rows == set((base + np.flatnonzero(sizes)).tolist())
+
+    @pytest.mark.parametrize("cell", sorted(WIDTHS))
+    def test_tail_fetches_each_live_expert_once(self, cell):
+        E, C, D, F = WIDTHS[cell]
+        _, valid = self._live(E, C, seed=2)
+        bk, bf = ops._fit_block(512, D), ops._fit_block(256, F)
+        n_live, *tables = fused_swiglu.gemv_fetch_tables(
+            jnp.arange(E, dtype=jnp.int32), jnp.asarray(valid)
+        )
+        f_t, k_t = F // bf, D // bk
+        _, wg_map, wu_map, wd_map, _ = fused_swiglu.gemv_index_maps()
+        live = int(valid.sum())
+        assert int(n_live) == live
+        count, rows = _block_fetches(
+            [wg_map, wu_map, wd_map], (n_live, f_t, k_t), tables
+        )
+        assert count == [live * f_t * k_t, live * f_t * k_t, live * f_t]
+        assert rows == set(np.flatnonzero(valid).tolist())
+
+    def test_nothing_live_runs_no_step(self):
+        """With no live group or row the grid bound is 0 (the call then
+        returns its zero buffer without issuing the kernel)."""
+        E, C = 8, 16
+        _, group_of_tile, row_in_group, bm, _ = ops._capacity_tiles(
+            jnp.zeros((E, C, 1)), 8
+        )
+        zero = jnp.zeros((E,), jnp.int32)
+        n_head, *_ = fused_swiglu.gmm_fetch_tables(
+            zero, group_of_tile, row_in_group, jnp.arange(E, dtype=jnp.int32), bm
+        )
+        n_tail, *_ = fused_swiglu.gemv_fetch_tables(jnp.arange(E), zero)
+        assert int(n_head) == 0 and int(n_tail) == 0
 
 
 # ---------------------------------------------------------------------------
