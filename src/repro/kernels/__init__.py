@@ -1,8 +1,7 @@
 # Pallas TPU kernels for the compute hot-spots of the Sieve runtime:
-#   fused_swiglu     — single-pass SwiGLU: grouped head + streaming tail,
-#                      gate/up/down in one kernel (the default dual path)
-#   grouped_gemm     — MXU path for popular experts (paper §6.3)
-#   expert_gemv      — streaming GEMV path for the 1-token tail (paper §6.2)
+#   fused_swiglu     — single-pass SwiGLU, gate/up/down in one kernel: the
+#                      grouped MXU head for popular experts (paper §6.3) and
+#                      the streaming GEMV tail for 1-token experts (§6.2)
 #   decode_attention — the memory-bound decode attention (paper §2.2)
 # ops.py holds the jit'd wrappers; ref.py the pure-jnp oracles.
 

@@ -1,7 +1,7 @@
 """Single-pass fused SwiGLU Pallas kernels (grouped GEMM + tail GEMV).
 
-The three-``pallas_call`` head path (``gate``/``up``/``down`` as separate
-grouped matmuls) reads the capacity slab from HBM twice and round-trips the
+The expert FFN is ``down(silu(gate(x)) * up(x))``.  Run as three separate
+matmuls it would read the capacity slab from HBM twice and round-trip the
 ``(G, C, d_expert)`` SiLU intermediate through HBM — exactly the bandwidth
 the Sieve intensity argument is about.  These kernels fuse the whole SwiGLU
 into one pass:
@@ -12,13 +12,12 @@ into one pass:
   the product straight into the down projection, accumulating the output
   row block across the f grid.  The capacity slab is streamed once per
   f-tile (F/bf slab passes; exactly one when d_expert fits a single
-  ``bf`` block — vs two full passes per n-tile sweep for the separate
-  gate/up calls), the ``(bm, bf)`` intermediate never leaves VMEM, and
+  ``bf`` block), the ``(bm, bf)`` intermediate never leaves VMEM, and
   only the final ``(bm, d_model)`` block is written to HBM.
 
 * :func:`fused_swiglu_gemv` — streaming tail path.  Each row streams its
   expert's ``wg``/``wu``/``wd`` tiles exactly once with the activation held
-  in-register (three ``pallas_call`` streams per row → one).
+  in-register.
 
 Only live work is visited.  The grid's first axis runs over the live
 m-tiles (head) or valid rows (tail) alone: :func:`gmm_fetch_tables` /
@@ -214,9 +213,10 @@ def fused_swiglu_gmm(
     interpret: bool = False,
 ) -> jax.Array:
     """Raw pallas_call; use ops.swiglu_gmm_capacity for the user-facing
-    wrapper.  Same layout contract as
-    :func:`repro.kernels.grouped_gemm.grouped_gemm`; ``rhs_of_group``
-    defaults to the identity (group g uses expert g's weights).  Only
+    wrapper.  Layout contract (built by the wrapper): rows are
+    group-major and each group's rows are padded to a multiple of ``bm``,
+    so no m-tile spans two groups; ``rhs_of_group`` defaults to the
+    identity (group g uses expert g's weights).  Only
     m-tiles with live rows are visited (:func:`gmm_fetch_tables`); the
     rows of the others read zero.
 

@@ -16,10 +16,8 @@ import jax.numpy as jnp
 
 from .decode_attention import decode_attention as _decode_attention
 from .decode_attention import decode_attention_paged as _decode_attention_paged
-from .expert_gemv import expert_gemv as _expert_gemv
 from .fused_swiglu import fused_swiglu_gemv as _fused_swiglu_gemv
 from .fused_swiglu import fused_swiglu_gmm as _fused_swiglu_gmm
-from .grouped_gemm import grouped_gemm as _grouped_gemm
 
 
 def _interpret_default() -> bool:
@@ -55,8 +53,8 @@ def _fit_block(b: int, dim: int) -> int:
     """Largest block size <= ``b`` that divides ``dim`` (k/n tile dims are
     not padded by the wrappers, so the block must divide exactly).  For
     power-of-two defaults this is gcd, which keeps the big power-of-two
-    factor — e.g. dim=768 (qwen3 d_expert) with the default b=512 -> 256
-    instead of the old ``min`` clamp's assert failure."""
+    factor — e.g. dim=768 (qwen3 d_expert) with the default b=512 -> 256,
+    where ``min`` alone would leave a block that does not divide it."""
     b = min(b, dim)
     if dim % b:
         b = math.gcd(b, dim)
@@ -64,17 +62,16 @@ def _fit_block(b: int, dim: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Grouped GEMM
+# Fused SwiGLU grouped GEMM (single-pass head path)
 # ---------------------------------------------------------------------------
 
 
 def _capacity_tiles(buf: jax.Array, bm: int):
-    """Shared capacity-layout prologue for the grouped kernels: clamp the
-    m-block to the (padded) capacity, pad C to a multiple of it, flatten
-    to group-major rows, and build the tile→group scalar-prefetch tables.
-    Returns ``(lhs, group_of_tile, row_in_group, bm, Cp)`` — one
-    implementation so the fused and unfused head paths can never
-    desynchronize on the layout contract."""
+    """Capacity-layout prologue of the head kernel: clamp the m-block to
+    the (padded) capacity, pad C to a multiple of it, flatten to
+    group-major rows, and build the tile→group scalar-prefetch tables.
+    Returns ``(lhs, group_of_tile, row_in_group, bm, Cp)``; each m-tile
+    then belongs to exactly one group."""
     G, C, K = buf.shape
     bm = _clamp_bm(bm, C)
     Cp = _round_up(C, bm)
@@ -92,101 +89,14 @@ def _capacity_tiles(buf: jax.Array, bm: int):
     return lhs, group_of_tile, row_in_group, bm, Cp
 
 
-@functools.partial(jax.jit, static_argnames=("group_padded", "bm", "bk", "bn", "interpret"))
-def gmm_capacity(
-    buf: jax.Array,  # (G, C, K) capacity-layout dispatch buffer
-    rhs: jax.Array,  # (E, K, N)
-    group_sizes: jax.Array,  # (G,) real rows per group
-    group_padded: int | None = None,
-    bm: int = 128,
-    bk: int = 512,
-    bn: int = 128,
-    interpret: bool | None = None,
-    rhs_of_group: jax.Array | None = None,  # (G,) weight row per group
-) -> jax.Array:
-    """Grouped GEMM over the (G, C, K) capacity buffer -> (G, C, N).
-
-    C is padded to a multiple of bm so each m-tile belongs to one group;
-    tiles with no live rows skip their MXU work.  Usually G == E and group
-    g multiplies ``rhs[g]``; pass ``rhs_of_group`` to let several groups
-    share one expert's weights (the EP a2a layout, where each (expert,
-    source-shard) segment is its own ragged group).
-    """
-    if interpret is None:
-        interpret = _interpret_default()
-    G, C, K = buf.shape
-    N = rhs.shape[2]
-    bk, bn = _fit_block(bk, K), _fit_block(bn, N)
-    lhs, group_of_tile, row_in_group, bm, Cp = _capacity_tiles(buf, bm)
-    out = _grouped_gemm(
-        lhs, rhs, group_sizes.astype(jnp.int32), group_of_tile, row_in_group,
-        rhs_of_group,
-        bm=bm, bk=bk, bn=bn, interpret=interpret,
-    )
-    return out.reshape(G, Cp, N)[:, :C, :]
-
-
-@functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "interpret"))
-def gmm_ragged(
-    lhs: jax.Array,  # (M, K) expert-major rows, group starts bm-aligned
-    rhs: jax.Array,  # (E, K, N)
-    group_sizes: jax.Array,  # (E,) real rows per group (dynamic)
-    bm: int = 128,
-    bk: int = 512,
-    bn: int = 128,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """True ragged grouped matmul: dynamic group sizes, bm-aligned layout.
-
-    Layout: group g occupies rows [g_start, g_start + padded_size(g)) with
-    padded_size = round_up(size, bm); M must equal sum of padded sizes.
-    """
-    if interpret is None:
-        interpret = _interpret_default()
-    M, K = lhs.shape
-    E = rhs.shape[0]
-    bm = min(bm, M)
-    assert bm % _SUBLANE == 0, (
-        f"gmm_ragged: bm={bm} is not a sublane multiple ({_SUBLANE}); the "
-        "caller-built layout must use an aligned block size"
-    )
-    bk, bn = _fit_block(bk, K), _fit_block(bn, rhs.shape[2])
-    padded = ((group_sizes + bm - 1) // bm) * bm
-    tile_counts = padded // bm
-    m_tiles = M // bm
-    # tile -> group: searchsorted over cumulative tile counts
-    cum_tiles = jnp.cumsum(tile_counts)
-    tile_idx = jnp.arange(m_tiles, dtype=jnp.int32)
-    group_of_tile = jnp.searchsorted(cum_tiles, tile_idx, side="right").astype(
-        jnp.int32
-    )
-    group_of_tile = jnp.minimum(group_of_tile, E - 1)
-    tile_start_of_group = jnp.concatenate(
-        [jnp.zeros(1, jnp.int32), cum_tiles[:-1].astype(jnp.int32)]
-    )
-    row_in_group = (tile_idx - tile_start_of_group[group_of_tile]) * bm
-    return _grouped_gemm(
-        lhs, rhs, group_sizes.astype(jnp.int32), group_of_tile, row_in_group,
-        bm=bm, bk=bk, bn=bn, interpret=interpret,
-    )
-
-
-# ---------------------------------------------------------------------------
-# Fused SwiGLU grouped GEMM (single-pass head path)
-# ---------------------------------------------------------------------------
-
-
 # Soft cap for the fused-SwiGLU fp32 output accumulator: when the full
 # (bm, d_model) block would exceed this many bytes, the n axis is blocked
 # so large-d_model configs still fit VMEM.  qwen3-30b (bm=128, N=2048,
 # 1 MB) stays a single n-tile — identical schedule to the unblocked kernel.
-_SWIGLU_ACC_BUDGET = int(
-    os.environ.get("REPRO_SWIGLU_ACC_BUDGET", 4 * 1024 * 1024)
-)
+_SWIGLU_ACC_BUDGET = 4 * 1024 * 1024
 
 
-def _fit_acc_bn(bm: int, n: int, budget: int = 0) -> int:
-    budget = budget or _SWIGLU_ACC_BUDGET
+def _fit_acc_bn(bm: int, n: int, budget: int = _SWIGLU_ACC_BUDGET) -> int:
     bn = n
     while bn > 128 and bm * bn * 4 > budget:
         bn //= 2
@@ -209,15 +119,14 @@ def swiglu_gmm_capacity(
 ) -> jax.Array:
     """Single-pass SwiGLU over the (G, C, K) capacity buffer -> (G, C, N).
 
-    Fuses the three ``gmm_capacity`` calls of the head path into one
-    kernel: the slab is streamed from HBM once per f-tile (F/bf passes —
-    exactly once when the expert dim fits one ``bf`` block — vs 2·F/bn
-    slab passes plus a full HBM round trip of the (G, C, F) intermediate
-    for the three-call path) and the ``silu(gate) * up`` intermediate
-    lives only in VMEM.  Same layout contract as :func:`gmm_capacity`
-    (C padded to a multiple of bm, ``rhs_of_group`` shares weights
-    between groups); only m-tiles with live rows are visited, so a dead
-    group streams no weights.
+    Gate, up and down projections in one kernel: the slab is streamed
+    from HBM once per f-tile (F/bf passes — exactly once when the expert
+    dim fits one ``bf`` block) and the ``silu(gate) * up`` intermediate
+    lives only in VMEM.  C is padded to a multiple of bm so each m-tile
+    belongs to one group; group g uses expert g's weights unless
+    ``rhs_of_group`` maps several groups to one expert (the EP a2a
+    layout, one ragged group per (expert, source shard)).  Only m-tiles
+    with live rows are visited, so a dead group streams no weights.
     """
     if interpret is None:
         interpret = _interpret_default()
@@ -235,34 +144,6 @@ def swiglu_gmm_capacity(
     return out.reshape(G, Cp, N)[:, :C, :]
 
 
-# ---------------------------------------------------------------------------
-# Expert GEMV (the TPU "PIM path")
-# ---------------------------------------------------------------------------
-
-
-@functools.partial(jax.jit, static_argnames=("bk", "bn", "interpret"))
-def expert_gemv(
-    tokens: jax.Array,  # (S, K)
-    weights: jax.Array,  # (E, K, N)
-    expert_ids: jax.Array,  # (S,) int32
-    valid: jax.Array | None = None,  # (S,) bool/int
-    bk: int = 512,
-    bn: int = 512,
-    interpret: bool | None = None,
-) -> jax.Array:
-    if interpret is None:
-        interpret = _interpret_default()
-    S = tokens.shape[0]
-    bk = _fit_block(bk, tokens.shape[1])
-    bn = _fit_block(bn, weights.shape[2])
-    if valid is None:
-        valid = jnp.ones((S,), jnp.int32)
-    return _expert_gemv(
-        tokens, weights, expert_ids.astype(jnp.int32), valid.astype(jnp.int32),
-        bk=bk, bn=bn, interpret=interpret,
-    )
-
-
 @functools.partial(jax.jit, static_argnames=("bk", "bf", "interpret"))
 def swiglu_gemv(
     tokens: jax.Array,  # (S, K)
@@ -275,9 +156,9 @@ def swiglu_gemv(
     bf: int = 256,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Fused tail path: per-row SwiGLU with the expert's weight matrices
-    streamed once each (three :func:`expert_gemv` streams -> one); an
-    invalid row is not visited and streams nothing."""
+    """Fused tail path: per-row SwiGLU with the row's expert's three
+    weight matrices streamed once each; an invalid row is not visited and
+    streams nothing."""
     if interpret is None:
         interpret = _interpret_default()
     S = tokens.shape[0]

@@ -6,34 +6,6 @@ import jax
 import jax.numpy as jnp
 
 
-def grouped_gemm_ref(
-    lhs: jax.Array,  # (M, K) expert-major rows, groups bm-aligned
-    rhs: jax.Array,  # (E, K, N)
-    group_sizes: jax.Array,  # (E,) real rows per group
-    group_padded: int,  # padded rows per group (M == E * group_padded)
-) -> jax.Array:
-    """Segment matmul over an aligned expert-major layout; padding rows -> 0."""
-    E, K, N = rhs.shape
-    M = lhs.shape[0]
-    assert M == E * group_padded
-    x = lhs.reshape(E, group_padded, K).astype(jnp.float32)
-    y = jnp.einsum("egk,ekn->egn", x, rhs.astype(jnp.float32))
-    rows = jnp.arange(group_padded)[None, :, None]
-    mask = rows < group_sizes[:, None, None]
-    return (y * mask).reshape(M, N).astype(lhs.dtype)
-
-
-def expert_gemv_ref(
-    tokens: jax.Array,  # (S, K)
-    weights: jax.Array,  # (E, K, N)
-    expert_ids: jax.Array,  # (S,)
-    valid: jax.Array,  # (S,)
-) -> jax.Array:
-    w = weights[expert_ids]  # (S, K, N)
-    y = jnp.einsum("sk,skn->sn", tokens.astype(jnp.float32), w.astype(jnp.float32))
-    return (y * (valid > 0)[:, None]).astype(tokens.dtype)
-
-
 def fused_swiglu_gmm_ref(
     buf: jax.Array,  # (G, C, K) capacity-layout dispatch buffer
     wg: jax.Array,  # (E, K, F)

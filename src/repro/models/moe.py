@@ -21,10 +21,10 @@ Design (DESIGN.md §5, §8.2):
 * **Sieve integration**: per-layer expert token counts are computed in-graph
   and exposed to the serving engine (which feeds the EMA cost table and the
   Sieve scheduler).  ``expert_exec="dual_path"`` routes 1-few-token
-  ("tail") experts through the streaming GEMV path (kernels/expert_gemv)
-  and popular ("head") experts through grouped GEMMs
-  (kernels/grouped_gemm) — the TPU adaptation of the paper's PIM/GPU split
-  (DESIGN.md §2).  The split is computed in-graph from the routed counts
+  ("tail") experts through a streaming SwiGLU GEMV and popular ("head")
+  experts through a grouped SwiGLU GEMM (both in kernels/fused_swiglu,
+  with XLA twins off the TPU) — the TPU adaptation of the paper's PIM/GPU
+  split (DESIGN.md §2).  The split is computed in-graph from the routed counts
   (:func:`repro.core.scheduler_jax.dual_path_split`): counts-driven, no
   host sync on the decode critical path.  ``expert_exec="dense"`` keeps the
   one-einsum capacity path as the bit-level reference oracle.
@@ -186,11 +186,11 @@ def capacity(T: int, cfg: MoEConfig, n_experts: int) -> int:
 
 # Counting-scatter dispatch does Theta(Tk * nE) work/memory for its
 # running-counter cumsum; the stable argsort it replaces is
-# O(Tk log Tk).  The crossover measured on the bench arch (E=128, k=8)
-# sits around Tk*(nE+1) ~ 4M elements (a ~16 MB int32 intermediate):
-# below it — every decode/serving-sized batch — the counters win (the
-# moe_bench `dispatch_ms` cells track this); above it — prefill-scale
-# batches — the sort stays faster, so dispatch falls back to it.  Both
+# O(Tk log Tk).  The crossover was set from CPU timings at E=128, k=8,
+# before the program ran on a chip: around Tk*(nE+1) ~ 4M elements (a
+# ~16 MB int32 intermediate), below which the counters won and above
+# which the sort did.  No benchmark cell reaches it (qwen3's 512-token
+# prefill is 0.53M elements), so on the chip it is unmeasured.  Both
 # formulations are bit-identical, so the switch is purely a cost choice
 # made at trace time (shapes are static under jit).
 _COUNTING_DISPATCH_MAX_ELEMS = 4_000_000
@@ -422,40 +422,6 @@ def _dual_backend() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
-def _fused_swiglu_default() -> bool:
-    """The head/tail Pallas paths run the single-pass fused SwiGLU kernels
-    by default; ``REPRO_FUSED_SWIGLU=0`` falls back to the three-call
-    (gate/up/down as separate ``pallas_call``s) formulation — kept for
-    A/B benchmarking (``moe_bench``'s fused cells) and as the fused
-    kernels' equivalence oracle."""
-    env = os.environ.get("REPRO_FUSED_SWIGLU")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return True
-
-
-def _swiglu_grouped_pallas(slab, wg, wu, wd, sizes, rhs_of_group=None,
-                           fused: Optional[bool] = None):
-    """Head path: one single-pass fused SwiGLU grouped matmul over the
-    capacity slab — the slab is read from HBM once and the SiLU
-    intermediate never leaves VMEM; only tiles with live rows are
-    visited, so a dead group streams no weights.  ``fused=False`` runs the three-call
-    formulation (two slab reads + an HBM round trip of the (G, C, f)
-    intermediate)."""
-    from repro.kernels import ops
-
-    if fused is None:
-        fused = _fused_swiglu_default()
-    if fused:
-        return ops.swiglu_gmm_capacity(
-            slab, wg, wu, wd, sizes, rhs_of_group=rhs_of_group
-        )
-    gate = ops.gmm_capacity(slab, wg, sizes, rhs_of_group=rhs_of_group)
-    up = ops.gmm_capacity(slab, wu, sizes, rhs_of_group=rhs_of_group)
-    h = jax.nn.silu(gate) * up
-    return ops.gmm_capacity(h, wd, sizes, rhs_of_group=rhs_of_group)
-
-
 def _swiglu_grouped_xla(slab, wg, wu, wd, sizes, rhs_of_group=None):
     """XLA twin of the grouped head path (einsum + live-row mask)."""
     if rhs_of_group is not None:
@@ -470,25 +436,6 @@ def _swiglu_grouped_xla(slab, wg, wu, wd, sizes, rhs_of_group=None):
     return y * live[..., None].astype(y.dtype)
 
 
-def _swiglu_gemv_pallas(toks, wg, wu, wd, eids, valid,
-                        fused: Optional[bool] = None):
-    """Tail path: each row streams its expert's weights (the PIM proxy).
-
-    Fused by default: one kernel streams ``wg``/``wu``/``wd`` once per
-    row with the activation in-register (three GEMV streams -> one);
-    ``fused=False`` keeps the three-call stream for A/B comparison."""
-    from repro.kernels import ops
-
-    if fused is None:
-        fused = _fused_swiglu_default()
-    if fused:
-        return ops.swiglu_gemv(toks, wg, wu, wd, eids, valid)
-    gate = ops.expert_gemv(toks, wg, eids, valid)
-    up = ops.expert_gemv(toks, wu, eids, valid)
-    h = jax.nn.silu(gate) * up
-    return ops.expert_gemv(h, wd, eids, valid)
-
-
 def _tail_path(slab, wg, wu, wd, e_of_g, valid, backend, gather_w: bool):
     """Shared tail executor over the (G, tau, d) per-group slab.
 
@@ -497,9 +444,13 @@ def _tail_path(slab, wg, wu, wd, e_of_g, valid, backend, gather_w: bool):
     identity gather would only copy the weights)."""
     G, tau, d = slab.shape
     if backend == "pallas":
+        # imported where a Pallas path is traced, not with this module: at
+        # module level it added 4-6 s to start-up on a TPU v5e host
+        from repro.kernels import ops as kernel_ops
+
         toks = slab.reshape(G * tau, d)
         eids = jnp.repeat(e_of_g, tau)
-        ty = _swiglu_gemv_pallas(
+        ty = kernel_ops.swiglu_gemv(
             toks, wg, wu, wd, eids, valid.reshape(G * tau).astype(jnp.int32)
         )
         return ty.reshape(G, tau, d)
@@ -535,7 +486,9 @@ def tail_stage(toks, wg, wu, wd, eids, valid, backend: Optional[str] = None):
     if backend is None:
         backend = _dual_backend()
     if backend == "pallas":
-        return _swiglu_gemv_pallas(toks, wg, wu, wd, eids, valid)
+        from repro.kernels import ops as kernel_ops
+
+        return kernel_ops.swiglu_gemv(toks, wg, wu, wd, eids, valid)
     we_g, we_u, we_d = wg[eids], wu[eids], wd[eids]
     g = jnp.einsum("td,tdf->tf", toks, we_g)
     u = jnp.einsum("td,tdf->tf", toks, we_u)
@@ -556,7 +509,9 @@ def head_stage(slab, wg, wu, wd, sizes, backend: Optional[str] = None):
     if backend is None:
         backend = _dual_backend()
     if backend == "pallas":
-        return _swiglu_grouped_pallas(slab, wg, wu, wd, sizes)
+        from repro.kernels import ops as kernel_ops
+
+        return kernel_ops.swiglu_gmm_capacity(slab, wg, wu, wd, sizes)
     return _swiglu_grouped_xla(slab, wg, wu, wd, sizes)
 
 
@@ -596,10 +551,10 @@ def experts_ffn_dual(
     """Runtime sieve-split dual-path expert execution.
 
     Splits the experts on the in-graph prefix rule: experts with more than
-    ``cfg.dual_tail_tokens`` buffered rows form the *head* and run as three
-    grouped matmuls over their capacity slabs (compacted to the
+    ``cfg.dual_tail_tokens`` buffered rows form the *head* and run as one
+    grouped SwiGLU over their capacity slabs (compacted to the
     ``cfg.dual_max_head`` most popular experts when a budget is set); the
-    remaining *tail* experts stream their rows through the expert-GEMV
+    remaining *tail* experts stream their rows through the SwiGLU GEMV
     kernel.  Under ``expert_exec="dual_path"`` the boundary is the fixed
     threshold (:func:`dual_path_split`); under ``"dual_path_cost"`` it is
     the cost-model argmin over the ``sieve`` state
@@ -645,7 +600,9 @@ def experts_ffn_dual(
             slab, head_sizes, rhs = buf, head_sizes_full, None
 
         if backend == "pallas":
-            y_head = _swiglu_grouped_pallas(
+            from repro.kernels import ops as kernel_ops
+
+            y_head = kernel_ops.swiglu_gmm_capacity(
                 slab, wg, wu, wd, head_sizes,
                 rhs_of_group=w_row if rhs is None else rhs,
             )
@@ -738,7 +695,9 @@ def experts_ffn_dual_segmented(
             slab, head_sizes, rhs = slab_full, head_sizes_full, e_of_g
 
         if backend == "pallas":
-            y_head = _swiglu_grouped_pallas(
+            from repro.kernels import ops as kernel_ops
+
+            y_head = kernel_ops.swiglu_gmm_capacity(
                 slab, wg, wu, wd, head_sizes, rhs_of_group=rhs
             )
         else:

@@ -1,218 +1,12 @@
-"""Pallas kernel sweeps vs pure-jnp oracles (interpret mode on CPU)."""
+"""Decode-attention kernel sweeps vs pure-jnp oracles (interpret mode on
+CPU); the expert kernels are in tests/test_fused_swiglu.py."""
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
 
 from repro.kernels import ops, ref
-
-
-def _tol(dtype):
-    return dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 else dict(
-        rtol=1e-5, atol=1e-5
-    )
-
-
-class TestGroupedGemmCapacity:
-    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    @pytest.mark.parametrize(
-        "E,C,K,N,bm", [(4, 16, 64, 96, 8), (8, 8, 128, 128, 8), (2, 32, 32, 64, 16)]
-    )
-    def test_against_oracle(self, dtype, E, C, K, N, bm):
-        ks = jax.random.split(jax.random.PRNGKey(0), 3)
-        buf = jax.random.normal(ks[0], (E, C, K), dtype)
-        rhs = jax.random.normal(ks[1], (E, K, N), dtype)
-        sizes = jax.random.randint(ks[2], (E,), 0, C + 1)
-        out = ops.gmm_capacity(buf, rhs, sizes, bm=bm, bk=32, bn=32, interpret=True)
-        exp = ref.grouped_gemm_ref(buf.reshape(E * C, K), rhs, sizes, C)
-        np.testing.assert_allclose(
-            np.asarray(out.reshape(E * C, N), np.float32),
-            np.asarray(exp, np.float32),
-            **_tol(dtype),
-        )
-
-    def test_empty_groups_produce_zeros(self):
-        E, C, K, N = 3, 8, 32, 32
-        buf = jnp.ones((E, C, K))
-        rhs = jnp.ones((E, K, N))
-        sizes = jnp.array([0, 8, 0])
-        out = ops.gmm_capacity(buf, rhs, sizes, bm=8, bk=32, bn=32, interpret=True)
-        assert float(jnp.abs(out[0]).max()) == 0.0
-        assert float(jnp.abs(out[2]).max()) == 0.0
-        assert float(jnp.abs(out[1]).max()) > 0.0
-
-    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    def test_all_groups_empty(self, dtype):
-        """Degenerate ragged case: every expert idle -> all-zero output."""
-        E, C, K, N = 4, 8, 32, 32
-        buf = jnp.ones((E, C, K), dtype)
-        rhs = jnp.ones((E, K, N), dtype)
-        out = ops.gmm_capacity(
-            buf, rhs, jnp.zeros((E,), jnp.int32), bm=8, bk=32, bn=32,
-            interpret=True,
-        )
-        assert float(jnp.abs(out).max()) == 0.0
-
-    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    def test_all_rows_one_expert(self, dtype):
-        """The other ragged extreme: one expert owns every live row."""
-        E, C, K, N = 4, 16, 32, 32
-        ks = jax.random.split(jax.random.PRNGKey(5), 2)
-        buf = jax.random.normal(ks[0], (E, C, K), dtype)
-        rhs = jax.random.normal(ks[1], (E, K, N), dtype)
-        sizes = jnp.zeros((E,), jnp.int32).at[2].set(C)
-        out = ops.gmm_capacity(buf, rhs, sizes, bm=8, bk=32, bn=32, interpret=True)
-        exp = ref.grouped_gemm_ref(buf.reshape(E * C, K), rhs, sizes, C)
-        np.testing.assert_allclose(
-            np.asarray(out.reshape(E * C, N), np.float32),
-            np.asarray(exp, np.float32),
-            **_tol(dtype),
-        )
-
-    @pytest.mark.parametrize("C", [4, 12, 20, 100])
-    def test_bm_clamp_small_capacity(self, C):
-        """Regression (ops.py clamp): C < 128 with the default bm used to
-        produce a non-sublane-aligned block size (e.g. bm=12)."""
-        E, K, N = 3, 32, 32
-        ks = jax.random.split(jax.random.PRNGKey(6), 3)
-        buf = jax.random.normal(ks[0], (E, C, K))
-        rhs = jax.random.normal(ks[1], (E, K, N))
-        sizes = jax.random.randint(ks[2], (E,), 0, C + 1)
-        out = ops.gmm_capacity(buf, rhs, sizes, bk=32, bn=32, interpret=True)
-        exp = ref.grouped_gemm_ref(buf.reshape(E * C, K), rhs, sizes, C)
-        np.testing.assert_allclose(
-            np.asarray(out.reshape(E * C, N)), np.asarray(exp),
-            rtol=1e-5, atol=1e-5,
-        )
-
-    def test_clamp_bm_is_sublane_aligned(self):
-        for bm in (8, 16, 128):
-            for rows in (1, 4, 7, 8, 12, 100, 128, 1000):
-                got = ops._clamp_bm(bm, rows)
-                assert got % ops._SUBLANE == 0 and got >= ops._SUBLANE
-
-    def test_default_blocks_fit_nonpow2_dims(self):
-        """Regression: qwen3-class dims (d_expert=768) with the default
-        bk=512 used to trip the K % bk assert in grouped_gemm."""
-        assert ops._fit_block(512, 768) == 256
-        assert ops._fit_block(512, 512) == 512
-        assert ops._fit_block(128, 96) == 96
-        E, C, K, N = 2, 8, 768, 128
-        ks = jax.random.split(jax.random.PRNGKey(10), 3)
-        buf = jax.random.normal(ks[0], (E, C, K))
-        rhs = jax.random.normal(ks[1], (E, K, N))
-        sizes = jax.random.randint(ks[2], (E,), 0, C + 1)
-        out = ops.gmm_capacity(buf, rhs, sizes, interpret=True)  # defaults
-        exp = ref.grouped_gemm_ref(buf.reshape(E * C, K), rhs, sizes, C)
-        np.testing.assert_allclose(
-            np.asarray(out.reshape(E * C, N)), np.asarray(exp),
-            rtol=1e-4, atol=1e-4,
-        )
-
-    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    def test_rhs_of_group_shared_weights(self, dtype):
-        """Segmented EP layout: several ragged groups share one expert's
-        weights through the prefetched rhs_of_group table."""
-        E, S, C, K, N = 3, 2, 8, 32, 32
-        G = E * S
-        ks = jax.random.split(jax.random.PRNGKey(7), 3)
-        buf = jax.random.normal(ks[0], (G, C, K), dtype)
-        rhs = jax.random.normal(ks[1], (E, K, N), dtype)
-        sizes = jax.random.randint(ks[2], (G,), 0, C + 1)
-        rog = jnp.repeat(jnp.arange(E, dtype=jnp.int32), S)
-        out = ops.gmm_capacity(
-            buf, rhs, sizes, bm=8, bk=32, bn=32, interpret=True,
-            rhs_of_group=rog,
-        )
-        exp = ref.grouped_gemm_ref(buf.reshape(G * C, K), rhs[rog], sizes, C)
-        np.testing.assert_allclose(
-            np.asarray(out.reshape(G * C, N), np.float32),
-            np.asarray(exp, np.float32),
-            **_tol(dtype),
-        )
-
-
-class TestGroupedGemmRagged:
-    @given(
-        sizes=st.lists(st.integers(min_value=0, max_value=20), min_size=2, max_size=6),
-        seed=st.integers(0, 2**16),
-    )
-    @settings(max_examples=12, deadline=None)
-    def test_ragged_random_groups(self, sizes, seed):
-        bm, K, N = 8, 32, 32
-        E = len(sizes)
-        sizes = jnp.asarray(sizes, jnp.int32)
-        padded = ((sizes + bm - 1) // bm) * bm
-        M = max(int(padded.sum()), bm)
-        if int(padded.sum()) == 0:
-            return
-        ks = jax.random.split(jax.random.PRNGKey(seed), 2)
-        lhs = jax.random.normal(ks[0], (int(padded.sum()), K), jnp.float32)
-        rhs = jax.random.normal(ks[1], (E, K, N), jnp.float32)
-        out = ops.gmm_ragged(lhs, rhs, sizes, bm=bm, bk=32, bn=32, interpret=True)
-        starts = np.concatenate([[0], np.cumsum(np.asarray(padded))[:-1]])
-        exp = np.zeros((lhs.shape[0], N), np.float32)
-        for g in range(E):
-            s, sz = int(starts[g]), int(sizes[g])
-            exp[s : s + sz] = np.asarray(lhs[s : s + sz] @ rhs[g])
-        np.testing.assert_allclose(np.asarray(out), exp, rtol=1e-4, atol=1e-4)
-
-
-class TestExpertGemv:
-    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    @pytest.mark.parametrize("S,E,K,N", [(5, 4, 64, 96), (16, 8, 128, 64), (1, 2, 32, 32)])
-    def test_against_oracle(self, dtype, S, E, K, N):
-        ks = jax.random.split(jax.random.PRNGKey(1), 3)
-        toks = jax.random.normal(ks[0], (S, K), dtype)
-        w = jax.random.normal(ks[1], (E, K, N), dtype)
-        eids = jax.random.randint(ks[2], (S,), 0, E)
-        valid = jnp.ones((S,), jnp.int32).at[0].set(0) if S > 2 else jnp.ones((S,), jnp.int32)
-        out = ops.expert_gemv(toks, w, eids, valid, bk=32, bn=32, interpret=True)
-        exp = ref.expert_gemv_ref(toks, w, eids, valid)
-        np.testing.assert_allclose(
-            np.asarray(out, np.float32), np.asarray(exp, np.float32), **_tol(dtype)
-        )
-
-    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    def test_all_tokens_one_expert(self, dtype):
-        S, E, K, N = 12, 4, 64, 32
-        ks = jax.random.split(jax.random.PRNGKey(8), 2)
-        toks = jax.random.normal(ks[0], (S, K), dtype)
-        w = jax.random.normal(ks[1], (E, K, N), dtype)
-        eids = jnp.full((S,), 1, jnp.int32)
-        out = ops.expert_gemv(toks, w, eids, None, bk=32, bn=32, interpret=True)
-        exp = ref.expert_gemv_ref(toks, w, eids, jnp.ones((S,), jnp.int32))
-        np.testing.assert_allclose(
-            np.asarray(out, np.float32), np.asarray(exp, np.float32), **_tol(dtype)
-        )
-
-    def test_all_rows_invalid_produce_zeros(self):
-        S, E, K, N = 6, 3, 32, 32
-        ks = jax.random.split(jax.random.PRNGKey(9), 2)
-        toks = jax.random.normal(ks[0], (S, K))
-        w = jax.random.normal(ks[1], (E, K, N))
-        eids = jnp.zeros((S,), jnp.int32)
-        out = ops.expert_gemv(
-            toks, w, eids, jnp.zeros((S,), jnp.int32), bk=32, bn=32,
-            interpret=True,
-        )
-        assert float(jnp.abs(out).max()) == 0.0
-
-    def test_matches_grouped_gemm_for_single_token_experts(self):
-        """The Sieve dual-path invariant: GEMV path == grouped path for
-        1-token experts (same math, different kernel)."""
-        E, K, N = 4, 64, 64
-        ks = jax.random.split(jax.random.PRNGKey(2), 2)
-        toks = jax.random.normal(ks[0], (E, K), jnp.float32)
-        w = jax.random.normal(ks[1], (E, K, N), jnp.float32)
-        eids = jnp.arange(E, dtype=jnp.int32)
-        gemv = ops.expert_gemv(toks, w, eids, None, bk=32, bn=32, interpret=True)
-        buf = toks[:, None, :]  # (E, C=1, K)
-        gmm = ops.gmm_capacity(buf, w, jnp.ones(E, jnp.int32), bm=8, bk=32, bn=32,
-                               interpret=True)[:, 0]
-        np.testing.assert_allclose(np.asarray(gemv), np.asarray(gmm), rtol=1e-5, atol=1e-5)
 
 
 class TestDecodeAttention:

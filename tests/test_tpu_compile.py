@@ -103,10 +103,6 @@ def _kernel_case(name, sds):
             gemv, (sds((DS_E, DS_D)), ds_in, ds_in, ds_out, sds((DS_E,), I32),
                    sds((DS_E,), I32)),
         ),
-        "expert_gemv": (
-            lambda t, w, e, v: ops.expert_gemv(t, w, e, v, interpret=False),
-            (toks, w_in, eids, eids),
-        ),
         "decode_attention": (
             lambda q, k, v, n: ops.decode_attention(q, k, v, n, interpret=False),
             (q, cache, cache, lens),
@@ -135,7 +131,6 @@ def _kernel_case(name, sds):
         "swiglu_gmm_capacity.qwen3_prefill",
         "swiglu_gmm_capacity.deepseek_share",
         "swiglu_gemv.deepseek_share",
-        "expert_gemv",
         "decode_attention",
         "decode_attention_split4",
         "decode_attention_paged",
