@@ -187,8 +187,9 @@ def kernel_checks(arch) -> None:
 
 
 class Tap:
-    """Wraps an engine's compiled step; keeps the inputs and logits of the
-    first ``keep`` calls as host arrays (everything else passes through)."""
+    """Wraps an engine's compiled step; keeps the inputs, logits and picked
+    ids of the first ``keep`` calls as host arrays (everything else passes
+    through)."""
 
     def __init__(self, fn, keep: int, inputs):
         self.fn, self.keep, self.inputs, self.calls = fn, keep, inputs, []
@@ -198,7 +199,9 @@ class Tap:
         if len(self.calls) < self.keep:
             import numpy as np
 
-            self.calls.append((self.inputs(*args), np.asarray(out[0])))
+            ids, logits = out[0]
+            self.calls.append((self.inputs(*args), np.asarray(logits),
+                               np.asarray(ids)))
         return out
 
     def __getattr__(self, name):
@@ -295,14 +298,14 @@ def replay(engine, pre: Tap, dec: Tap):
     # a fresh engine's cost-split state is the one the recorded calls saw
     sieve = {"sieve": engine._sieve_state} if engine.uses_cost_split else {}
     outs = []
-    for inp, _ in pre.calls:
-        logits, engine.cache, _ = engine._prefill_chunk(
+    for inp, *_ in pre.calls:
+        (_, logits), engine.cache, _ = engine._prefill_chunk(
             engine.params, {"tokens": jnp.asarray(inp["tokens"]), **sieve},
             engine.cache, np.int32(inp["slot"]),
         )
         outs.append(np.asarray(logits))
-    inp, _ = dec.calls[0]
-    logits, engine.cache, _ = engine._decode(
+    inp = dec.calls[0][0]
+    (_, logits), engine.cache, _ = engine._decode(
         engine.params,
         {"tokens": jnp.asarray(inp["tokens"]),
          "position": jnp.asarray(inp["position"]), **sieve},
@@ -315,15 +318,22 @@ def compare_logits(label: str, pre: Tap, dec: Tap, ref_pre, ref_dec,
                    vocab: int, live_slots, tol: float) -> None:
     import numpy as np
 
-    got_p = np.concatenate([lg for _, lg in pre.calls])[..., :vocab]
+    got_p = np.concatenate([c[1] for c in pre.calls])[..., :vocab]
     exp_p = np.concatenate(ref_pre)[..., :vocab]
+    ids_p = np.concatenate([c[2] for c in pre.calls])
     got_d = dec.calls[0][1][live_slots, ..., :vocab]
     exp_d = ref_dec[live_slots, ..., :vocab]
-    for phase, got, exp in (("prefill", got_p, exp_p), ("decode", got_d, exp_d)):
+    ids_d = dec.calls[0][2][live_slots]
+    for phase, got, exp, ids in (("prefill", got_p, exp_p, ids_p),
+                                 ("decode", got_d, exp_d, ids_d)):
         got = got.astype(np.float32).reshape(-1, vocab)
         exp = exp.astype(np.float32).reshape(-1, vocab)
         if not (np.isfinite(got).all() and np.isfinite(exp).all()):
             raise Fail(f"{label} {phase}: non-finite logits")
+        # the step's own greedy pick is the host argmax of its logits
+        if not (ids == got.argmax(-1)).all():
+            raise Fail(f"{label} {phase}: the step's picked ids are not the "
+                       "argmax of its logits")
         rel = float(np.linalg.norm(got - exp) / np.linalg.norm(exp))
         agree = float((got.argmax(-1) == exp.argmax(-1)).mean())
         ok = rel <= tol

@@ -70,6 +70,28 @@ _SENTINEL_PROBES = 3  # repeats per boundary; the mean damps OS jitter
 _PIM_BLOCKED_TIME = 1e9
 
 
+def greedy_ids(logits: jax.Array, vocab_size: int) -> jax.Array:
+    """Each row's greedy token from a step's logits ``(B, S, V_padded)`` at
+    the last position: ``(B,)`` int32, the first index on ties, as numpy's
+    argmax takes it."""
+    return jnp.argmax(logits[:, -1, :vocab_size], axis=-1).astype(jnp.int32)
+
+
+def decode_program(lm: LM):
+    """The function the engine compiles for a decode step: ``lm.decode_step``
+    and each row's greedy pick of its logits, as ``((ids, logits), cache,
+    aux)``.  A greedy engine copies only the ``(B,)`` ids to the host; one
+    that samples on the host copies the logits.  The inner function's name
+    keeps the compiled module ``jit_decode_step``, the name a device trace
+    finds the decode program by."""
+
+    def decode_step(params, batch, cache):
+        logits, cache, aux = lm.decode_step(params, batch, cache)
+        return (greedy_ids(logits, lm.arch.vocab_size), logits), cache, aux
+
+    return decode_step
+
+
 def _placed_cache(lm: LM, make, per_slot: bool = True) -> Any:
     """The cache ``make`` builds, created already sharded by
     ``cache_pspecs`` when the model runs on a mesh (never gathered onto
@@ -173,7 +195,7 @@ class ServingEngine:
         # copies — at decode that is a whole-cache memcpy per step.  With
         # donation XLA aliases cache-in to cache-out and the update is
         # in-place (pinned by tests/test_serving.py::TestBufferDonation).
-        self._decode = jax.jit(lm.decode_step, donate_argnums=(2,))
+        self._decode = jax.jit(decode_program(lm), donate_argnums=(2,))
         # the slot is a traced int32, so prefill compiles once per prompt
         # length, not once per (slot, prompt length) pair
         self._prefill_chunk = jax.jit(
@@ -353,7 +375,9 @@ class ServingEngine:
     # ------------------------------------------------------------------
     def _prefill_chunk_impl(self, params, batch, cache, slot: jax.Array):
         """Prefill one whole prompt into slot ``slot`` (B=1 path; ``slot``
-        is a traced int32 scalar)."""
+        is a traced int32 scalar).  Returns ``((ids, logits), cache, aux)``
+        as the decode program does: the ``(1,)`` greedy id of the prompt's
+        next token beside its logits."""
         block_ids = batch.pop("block_ids", None)  # paged: slot's block-table row
         logits, req_cache, aux = self.lm.prefill(params, batch)
 
@@ -386,7 +410,8 @@ class ServingEngine:
                 )
 
         new_cache = jax.tree.map(insert, cache, req_cache)
-        return logits, new_cache, aux
+        ids = greedy_ids(logits, self.lm.arch.vocab_size)
+        return (ids, logits), new_cache, aux
 
     # ------------------------------------------------------------------
     def submit(self, req: Request) -> bool:
@@ -629,16 +654,16 @@ class ServingEngine:
                 pos = jnp.broadcast_to(jnp.arange(P), (1, P))
                 batch["mrope_positions"] = jnp.stack([pos, pos, pos])
             with tel.span("engine/prefill", value=float(len(req.prompt))):
-                logits, self.cache, p_aux = self._prefill_chunk(
+                out, self.cache, p_aux = self._prefill_chunk(
                     self.params, batch, self.cache, np.int32(req.slot)
                 )
-                logits = np.asarray(logits)
+                picked = self._picked(out)
             if self.is_moe:
                 self.stats.dropped_tokens += int(p_aux.dropped)
                 self.stats.routed_tokens += int(np.asarray(p_aux.counts).sum())
             req.prefill_done = len(req.prompt)
             self.stats.prefill_tokens += len(req.prompt)
-            tok = self._sample(logits[:, -1])
+            tok = self._sample(picked)
             req.generated.append(int(tok[0]))
             if req.first_token_time is None:
                 req.first_token_time = time.perf_counter()
@@ -675,21 +700,25 @@ class ServingEngine:
                 db["mrope_positions"] = jnp.concatenate([mp, mp, mp], axis=0)
             with tel.span("engine/decode", value=float(len(batch_reqs))):
                 with tel.span("engine/decode_launch"):
-                    logits, self.cache, aux = self._decode(
+                    out, self.cache, aux = self._decode(
                         self.params, db, self.cache
                     )
                 if tel.enabled:
                     # the device's end, apart from the copy that follows
                     # (np.asarray makes the same sync untraced)
                     with tel.span("engine/decode_wait"):
-                        jax.block_until_ready(logits)
+                        jax.block_until_ready(out)
                 with tel.span("engine/decode_logits"):
-                    logits = np.asarray(logits)
-            with tel.span("engine/decode_sample", value=float(logits.shape[0])):
-                toks = self._sample(logits[:, 0])
+                    picked = self._picked(out)
+            with tel.span("engine/decode_sample", value=float(picked.shape[0])):
+                toks = self._sample(picked)
                 for r in batch_reqs:
                     r.generated.append(int(toks[r.slot]))
                     self.stats.decode_tokens += 1
+            tel.counter(
+                "engine/device_sampled_rows",
+                float(len(batch_reqs)) if self.greedy else 0.0,
+            )
             if self.is_moe:
                 with tel.span("engine/aux_to_host"):
                     dropped = int(aux.dropped)
@@ -804,9 +833,22 @@ class ServingEngine:
             self.step()
         return self.sched.finished
 
-    def _sample(self, logits: np.ndarray) -> np.ndarray:
+    def _picked(self, out) -> np.ndarray:
+        """The host copy that sampling reads from a compiled step's
+        ``(ids, logits)``: the ``(B,)`` greedy ids the step picked, or,
+        where the engine samples on the host, the ``(B, V)`` logits of the
+        last position."""
+        ids, logits = out
+        return np.asarray(ids) if self.greedy else np.asarray(logits)[:, -1]
+
+    def _sample(self, picked: np.ndarray) -> np.ndarray:
+        """Each row's token from :meth:`_picked`'s copy: the step's own
+        greedy ids, or a temperature-1 draw from the logits with the
+        engine's RNG (in float32: bfloat16 probabilities do not sum to 1
+        closely enough for ``choice``)."""
         if self.greedy:
-            return logits.argmax(-1)
+            return picked
+        logits = picked.astype(np.float32)
         z = logits - logits.max(-1, keepdims=True)
         p = np.exp(z) / np.exp(z).sum(-1, keepdims=True)
         return np.array(

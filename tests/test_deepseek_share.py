@@ -294,12 +294,12 @@ def test_engine_prefill_then_decode_matches_reference():
 
     def tap_prefill(*a):
         out = prefill(*a)
-        seen.append(("prefill", np.asarray(out[0])[0, -1]))
+        seen.append(("prefill", np.asarray(out[0][1])[0, -1]))
         return out
 
     def tap_decode(*a):
         out = decode(*a)
-        seen.append(("decode", np.asarray(out[0])[:, 0]))
+        seen.append(("decode", np.asarray(out[0][1])[:, 0]))
         return out
 
     eng._prefill_chunk, eng._decode = tap_prefill, tap_decode

@@ -187,6 +187,44 @@ def test_qwen3_decode_step_compiles_for_v5e(
         assert re.search(rf"%{kernel}\.\d+ = \S+ custom-call\(", text), kernel
 
 
+def test_engine_decode_program_picks_ids_for_v5e(
+    one_chip, no_compile_cache, monkeypatch
+):
+    """The engine's compiled decode function at qwen3's full width and the
+    cell's 48 slots: the greedy argmax over all 151936 columns compiles for
+    the chip beside the decode step, and the program returns the ``(48,)``
+    int32 ids the engine copies to the host, beside the logits."""
+    from repro.serving.engine import decode_program
+
+    monkeypatch.setattr(moe, "_dual_backend", lambda: "pallas")
+    monkeypatch.setattr(attention, "_flash_decode_mode", lambda: "kernel")
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    lm = LM(cut_depth(ARCH, 1), dtype=BF)
+    slots = 48
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree,
+        )
+
+    params = placed(lm.abstract_params())
+    cache = placed(jax.eval_shape(lambda: lm.init_cache(slots, MAX_SEQ)))
+    batch = placed({
+        "tokens": jax.ShapeDtypeStruct((slots, 1), I32),
+        "position": jax.ShapeDtypeStruct((slots,), I32),
+    })
+    lowered = jax.jit(decode_program(lm), donate_argnums=(2,)).lower(
+        params, batch, cache
+    )
+    compiled = lowered.compile()
+    (ids, logits), _, _ = compiled.out_info
+    assert (ids.shape, ids.dtype) == ((slots,), jnp.int32)
+    assert logits.shape == (slots, 1, ARCH.vocab_size)
+    assert lowered.as_text().startswith("module @jit_decode_step")
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') >= 3
+
+
 def test_qwen3_decode_step_reads_expert_stacks_in_place(
     one_chip, no_compile_cache, monkeypatch
 ):
