@@ -4,6 +4,7 @@ from .base import (  # noqa: F401
     ARCH_IDS,
     ArchConfig,
     AttnConfig,
+    LinearAttnConfig,
     MLAConfig,
     MoEConfig,
     SHAPES,

@@ -53,6 +53,13 @@ class AttnConfig:
     rope_scaling: Optional[YarnConfig] = None  # MLA rope only
     # Qwen2-VL M-RoPE: head-dim split across (temporal, height, width)
     mrope_sections: Optional[Tuple[int, int, int]] = None
+    # Qwen3-Next's gated attention: rope on the first ``rotary_dim`` dims
+    # of each head (0: all of them), an RMSNorm over the head dim on q and
+    # k before rope, and the output multiplied by sigmoid(x W_gate) before
+    # the output projection (``q_proj`` gives [q, gate])
+    rotary_dim: int = 0
+    qk_norm: bool = False
+    out_gate: bool = False
 
 
 @dataclass(frozen=True)
@@ -108,6 +115,9 @@ class MoEConfig:
     # held = 0 holds every expert.
     held: int = 0
     held_offset: int = 0
+    # Qwen3-Next: the shared expert's output scaled by sigmoid(x w), one
+    # logit a token
+    shared_gate: bool = False
 
     def __post_init__(self):
         E, G = self.n_experts, self.n_group
@@ -156,9 +166,33 @@ class SSMConfig:
 
 
 @dataclass(frozen=True)
+class LinearAttnConfig:
+    """Gated DeltaNet linear attention (Qwen3-Next) and the layer pattern
+    that interleaves it with gated softmax attention: layer ``i`` is full
+    attention where ``(i + 1) % full_attention_interval == 0``, linear
+    attention otherwise.  Value head ``h`` reads key head
+    ``h // (n_v_heads // n_k_heads)``; q, k and v pass a causal depthwise
+    conv of ``conv_width`` taps; each value head keeps a ``(d_k, d_v)``
+    float32 state per sequence."""
+
+    n_k_heads: int = 16
+    n_v_heads: int = 32
+    d_k: int = 128
+    d_v: int = 128
+    conv_width: int = 4
+    full_attention_interval: int = 4
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels of the conv: q, k and v side by side."""
+        return 2 * self.n_k_heads * self.d_k + self.n_v_heads * self.d_v
+
+
+@dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str  # "dense" | "moe" | "hybrid" | "ssm" | "audio" | "vlm"
+    # "dense" | "moe" | "hybrid" | "ssm" | "audio" | "vlm" | "linear_hybrid"
+    family: str
     n_layers: int
     d_model: int
     d_ff: int
@@ -166,6 +200,9 @@ class ArchConfig:
     attn: AttnConfig = field(default_factory=AttnConfig)
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
+    # linear_hybrid (qwen3-next): Gated DeltaNet layers beside gated
+    # attention, MoE in every layer
+    linear: Optional[LinearAttnConfig] = None
     norm: str = "rmsnorm"  # "rmsnorm" | "layernorm"
     act: str = "swiglu"  # "swiglu" | "gelu"
     pos: str = "rope"  # "rope" | "mrope" | "learned" | "none"
@@ -249,6 +286,7 @@ class ArchConfig:
                 if a.mla is not None
                 else None,
                 mrope_sections=(4, 2, 2) if a.mrope_sections else None,
+                rotary_dim=4 if a.rotary_dim else 0,
             )
         if self.moe is not None:
             kw["moe"] = dataclasses.replace(
@@ -262,6 +300,11 @@ class ArchConfig:
             kw["ssm"] = dataclasses.replace(
                 self.ssm, d_state=16, head_dim=16, decay_lora=8, wkv_chunk=16
             )
+        if self.linear is not None:
+            kw["linear"] = dataclasses.replace(
+                self.linear, n_k_heads=2, n_v_heads=4, d_k=16, d_v=16
+            )
+            kw["n_layers"] = self.linear.full_attention_interval
         if self.encdec:
             kw["enc_layers"] = 2
             kw["enc_seq"] = 16
@@ -311,6 +354,8 @@ _MODULE_OF = {
     "whisper-base": "whisper_base",
     "qwen2-vl-7b": "qwen2_vl_7b",
     "rwkv6-7b": "rwkv6_7b",
+    # served and benchmarked, outside the assigned sweep of ARCH_IDS
+    "qwen3-next-80b-a3b": "qwen3_next_80b_a3b",
 }
 
 

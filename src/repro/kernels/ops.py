@@ -220,3 +220,36 @@ def decode_attention_paged(
         q, pool_k, pool_v, block_tables.astype(jnp.int32),
         lengths.astype(jnp.int32), interpret=interpret,
     )
+
+
+# ---------------------------------------------------------------------------
+# Gated DeltaNet decode recurrence
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnames=("hb", "interpret"))
+def gated_delta_decode(
+    state: jax.Array,  # (L, B, H, dk, dv) float32, every linear layer
+    layer: jax.Array,  # () int32
+    q: jax.Array,  # (B, H, dk) float32
+    k: jax.Array,  # (B, H, dk) float32
+    v: jax.Array,  # (B, H, dv) float32
+    decay: jax.Array,  # (B, H) float32: exp(g)
+    beta: jax.Array,  # (B, H) float32
+    hb: int = 16,
+    interpret: bool | None = None,
+):
+    """One token of the delta rule for layer ``layer`` of the whole
+    layer-stacked state, updated in place; returns ``(o (B, H, dv),
+    state)``.  The per-head gates are laid along ``dv`` for the kernel."""
+    from .gated_delta import gated_delta_decode as _gated_delta_decode
+
+    if interpret is None:
+        interpret = _interpret_default()
+    dv = v.shape[-1]
+    lanes = lambda a: jnp.broadcast_to(  # noqa: E731
+        a.astype(jnp.float32)[..., None], a.shape + (dv,))
+    return _gated_delta_decode(
+        state, layer, q, k, v, lanes(decay), lanes(beta), hb=hb,
+        interpret=interpret,
+    )

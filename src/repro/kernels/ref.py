@@ -86,3 +86,26 @@ def gather_pages(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
     _, Kv, page, dh = pool.shape
     g = pool[block_tables]  # (B, nb, Kv, page, dh)
     return g.transpose(0, 2, 1, 3, 4).reshape(B, Kv, nb * page, dh)
+
+
+def gated_delta_decode_ref(
+    state: jax.Array,  # (L, B, H, dk, dv) float32
+    layer: jax.Array,  # () int32
+    q: jax.Array,  # (B, H, dk)
+    k: jax.Array,  # (B, H, dk)
+    v: jax.Array,  # (B, H, dv)
+    decay: jax.Array,  # (B, H): exp(g)
+    beta: jax.Array,  # (B, H)
+):
+    """One token of the gated delta rule on layer ``layer`` of the stacked
+    state, as HF ``torch_recurrent_gated_delta_rule`` steps it: returns
+    ``(o (B, H, dv) float32, state with that layer advanced)``."""
+    f32 = jnp.float32
+    s = jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False).astype(f32)
+    s = s * decay.astype(f32)[..., None, None]
+    kf, qf = k.astype(f32), q.astype(f32)
+    kv = jnp.einsum("bhkv,bhk->bhv", s, kf, precision="highest")
+    delta = beta.astype(f32)[..., None] * (v.astype(f32) - kv)
+    s = s + kf[..., :, None] * delta[..., None, :]
+    o = jnp.einsum("bhkv,bhk->bhv", s, qf, precision="highest")
+    return o, jax.lax.dynamic_update_index_in_dim(state, s.astype(state.dtype), layer, 0)

@@ -50,6 +50,11 @@ def init_gqa(key, cfg: AttnConfig, d_model: int, dtype=jnp.bfloat16) -> dict:
         p["bq"] = jnp.zeros((H * dh,), dtype)
         p["bk"] = jnp.zeros((K * dh,), dtype)
         p["bv"] = jnp.zeros((K * dh,), dtype)
+    if cfg.qk_norm:
+        p["q_norm_scale"] = jnp.ones((dh,), jnp.float32)
+        p["k_norm_scale"] = jnp.ones((dh,), jnp.float32)
+    if cfg.out_gate:
+        p["wq_gate"] = _he(jax.random.fold_in(ks[0], 1), (d_model, H * dh), 1.0, dtype)
     return p
 
 
@@ -211,7 +216,22 @@ def _rope_or_mrope(x, positions, cfg: AttnConfig, mrope_positions):
         return apply_mrope(x, mrope_positions, cfg.rope_theta, cfg.mrope_sections)
     if positions is None:
         return x
+    if cfg.rotary_dim:  # partial rotary: rope on the first rotary_dim dims
+        r = cfg.rotary_dim
+        return jnp.concatenate(
+            [apply_rope(x[..., :r], positions, cfg.rope_theta), x[..., r:]], -1)
     return apply_rope(x, positions, cfg.rope_theta)
+
+
+def _attn_out(params: dict, x: jax.Array, o: jax.Array, cfg: AttnConfig) -> jax.Array:
+    """The output projection of attention ``o`` for input ``x`` (B, S, d),
+    after the sigmoid output gate where ``cfg.out_gate``."""
+    B, S = x.shape[:2]
+    o = o.reshape(B, S, -1)
+    if cfg.out_gate:
+        gate = jax.nn.sigmoid((x @ params["wq_gate"]).astype(jnp.float32))
+        o = (o.astype(jnp.float32) * gate).astype(x.dtype)
+    return o @ params["wo"]
 
 
 def gqa_project_qkv(
@@ -234,6 +254,9 @@ def gqa_project_qkv(
     q = q.reshape(B, S, H, dh)
     k = k.reshape(B, S, K, dh)
     v = v.reshape(B, S, K, dh)
+    if cfg.qk_norm:
+        q = _rms(q, params["q_norm_scale"])
+        k = _rms(k, params["k_norm_scale"])
     if use_rope:
         q = _rope_or_mrope(q, positions, cfg, mrope_positions)
         k = _rope_or_mrope(k, positions, cfg, mrope_positions)
@@ -256,8 +279,7 @@ def gqa_prefill(
     use_rope = cfg.mrope_sections is not None or positions is not None
     q, k, v = gqa_project_qkv(params, x, positions, cfg, mrope_positions, use_rope)
     o = flash_attention(q, k, v, causal=causal, q_chunk=q_chunk, kv_chunk=kv_chunk)
-    B, S = x.shape[:2]
-    y = o.reshape(B, S, -1) @ params["wo"]
+    y = _attn_out(params, x, o, cfg)
     return y, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
 
 
@@ -296,7 +318,7 @@ def gqa_decode(
     else:
         # the dense einsum is both the XLA twin and the oracle here
         o = decode_attention_ref(q, cache_k, cache_v, idx + 1)
-    y = o.reshape(B, 1, -1) @ params["wo"]
+    y = _attn_out(params, x, o, cfg)
     return y, cache_k, cache_v
 
 

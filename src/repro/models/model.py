@@ -7,6 +7,8 @@ One class (:class:`LM`) covers the five structural families:
   * hybrid (zamba2)  — scan over [shared-attn + (attn_every-1) Mamba2]
     segments plus a Mamba2 tail;
   * ssm (rwkv6)      — scan over RWKV6 blocks;
+  * linear_hybrid (qwen3-next) — scan over periods of [Gated DeltaNet x
+    (interval-1) + gated attention], MoE in every layer;
   * encoder-decoder (whisper) — encoder scan + decoder scan w/ cross-attn.
 
 Everything is functional; ``params`` / ``cache`` are nested dicts of arrays
@@ -22,6 +24,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, ShapeSpec
+from . import attention as attn_lib
+from . import gated_delta as gdn
 from . import transformer as tf
 from .layers import (
     apply_norm,
@@ -52,6 +56,20 @@ class StepAux(NamedTuple):
 
 def _stack_init(fn, key, n: int):
     return jax.vmap(fn)(jax.random.split(key, n))
+
+
+def _linear_layout(arch: ArchConfig) -> Tuple[int, int]:
+    """(linear layers, full-attention layers) of a linear_hybrid stack:
+    whole periods of ``full_attention_interval`` layers, the last of each
+    full attention."""
+    per = arch.linear.full_attention_interval
+    if arch.n_layers % per:
+        raise ValueError(
+            f"{arch.name}: {arch.n_layers} layers are not whole periods of "
+            f"{per} (full_attention_interval)"
+        )
+    n_full = arch.n_layers // per
+    return arch.n_layers - n_full, n_full
 
 
 def _zamba_layout(arch: ArchConfig) -> Tuple[int, int, int]:
@@ -154,6 +172,21 @@ class LM:
             p["blocks"] = _stack_init(
                 lambda k: tf.init_rwkv_block(k, arch, dtype), ks[2], arch.n_layers
             )
+        elif arch.family == "linear_hybrid":
+            # the MoE (and norms) of every layer form one (L, ...) stack;
+            # each mixer kind its own
+            n_lin, n_full = _linear_layout(arch)
+            p["blocks"] = _stack_init(
+                lambda k: tf.init_norms_moe_block(k, arch, dtype), ks[3], arch.n_layers
+            )
+            p["linear_attn"] = _stack_init(
+                lambda k: gdn.init_gated_delta(k, arch.linear, arch.d_model, dtype),
+                ks[4], n_lin,
+            )
+            p["full_attn"] = _stack_init(
+                lambda k: attn_lib.init_gqa(k, arch.attn, arch.d_model, dtype),
+                ks[5], n_full,
+            )
         elif arch.family == "audio":
             p["enc_blocks"] = _stack_init(
                 lambda k: tf.init_enc_block(k, arch, dtype), ks[2], arch.enc_layers
@@ -206,9 +239,9 @@ class LM:
         always slices: a closed-over stack would turn into a whole-stack
         gradient accumulator under ``scan``."""
         arch = self.arch
-        if arch.family not in ("dense", "moe", "vlm") or not experts_in_place(
-            arch, self.mi
-        ):
+        if arch.family not in (
+            "dense", "moe", "vlm", "linear_hybrid"
+        ) or not experts_in_place(arch, self.mi):
             return 0
         return arch.n_layers - arch.moe.first_k_dense
 
@@ -273,6 +306,93 @@ class LM:
         if collect_cache:
             caches["blocks"] = cache_stack
         return x, caches, auxes, aux_stack
+
+    def _walk_linear_hybrid(self, p, x, positions, cache, sieve, step: bool):
+        """qwen3-next: a scan over periods of the layer pattern, each
+        ``[linear] * (interval - 1) + [full]``.  The MoE stays one ``(L,
+        ...)`` stack; where the expert kernels read it in place, layer
+        ``l`` gets base row ``l*E``.  Decode carries the recurrent and conv
+        states whole through the scan (linear layer ``i`` advances its own
+        rows in place) and takes the full layers' KV as the scan's slices;
+        prefill returns every layer's new state.  Returns ``(x, cache,
+        aux)`` with aux stacked ``(L, ...)``."""
+        arch, mi = self.arch, self.mi
+        per = arch.linear.full_attention_interval
+        L = arch.n_layers
+        n_per = L // per
+        moe_p = p["blocks"]["moe"]
+        E = moe_p["w_gate"].shape[1]
+        whole = {}
+        if self.moe_layers_in_place() > 0:
+            whole = {k: moe_p[k].reshape((L * E,) + moe_p[k].shape[2:])
+                     for k in ROUTED_STACKS}
+
+        def periods(tree, n):
+            return jax.tree.map(lambda a: a.reshape((n_per, n) + a.shape[1:]), tree)
+
+        xs = {
+            "blocks": periods({**p["blocks"], "moe": {
+                k: v for k, v in moe_p.items() if k not in whole}}, per),
+            "lin": periods(p["linear_attn"], per - 1),
+            "full": p["full_attn"],
+            "period": jnp.arange(n_per, dtype=jnp.int32),
+        }
+        if step:
+            xs["kv"] = cache["kv"]
+
+        def body(carry, inp):
+            x, rec, conv = carry if step else (carry, None, None)
+            t = inp["period"]
+            auxes, lin_states = [], []
+            for j in range(per):
+                blk = jax.tree.map(lambda a: a[j], inp["blocks"])
+                if whole:
+                    blk["moe"] = {**blk["moe"], **whole,
+                                  "expert_base": (t * per + j) * E}
+                if j < per - 1:
+                    lp = jax.tree.map(lambda a: a[j], inp["lin"])
+
+                    def mixer(h):
+                        if step:
+                            return gdn.gated_delta_decode(
+                                lp, h, rec, conv, t * (per - 1) + j, arch.linear)
+                        return gdn.gated_delta_prefill(lp, h, arch.linear)
+
+                    x, st, aux = tf.mixer_moe_block(blk, x, mixer, arch, mi, sieve)
+                    if step:
+                        rec, conv = st
+                    else:
+                        lin_states.append(st)
+                else:
+
+                    def mixer(h):
+                        if step:
+                            y, k, v = attn_lib.gqa_decode(
+                                inp["full"], h, positions, inp["kv"][0], inp["kv"][1],
+                                arch.attn, mi=mi)
+                        else:
+                            y, k, v = attn_lib.gqa_prefill(
+                                inp["full"], h, positions, arch.attn,
+                                q_chunk=self.q_chunk, kv_chunk=self.kv_chunk)
+                        return y, (k, v)
+
+                    x, kv, aux = tf.mixer_moe_block(blk, x, mixer, arch, mi, sieve)
+                auxes.append(aux)
+            aux = jax.tree.map(lambda *a: jnp.stack(a), *auxes)
+            if step:
+                return (x, rec, conv), (kv, aux)
+            lin = jax.tree.map(lambda *a: jnp.stack(a), *lin_states)
+            return x, (kv, lin, aux)
+
+        def flat(tree):  # (n_per, n, ...) -> (n_per * n, ...)
+            return jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), tree)
+
+        if step:
+            (x, rec, conv), (kv, aux) = jax.lax.scan(
+                body, (x, cache["recurrent"], cache["conv"]), xs)
+            return x, {"kv": kv, "recurrent": rec, "conv": conv}, flat(aux)
+        x, (kv, (rec, conv), aux) = jax.lax.scan(body, x, xs)
+        return x, {"kv": kv, "recurrent": flat(rec), "conv": flat(conv)}, flat(aux)
 
     def _walk_hybrid_stack(self, p, x, positions, states, collect_cache: bool,
                            step: bool):
@@ -400,6 +520,11 @@ class LM:
                 sieve=batch.get("sieve"),
             )
             aux = _aggregate_aux(arch, prefix_aux, aux_stack)
+        elif arch.family == "linear_hybrid":
+            x, _, aux_stack = self._walk_linear_hybrid(
+                p, x, positions, None, batch.get("sieve"), step=False
+            )
+            aux = _aggregate_aux(arch, [], aux_stack)
         elif arch.family == "hybrid":
             x, _ = self._walk_hybrid_stack(
                 p, x, positions, None, collect_cache=False, step=False
@@ -519,6 +644,20 @@ class LM:
                 if n_prefix:
                     c["prefix"] = kv(n_prefix)
             return c
+        if arch.family == "linear_hybrid":
+            # two kinds of per-slot state side by side: the full layers' KV,
+            # and each linear layer's float32 recurrent state and conv tail
+            lin = arch.linear
+            n_lin, n_full = _linear_layout(arch)
+            return {
+                "kv": kv(n_full),
+                "recurrent": jnp.zeros(
+                    (n_lin, batch, lin.n_v_heads, lin.d_k, lin.d_v), jnp.float32
+                ),
+                "conv": jnp.zeros(
+                    (n_lin, batch, lin.conv_width - 1, lin.conv_dim), dtype
+                ),
+            }
         if arch.family == "hybrid":
             nseg, per, tail = _zamba_layout(arch)
             seg_states = jax.vmap(
@@ -606,6 +745,11 @@ class LM:
                 vs = [c[1] for c in caches["prefix"]]
                 cache["prefix"] = (jnp.stack(ks), jnp.stack(vs))
             aux = _aggregate_aux(arch, prefix_aux, aux_stack)
+        elif arch.family == "linear_hybrid":
+            x, cache, aux_stack = self._walk_linear_hybrid(
+                p, x, positions, None, batch.get("sieve"), step=False
+            )
+            aux = _aggregate_aux(arch, [], aux_stack)
         elif arch.family == "hybrid":
             x, new_states = self._walk_hybrid_stack(
                 p, x, positions, None, collect_cache=True, step=False
@@ -727,6 +871,11 @@ class LM:
             if n_prefix:
                 new_cache["prefix"] = new_prefix
             aux = _aggregate_aux(arch, auxes, aux_stack)
+        elif arch.family == "linear_hybrid":
+            x, new_cache, aux_stack = self._walk_linear_hybrid(
+                p, x, position, cache, batch.get("sieve"), step=True
+            )
+            aux = _aggregate_aux(arch, [], aux_stack)
         elif arch.family == "hybrid":
             x, new_cache = self._walk_hybrid_stack(
                 p, x, position, cache, collect_cache=True, step=True

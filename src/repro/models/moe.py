@@ -99,6 +99,9 @@ def init_moe(key, arch: ArchConfig, dtype=jnp.bfloat16) -> dict:
             "w_up": _he(sks[1], (d, cfg.n_shared * f), 1.0, dtype),
             "w_down": _he(sks[2], (cfg.n_shared * f, d), 1.0, dtype),
         }
+        if cfg.shared_gate:
+            p["shared"]["w_expert_gate"] = _he(
+                jax.random.fold_in(ks[4], 1), (d, 1), 1.0, dtype)
     return p
 
 
@@ -118,6 +121,8 @@ def moe_param_pspecs(arch: ArchConfig, model_axis: str) -> dict:
             "w_up": P(None, model_axis),
             "w_down": P(model_axis, None),
         }
+        if cfg.shared_gate:
+            p["shared"]["w_expert_gate"] = P(None, None)
     return p
 
 
@@ -952,7 +957,8 @@ def moe_block(
     ``expert_exec="dual_path_cost"`` (ignored by the other modes; the
     roofline default is used when it is needed but absent).  Shared
     experts run outside the shard_map as plain tensor-parallel dense
-    MLPs (every token visits them — the paper's early-weight-load case)."""
+    MLPs (every token visits them — the paper's early-weight-load case),
+    scaled by ``sigmoid(x w_expert_gate)`` where ``cfg.shared_gate``."""
     cfg = arch.moe
     B, S, d = x.shape
     xt = x.reshape(B * S, d)
@@ -1020,7 +1026,11 @@ def moe_block(
         sp = params["shared"]
         gate = xt @ sp["w_gate"]
         up = xt @ sp["w_up"]
-        y = y + (jax.nn.silu(gate) * up) @ sp["w_down"]
+        shared = (jax.nn.silu(gate) * up) @ sp["w_down"]
+        if cfg.shared_gate:
+            g = jax.nn.sigmoid((xt @ sp["w_expert_gate"]).astype(jnp.float32))
+            shared = (shared.astype(jnp.float32) * g).astype(shared.dtype)
+        y = y + shared
 
     return MoEOut(y.reshape(B, S, d), routed.aux_loss, routed.counts, routed.n_dropped)
 

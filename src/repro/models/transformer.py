@@ -4,6 +4,7 @@ Block kinds:
   * ``attn_mlp``  — (GQA | MLA) attention + (dense MLP | MoE)   [most archs]
   * ``mamba``     — Mamba2 block                                 [zamba2]
   * ``rwkv``      — RWKV6 time-mix + channel-mix                 [rwkv6]
+  * ``mixer_moe`` — Gated DeltaNet | gated GQA + MoE             [qwen3-next]
   * ``enc``/``dec`` — whisper encoder / decoder (w/ cross-attn)
 
 Stacks scan over stacked per-layer params (HLO size O(1) in depth) with
@@ -149,6 +150,30 @@ def attn_mlp_block_decode(
         x = x + apply_mlp(p["mlp"], h, arch.act)
         aux = _zero_aux()
     return x, new_cache, aux
+
+
+# ---------------------------------------------------------------------------
+# (linear | gated) attention + MoE block (qwen3-next)
+# ---------------------------------------------------------------------------
+
+
+def init_norms_moe_block(key, arch: ArchConfig, dtype=jnp.bfloat16) -> dict:
+    """A layer's norms and MoE, without its mixer: the mixers of a layer
+    pattern that alternates kinds live in stacks of their own."""
+    d = arch.d_model
+    return {"norm1": init_norm(d, arch.norm), "norm2": init_norm(d, arch.norm),
+            "moe": init_moe(key, arch, dtype)}
+
+
+def mixer_moe_block(p: dict, x: jax.Array, mixer, arch: ArchConfig,
+                    mi: MeshInfo, sieve=None):
+    """``x + mixer(norm1(x))``, then its MoE; ``mixer`` maps the normed
+    input to ``(y, state)``.  Returns ``(x, state, aux)``."""
+    a, state = mixer(apply_norm(p["norm1"], x, arch.norm))
+    x = x + a
+    out: MoEOut = moe_block(p["moe"], apply_norm(p["norm2"], x, arch.norm), arch, mi,
+                            sieve=sieve)
+    return x + out.y, state, BlockAux(out.aux_loss, out.counts, out.n_dropped)
 
 
 # ---------------------------------------------------------------------------

@@ -316,6 +316,13 @@ class ServingEngine:
             # the experts each MoE layer holds (all of the router's, or
             # one chip's share of an expert-parallel group)
             self.tel.gauge("engine/moe_experts_held", float(arch.moe.n_held))
+        if arch.family == "linear_hybrid":
+            # fixed-size per-slot state beside the KV: the linear layers'
+            # recurrent state and conv tails, for every slot
+            self.tel.gauge(
+                "engine/recurrent_state_bytes",
+                float(self.cache["recurrent"].nbytes + self.cache["conv"].nbytes),
+            )
 
     # ------------------------------------------------------------------
     def _refresh_sieve_state(self, step: int, gpu_only: bool = False) -> None:

@@ -24,7 +24,10 @@ SLOTS, MAX_SEQ, PAGE = 32, 2048, 64
 # one DeepSeek-V2 chip's share: 20 held experts at published widths
 DS = get_arch("deepseek-v2-236b")
 DS_E, DS_D, DS_F = 20, DS.d_model, DS.moe.d_expert
-BF, I32 = jnp.bfloat16, jnp.int32
+# one Qwen3-Next chip's share: 8 layers (6 Gated DeltaNet), 64 held experts
+QN = get_arch("qwen3-next-80b-a3b")
+QN_LIN = QN.linear
+BF, I32, F32 = jnp.bfloat16, jnp.int32, jnp.float32
 
 
 @pytest.fixture(scope="module")
@@ -119,6 +122,21 @@ def _kernel_case(name, sds):
             ),
             (q, pool, pool, sds((SLOTS, MAX_SEQ // PAGE), I32), lens),
         ),
+        # Qwen3-Next's gated attention (head dim 256, 8 q heads a kv
+        # head) and its linear layers' recurrence, at the cell's 128 slots
+        "decode_attention.qwen3_next": (
+            lambda q, k, v, n: ops.decode_attention(q, k, v, n, interpret=False),
+            (sds((128, 16, 256)), sds((128, 2, MAX_SEQ, 256)),
+             sds((128, 2, MAX_SEQ, 256)), sds((128,), I32)),
+        ),
+        "gated_delta_decode": (
+            lambda st, l, q, k, v, g, b: ops.gated_delta_decode(
+                st, l, q, k, v, g, b, interpret=False
+            ),
+            (sds((6, 128, QN_LIN.n_v_heads, QN_LIN.d_k, QN_LIN.d_v), F32),
+             sds((), I32), *[sds((128, QN_LIN.n_v_heads, 128), F32)] * 3,
+             *[sds((128, QN_LIN.n_v_heads), F32)] * 2),
+        ),
     }[name]
 
 
@@ -134,6 +152,8 @@ def _kernel_case(name, sds):
         "decode_attention",
         "decode_attention_split4",
         "decode_attention_paged",
+        "decode_attention.qwen3_next",
+        "gated_delta_decode",
     ],
 )
 def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache):
@@ -315,3 +335,130 @@ def test_deepseek_share_decode_step_compiles_for_v5e(
     results = {m.group(1) for m in re.finditer(r"= (bf16\[[\d,]+\])\S* [\w\-]+\(", text)}
     assert not {f"bf16[20,{d},{f}]", f"bf16[20,{f},{d}]"} & results
     assert f"bf16[40,{d},{f}]" in results  # the two layers' stacks, viewed whole
+
+
+def _placed(tree, one_chip):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+
+
+def _decode_program_text(arch, slots, one_chip):
+    """The engine's compiled decode program for ``arch`` at ``slots``
+    slots of 2048 positions, on the Pallas paths the chip selects."""
+    from repro.serving.engine import decode_program
+
+    lm = LM(arch, dtype=BF)
+    batch = _placed({"tokens": jax.ShapeDtypeStruct((slots, 1), I32),
+                     "position": jax.ShapeDtypeStruct((slots,), I32)}, one_chip)
+    cache = _placed(jax.eval_shape(lambda: lm.init_cache(slots, MAX_SEQ)), one_chip)
+    return (
+        jax.jit(decode_program(lm), donate_argnums=(2,))
+        .lower(_placed(lm.abstract_params(), one_chip), batch, cache)
+        .compile()
+        .as_text()
+    )
+
+
+@pytest.fixture
+def chip_paths(monkeypatch):
+    from repro.models import gated_delta
+
+    monkeypatch.setattr(moe, "_dual_backend", lambda: "pallas")
+    monkeypatch.setattr(attention, "_flash_decode_mode", lambda: "kernel")
+    monkeypatch.setattr(gated_delta, "_decode_mode", lambda: "kernel")
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+
+
+def test_qwen3_next_share_decode_step_compiles_for_v5e(
+    one_chip, no_compile_cache, chip_paths
+):
+    """Qwen3-Next's served decode program as the benchmark cell runs it:
+    8 layers (6 Gated DeltaNet, 2 gated attention) holding 64 of 512
+    experts, 128 slots.  The recurrence is the ``gated_delta_decode``
+    kernel, once per linear layer of a period; the float32 state is
+    updated in place: no operation outside the kernel makes one layer's
+    ``(128, 32, 128, 128)`` slice, and the whole state is only passed
+    along, never copied."""
+    import dataclasses
+    import re
+
+    arch = cut_depth(QN, 8)
+    arch = dataclasses.replace(arch, moe=dataclasses.replace(
+        arch.moe, held=64, capacity_factor=51.2))
+    text = _decode_program_text(arch, 128, one_chip)
+    kernels = re.findall(r"%(\w+?)(?:\.\d+)? = \S.*? custom-call\(", text)
+    assert kernels.count("gated_delta_decode") == 3, kernels
+    for k in ("swiglu_gmm_capacity", "swiglu_gemv", "decode_attention"):
+        assert k in kernels, k
+    lin = QN_LIN
+    layer = f"f32[128,{lin.n_v_heads},{lin.d_k},{lin.d_v}]"
+    whole = f"f32[6,128,{lin.n_v_heads},{lin.d_k},{lin.d_v}]"
+    made = {}
+    for m in re.finditer(r"= (f32\[[\d,]+\])\S* ([\w\-]+)\(", text):
+        made.setdefault(m.group(1), set()).add(m.group(2))
+    assert layer not in made
+    assert made[whole] <= {"parameter", "get-tuple-element", "bitcast", "tuple"}
+
+
+def _program_fingerprint(text: str) -> str:
+    """A compiled program's HLO without its debug information (the
+    source-file tables, op metadata) and with each Mosaic kernel body
+    read back and printed without locations: the same for the same
+    program wherever the checkout lies."""
+    import base64
+    import hashlib
+    import re
+
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    def body(m):
+        with ir.Context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            tpu.register_dialect(ctx)
+            asm = ir.Module.parse(base64.b64decode(m.group(1))).operation.get_asm(
+                enable_debug_info=False)
+        return '"body":"' + hashlib.sha256(asm.encode()).hexdigest() + '"'
+
+    lines, skip = [], False
+    for line in text.splitlines():
+        if line in ("FileNames", "FunctionNames", "FileLocations", "StackFrames"):
+            skip = True
+        elif skip and (line.startswith("%") or line.startswith("ENTRY")):
+            skip = False
+        if not skip:
+            lines.append(line)
+    text = re.sub(r", metadata=\{[^}]*\}", "", "\n".join(lines))
+    text = re.sub(r'"body":"([^"]*)"', body, text)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+# The fingerprints of the accepted cells' decode programs as the program
+# stood before Qwen3-Next's hybrid family joined it: that family's switch
+# must not reach them.  A change that alters either program on purpose
+# updates its fingerprint here and says why.
+ACCEPTED_DECODE_PROGRAMS = {
+    "qwen3-moe-30b-a3b": "6d8de56a5c1a08a2",
+    "deepseek-v2-236b": "9778bcd7ea4a8f9a",
+}
+
+
+@pytest.mark.parametrize("name", sorted(ACCEPTED_DECODE_PROGRAMS))
+def test_accepted_cells_decode_programs_unchanged(
+    name, one_chip, no_compile_cache, chip_paths
+):
+    """qwen3 (one layer, 32 slots) and the DeepSeek-V2 share (dense lead +
+    two MoE layers holding 20 of 160 experts, 128 slots): the engine's
+    decode program compiles to the recorded HLO and kernel bodies."""
+    import dataclasses
+
+    if name == "qwen3-moe-30b-a3b":
+        arch, slots = cut_depth(ARCH, 1), SLOTS
+    else:
+        arch = cut_depth(DS, 3)
+        arch = dataclasses.replace(arch, moe=dataclasses.replace(
+            arch.moe, held=20, expert_exec="dual_path_cost"))
+        slots = 128
+    got = _program_fingerprint(_decode_program_text(arch, slots, one_chip))
+    assert got == ACCEPTED_DECODE_PROGRAMS[name], got
